@@ -154,51 +154,10 @@ def overrides_from_args(args: argparse.Namespace) -> dict:
     return ov
 
 
-def main(argv=None) -> int:
-    if argv is None:
-        argv = sys.argv[1:]
-    if argv and argv[0] == "fork":
-        # first-class fork verb: `python -m shadow_tpu fork cfg.yaml
-        # --from CKPT --branches branches.yaml` — checkpoint-forked
-        # what-if trees (shadow_tpu/forks.py; also reachable as
-        # `python -m shadow_tpu.fleet sweep --fork-from`)
-        from shadow_tpu.forks import main as _fork_main
-
-        return _fork_main(argv[1:])
-    args = build_parser().parse_args(argv)
-    from shadow_tpu.config import load_config
+def _run(cfg, args):
+    """Run the simulation the config asks for: the result summary, or an
+    exit code after a clean one-line refusal."""
     from shadow_tpu.core.controller import Controller
-
-    try:
-        cfg = load_config(args.config, overrides_from_args(args))
-    except FileNotFoundError:
-        print(f"shadow_tpu: config file not found: {args.config}", file=sys.stderr)
-        return 2
-    except ValueError as exc:
-        print(f"shadow_tpu: {exc}", file=sys.stderr)
-        return 2
-    if args.show_config:
-        import dataclasses
-
-        def enc(o):
-            if dataclasses.is_dataclass(o):
-                return dataclasses.asdict(o)
-            return str(o)
-
-        print(json.dumps(
-            {
-                "general": dataclasses.asdict(cfg.general),
-                "network": cfg.network,
-                "experimental": dataclasses.asdict(cfg.experimental),
-                "hosts": [dataclasses.asdict(h) for h in cfg.hosts],
-                **({"faults": dataclasses.asdict(cfg.faults)}
-                   if cfg.faults is not None else {}),
-                **({"telemetry": dataclasses.asdict(cfg.telemetry)}
-                   if cfg.telemetry is not None else {}),
-            },
-            indent=2, default=enc,
-        ))
-        return 0
 
     if cfg.general.supervise is not None:
         # self-healing path (shadow_tpu/supervise.py): wraps the sharded or
@@ -262,6 +221,65 @@ def main(argv=None) -> int:
             print(f"shadow_tpu: {exc}", file=sys.stderr)
             return 2
         result = controller.run()
+    return result
+
+
+def main(argv=None) -> int:
+    if argv is None:
+        argv = sys.argv[1:]
+    if argv and argv[0] == "fork":
+        # first-class fork verb: `python -m shadow_tpu fork cfg.yaml
+        # --from CKPT --branches branches.yaml` — checkpoint-forked
+        # what-if trees (shadow_tpu/forks.py; also reachable as
+        # `python -m shadow_tpu.fleet sweep --fork-from`)
+        from shadow_tpu.forks import main as _fork_main
+
+        return _fork_main(argv[1:])
+    args = build_parser().parse_args(argv)
+    from shadow_tpu.config import load_config
+
+    try:
+        cfg = load_config(args.config, overrides_from_args(args))
+    except FileNotFoundError:
+        print(f"shadow_tpu: config file not found: {args.config}", file=sys.stderr)
+        return 2
+    except ValueError as exc:
+        print(f"shadow_tpu: {exc}", file=sys.stderr)
+        return 2
+    if args.show_config:
+        import dataclasses
+
+        def enc(o):
+            if dataclasses.is_dataclass(o):
+                return dataclasses.asdict(o)
+            return str(o)
+
+        print(json.dumps(
+            {
+                "general": dataclasses.asdict(cfg.general),
+                "network": cfg.network,
+                "experimental": dataclasses.asdict(cfg.experimental),
+                "hosts": [dataclasses.asdict(h) for h in cfg.hosts],
+                **({"faults": dataclasses.asdict(cfg.faults)}
+                   if cfg.faults is not None else {}),
+                **({"telemetry": dataclasses.asdict(cfg.telemetry)}
+                   if cfg.telemetry is not None else {}),
+            },
+            indent=2, default=enc,
+        ))
+        return 0
+
+    from shadow_tpu.network.devroute import DataPlaneError
+
+    try:
+        result = _run(cfg, args)
+    except DataPlaneError as exc:
+        # a device policy whose plane could not come up fails the run —
+        # it never finishes on a host twin in silence
+        print(f"shadow_tpu: {exc}", file=sys.stderr)
+        return 1
+    if isinstance(result, int):
+        return result  # a clean one-line refusal, already printed
     if args.json_summary:
         print(json.dumps(result))
     if result.get("exit_reason") == "interrupted":

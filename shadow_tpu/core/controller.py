@@ -793,6 +793,10 @@ class Controller:
                         self._active.discard(h.id)
             self._events_wall += _walltime.perf_counter() - t_ev
             self.engine.end_of_round(now, round_end)
+            if self.engine.device_error is not None:
+                # a background device attach failed: the run fails here
+                # rather than finishing on the host twin
+                self.engine.raise_device_error()
             if devt is not None:
                 executed += devt.take_executed()
             self.rounds += 1
@@ -1036,9 +1040,8 @@ class Controller:
         for h in self.hosts:  # merge AFTER reaping so its counters land
             h.fold_counters()
             self.counters.merge(h.counters)
-        close = getattr(self.engine, "close", None)
-        if close is not None:
-            close()  # join the device-init thread before teardown
+        self.engine.close()  # join the device-init thread before teardown
+        self.engine.raise_device_error()  # an attach that failed late
         sim_sec = end_time / NS_PER_SEC
         rate = sim_sec / self.wall_seconds if self.wall_seconds > 0 else float("inf")
         self.log.info(

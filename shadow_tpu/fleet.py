@@ -27,8 +27,10 @@ config.yaml --seeds 10 --jobs M``) packs M concurrent seeded simulations:
   via ``SHADOW_TPU_DRAW_SERVICE``). The draw kernels take the threefry
   key as *data*, so every member seed shares the same compiled programs.
   Routing is wall-clock policy: the proxy's results are bit-identical to
-  the in-process twins, and any transport failure falls back to the
-  local numpy twin — a dead server can never change results.
+  the in-process twins. A member never attaches the chip itself (the
+  parent holds it): with the service unreachable or lost it runs the
+  local numpy twin and its ``fleet_manifest.json`` records that
+  (``draw_service``); a parent whose attach failed fails the sweep.
 - Per-seed isolation: each seed runs with
   ``data_directory = <sweep_dir>/seed_<s>`` — its host log tree, flow and
   metric streams, and digest stream land there, byte-identical to the
@@ -183,9 +185,12 @@ class FleetDrawClient:
     name = "fleet"
 
     def __init__(self, conn, seed: int, dev_s: float, np_per_unit: float,
-                 spec_bucket: int, max_batch: int, max_pkts: int) -> None:
+                 spec_bucket: int, max_batch: int, max_pkts: int,
+                 device_info: dict = None) -> None:
         self._conn = conn
         self.seed = int(seed)
+        #: the server's device identity (the member's summary reports it)
+        self.device_info = device_info
         self.dev_s = dev_s
         self.np_per_unit = np_per_unit
         self.SPEC_BUCKET = spec_bucket
@@ -243,7 +248,7 @@ class FleetDrawClient:
                     raise TimeoutError(
                         f"fleet draw service at {address!r}: no hello "
                         f"reply within {timeout}s (attach stuck?)")
-            op, dev_s, np_per_unit, spec_bucket, srv_max_batch = \
+            op, dev_s, np_per_unit, spec_bucket, srv_max_batch, info = \
                 conn.recv()
         except BaseException:
             conn.close()
@@ -252,7 +257,8 @@ class FleetDrawClient:
             conn.close()
             raise RuntimeError(f"draw service refused: {op!r}")
         return cls(conn, seed, dev_s, np_per_unit, spec_bucket,
-                   min(int(max_batch), int(srv_max_batch)), max_pkts)
+                   min(int(max_batch), int(srv_max_batch)), max_pkts,
+                   info)
 
     # -- plane interface (devroute window machinery) -----------------------
     def dispatch(self, uid_lo, uid_hi, npkts, thresh):
@@ -531,6 +537,11 @@ def _run_one_seed(config_path: str, overrides: dict, sweep_dir,
         "streams_sha256": _stream_digests(d),
         "summary": summary,
     }
+    svc = result.get("device", {}).get("draw_service")
+    if svc is not None:
+        # how this member reached the parent's shared device plane; not
+        # "connected" means the numpy twin served its draws
+        man["draw_service"] = svc
     _write_json(d / SEED_MANIFEST, man)
     return man
 
@@ -574,8 +585,9 @@ def _fleet_worker_main(conn, config_path: str, overrides: dict,
             os.sched_setaffinity(0, {worker_idx % ncpu})
         except (AttributeError, OSError):
             pass  # pinning is a locality hint, never a requirement
-    if service_addr:
-        os.environ[SERVICE_ENV] = str(service_addr)
+    # a member never attaches the chip itself: it reaches the parent's
+    # shared plane, or (no service) runs the twins — "off" tells devroute
+    os.environ[SERVICE_ENV] = str(service_addr) if service_addr else "off"
     seeds_run = 0
     while True:
         try:
@@ -683,6 +695,7 @@ class FleetRunner:
         self.device_service = bool(device_service)
         self.quiet = bool(quiet)
         self._server = None
+        self._server_error = None  # DrawServer construction failure
         self._procs: list = []
         self._conns: list = []
         self._inflight: dict = {}  # worker idx -> seed
@@ -878,8 +891,10 @@ class FleetRunner:
                                 cfg0.experimental.unit_mtus,
                                 address=self._service_addr)
                         except Exception as exc:
-                            self._log(f"draw service unavailable "
-                                      f"({exc}); members attach locally")
+                            # members run the twin (a member never
+                            # attaches the chip); the sweep fails with it
+                            self._server_error = exc
+                            self._log(f"draw service failed: {exc}")
 
                     server_thread = threading.Thread(
                         target=_build_server, name="fleet-draw-server",
@@ -929,6 +944,12 @@ class FleetRunner:
             "served_units": self._server.served_units,
             "attach_wall_seconds": round(self._server.attach_wall, 3),
         }} if self._server is not None else {})
+        err = self._server_error or getattr(self._server, "error", None)
+        if err is not None:
+            # the shared plane never came up: reported, and the sweep's
+            # exit status says so (sweep_exit_code)
+            service_doc.setdefault("draw_service", {})["error"] = (
+                f"{type(err).__name__}: {err}")
         if self.fork is not None:
             from shadow_tpu import forks as _forks
 
@@ -1503,6 +1524,13 @@ def _is_fork_dir(d) -> bool:
             or any(sorted(d.glob("branch_*/" + _forks.FORK_MANIFEST))))
 
 
+def sweep_exit_code(summary: dict) -> int:
+    """0 when every member succeeded and the shared draw service (if
+    any) came up; 1 otherwise."""
+    bad = summary["failed"] or summary.get("draw_service", {}).get("error")
+    return 1 if bad else 0
+
+
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     if args.cmd == "report":
@@ -1513,7 +1541,7 @@ def main(argv=None) -> int:
             print(json.dumps(summary) if args.json
                   else _forks.render_compare(summary) if args.compare
                   else _forks.render_fork_report(summary))
-            return 0 if not summary["failed"] else 1
+            return sweep_exit_code(summary)
         if args.compare:
             print(f"fleet: {args.sweep_dir} is a seed sweep, not a fork "
                   f"— --compare diffs fork branches against their trunk",
@@ -1522,7 +1550,7 @@ def main(argv=None) -> int:
         summary = reduce_sweep(args.sweep_dir)
         print(json.dumps(summary) if args.json
               else render_report(summary))
-        return 0 if not summary["failed"] else 1
+        return sweep_exit_code(summary)
     try:
         fork_plan = None
         if args.fork_from or args.branches:
@@ -1584,7 +1612,7 @@ def main(argv=None) -> int:
     if summary.get("exit_reason") == "interrupted":
         return 130  # conventional SIGINT status; the summary above is a
         # valid partial artifact and --resume finishes the sweep
-    return 0 if not summary["failed"] else 1
+    return sweep_exit_code(summary)
 
 
 if __name__ == "__main__":

@@ -72,7 +72,8 @@ from shadow_tpu.network.fluid import (
     clamped_refill,
     loss_flags,
 )
-from shadow_tpu.network.devroute import WINDOW_SLOTS, DeviceRoutedPlane
+from shadow_tpu.network.devroute import (WINDOW_SLOTS, DataPlaneError,
+                                         DeviceRoutedPlane)
 from shadow_tpu.network.graph import INF_I64, NetworkGraph
 
 # egress row field indices (tuples appended by Host.emit_msg)
@@ -187,8 +188,10 @@ class ColumnarPlane(DeviceRoutedPlane):
         self._init_device_routing(backend, tpu_options, params)
         #: C engine (native/colcore/colcore.c): same structures, C hot
         #: loops. Bit-identical to this file's Python paths (enforced by
-        #: tests/test_colcore.py + the cross-plane suite); absent or
-        #: disabled, everything below runs pure Python.
+        #: tests/test_colcore.py + the cross-plane suite); disabled
+        #: (experimental.native_colcore: false) or under a non-fifo qdisc,
+        #: everything below runs pure Python. Asked for but not built is
+        #: an error (attach_colcore).
         self._c = None
         self.attach_colcore(tpu_options)
         #: device-resident columnar transport (network/devtransport.py):
@@ -213,8 +216,11 @@ class ColumnarPlane(DeviceRoutedPlane):
             return None
         try:
             from shadow_tpu.native import _colcore
-        except ImportError:
-            return None
+        except ImportError as exc:
+            raise DataPlaneError(
+                f"experimental.native_colcore is on but the C engine is "
+                f"not built ({exc}); run `make -C native`, or set "
+                f"experimental.native_colcore: false") from exc
         for i, b in enumerate(self.pending):
             if isinstance(b, StoreBatch):
                 cb = _colcore.shell("CBatch")

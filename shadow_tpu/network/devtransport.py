@@ -232,7 +232,11 @@ class DeviceTransport:
         self._bg.start()
 
     def _bg_attach(self) -> None:
-        self._devk = TK.DeviceAckKernel.attach()  # None when unusable
+        try:
+            self._devk = TK.DeviceAckKernel.attach()
+        except Exception as exc:
+            # the plane's barrier check raises it on the main thread
+            self.plane.device_error = exc
 
     def close(self) -> None:
         t = self._bg

@@ -148,8 +148,8 @@ class DeviceAckKernel:
     routing between them is pure wall-clock policy — the devroute
     argument, applied to transport arithmetic.
 
-    attach() returns None when jax/x64 is unavailable; callers fall back
-    to the numpy twin (never an error, never a result change)."""
+    attach() raises when the device path cannot be brought up; the
+    caller reports it (network/devtransport.py), it never falls back."""
 
     def __init__(self, jax, jnp) -> None:
         self._jax = jax
@@ -158,19 +158,16 @@ class DeviceAckKernel:
 
     @classmethod
     def attach(cls):
-        try:
-            from shadow_tpu.ops.jaxcfg import configure
+        from shadow_tpu.ops.jaxcfg import configure
 
-            configure()
-            import jax
-            import jax.numpy as jnp
+        configure()
+        import jax
+        import jax.numpy as jnp
 
-            jax.config.update("jax_enable_x64", True)
-            k = cls(jax, jnp)
-            k.run(*[np.zeros(2, dtype=np.int64) for _ in range(8)])
-            return k
-        except Exception:
-            return None  # no usable device path: numpy serves everything
+        jax.config.update("jax_enable_x64", True)
+        k = cls(jax, jnp)
+        k.run(*[np.zeros(2, dtype=np.int64) for _ in range(8)])
+        return k
 
     def _fn(self, n: int):
         fn = self._fns.get(n)

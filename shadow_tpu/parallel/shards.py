@@ -1695,6 +1695,9 @@ class ShardedRun:
             "process_errors": error_strs,
             "phase_wall": phase,
             "device_windows_dispatched": 0,
+            # shard workers run with device draws off (tpu_device_floor
+            # -1, _worker_main): N workers cannot share one chip
+            "device": {"draws": "off in shard workers"},
             **({"fault_transitions_applied":
                 finals[0]["fault_transitions_applied"]}
                if finals[0]["fault_transitions_applied"] is not None

@@ -19,14 +19,7 @@ import numpy as np
 from shadow_tpu.config.schema import load_config
 from shadow_tpu.core.controller import Controller
 
-import pathlib
-import subprocess
-
-subprocess.run(
-    ["make", "-C", str(pathlib.Path(__file__).resolve().parent.parent
-                       / "native")],
-    check=True, capture_output=True)
-from shadow_tpu.native import _colcore  # noqa: E402
+from shadow_tpu.native import _colcore  # built by conftest.py
 
 from shadow_tpu.core.controller import VOLATILE_SUMMARY_KEYS as VOLATILE
 

@@ -414,8 +414,9 @@ def test_fleet_sweep_interrupt_partial_summary(tmp_path):
 @pytest.mark.slow
 def test_draw_service_round_trip_and_fallback():
     """The shared draw service serves bit-identical flags and min-draws
-    for arbitrary member seeds from ONE attach, and a closed server
-    degrades the proxy to the local twin — same results, no error."""
+    for arbitrary member seeds from ONE attach, and a server lost mid-run
+    leaves the proxy on the local twin — same results, and the proxy
+    marks itself dead so the member's manifest reports it."""
     from shadow_tpu.network.fluid import MAX_PKTS, loss_flags
     from shadow_tpu.ops.propagate import DrawServer
 
@@ -431,6 +432,7 @@ def test_draw_service_round_trip_and_fallback():
         for member_seed in (123, 9999):  # incl. a seed != the attach seed
             cl = fleet.FleetDrawClient.connect(
                 server.address, member_seed, 4096, MAX_PKTS, timeout=120)
+            assert cl.device_info["platform"] == "cpu"
             flags = cl.dispatch(lo, hi, npk, th).read()
             assert (flags == loss_flags(member_seed, lo, hi, npk,
                                         th)).all()
@@ -448,5 +450,6 @@ def test_draw_service_round_trip_and_fallback():
         mins = cl.dispatch_min(lo, hi, npk).read()
         assert (mins == fleet._min_draw_np(42, lo, hi, npk,
                                            MAX_PKTS)).all()
+        assert cl._dead  # reported: "lost mid-run" in the summary
     finally:
         server.close()

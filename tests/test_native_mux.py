@@ -18,12 +18,6 @@ ROOT = Path(__file__).resolve().parents[1]
 BUILD = ROOT / "native" / "build"
 
 
-@pytest.fixture(scope="module", autouse=True)
-def build_native():
-    subprocess.run(["make", "-C", str(ROOT / "native")], check=True,
-                   capture_output=True)
-
-
 @pytest.mark.parametrize("mode", ["poll", "epoll"])
 def test_mux_srv_native_oracle(mode):
     import random

@@ -68,12 +68,6 @@ pytestmark = pytest.mark.skipif(
            + ", ".join(map(str, _MISSING_CAPS)))
 
 
-@pytest.fixture(scope="module", autouse=True)
-def build_native():
-    subprocess.run(["make", "-C", str(ROOT / "native")], check=True,
-                   capture_output=True)
-
-
 # ---- native oracle runs ---------------------------------------------------
 
 def test_sleep_clock_native():

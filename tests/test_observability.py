@@ -141,8 +141,6 @@ def test_all_knobs_implemented_no_warnings():
 
 
 def test_strace_logging_managed_process():
-    subprocess.run(["make", "-C", str(ROOT / "native")], check=True,
-                   capture_output=True)
     cfg_text = f"""
 general:
   stop_time: 6s

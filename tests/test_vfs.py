@@ -23,12 +23,6 @@ ROOT = Path(__file__).resolve().parents[1]
 BUILD = ROOT / "native" / "build"
 
 
-@pytest.fixture(scope="module", autouse=True)
-def build_native():
-    subprocess.run(["make", "-C", str(ROOT / "native")], check=True,
-                   capture_output=True)
-
-
 def _serve_native(srv, count):
     for _ in range(count):
         conn, _a = srv.accept()

@@ -21,10 +21,9 @@ ASAN_LIB=$(gcc -print-file-name=libasan.so)
 # jax throws C++ exceptions in normal operation; ASan's __cxa_throw
 # interceptor needs libstdc++ resolvable at preload time
 STDCXX_LIB=$(gcc -print-file-name=libstdc++.so.6)
-# the loader override + colplane attach both swallow ImportError into a
-# silent Python-plane fallback — probe the sanitized extension imports
-# under the exact smoke environment, so the gate can never "pass" while
-# sanitizing nothing
+# probe that the sanitized extension is the one that imports under the
+# exact smoke environment, so the gate can never "pass" while sanitizing
+# nothing (a missing _colcore fails a device-policy run outright)
 LD_PRELOAD="$ASAN_LIB $STDCXX_LIB" \
 ASAN_OPTIONS=detect_leaks=0 \
 SHADOW_TPU_COLCORE_SO=native/build/asan/_colcore.so \

@@ -7,7 +7,8 @@ tgen bulk transfer and the 10k-node gossip flood). Run from the repo root:
 
 The outputs (examples/tgen_1k.yaml, examples/gossip_10k.yaml) are committed
 so benchmarks are reproducible without re-running this script; regenerate
-only when deliberately changing the benchmark shape.
+only when deliberately changing the benchmark shape. ``tor_doc`` builds
+config #5 (Tor) in memory at any scale, for bench.py and chip_smoke.py.
 """
 
 from __future__ import annotations
@@ -98,6 +99,46 @@ def tornet_gml(rng, g, path) -> None:
     lines.append("]")
     with open(path, "w") as fh:
         fh.write("\n".join(lines) + "\n")
+
+
+def tor_doc(n_relays: int, n_clients: int, stop_s: int,
+             fetch: str = "20 kB") -> dict:
+    """Config #5 generator (BASELINE.md): onion-routing at tornettools
+    shape — TorRelay/TorExit relays, TGen web servers, TorClients
+    building 3-hop circuits and fetching through them, on a 64-node
+    random graph. Deterministic from the fixed seed. ``tor_doc(700,
+    10_000, 8)`` is "tor 1/10": 10,720 hosts, the chip_smoke.py
+    workload."""
+    rng = np.random.default_rng(42)
+    g = 64
+    gml = random_gml(rng, g, min_lat_ms=10, max_lat_ms=120, max_loss=0.002,
+                     bw_choices=("50 Mbit", "100 Mbit", "1 Gbit"))
+    hosts = {}
+    n_exits = max(1, n_relays // 8)  # exits FIRST: clients draw their last hop
+    # from relay0..relay{n_exits-1} (TorClient's n_exits arg)
+    for i in range(n_relays):
+        cls = "TorExit" if i < n_exits else "TorRelay"
+        hosts[f"relay{i}"] = {
+            "network_node_id": int(rng.integers(0, g)),
+            "processes": [{"path": f"pyapp:shadow_tpu.models.tor:{cls}",
+                           "args": ["9001"]}]}
+    for i in range(20):
+        hosts[f"web{i}"] = {
+            "network_node_id": int(rng.integers(0, g)),
+            "processes": [{"path": "pyapp:shadow_tpu.models.tgen:TGenServer",
+                           "args": ["80"]}]}
+    per = n_clients // g
+    for i in range(g):
+        q = per + (n_clients - per * g if i == g - 1 else 0)
+        hosts[f"u{i}_"] = {
+            "network_node_id": i, "quantity": q,
+            "processes": [{"path": "pyapp:shadow_tpu.models.tor:TorClient",
+                           "args": [str(n_relays), "9001", f"web{i % 20}",
+                                    "80", fetch, "1", str(n_exits)],
+                           "start_time": f"{2000 + i * 150} ms"}]}
+    return {"general": {"stop_time": f"{stop_s}s", "seed": 6},
+            "network": {"graph": {"type": "gml", "inline": gml}},
+            "hosts": hosts}
 
 
 def gen_tgen_1k(path="examples/tgen_1k.yaml"):
