@@ -1,0 +1,8 @@
+"""device: 1 - (union of device-op intervals / traced window)."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if tr is None or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
