@@ -4,9 +4,10 @@
 #   make test     - full pytest suite (CPU JAX, 8 virtual devices)
 #   make ci       - the full gate: native build, tests, multichip dry run,
 #                   and the 1k-host twice-run determinism check
-#   make bench    - the benchmark harness (one JSON line on stdout)
+#
+# The benchmark is benchmark/run.py (BENCHMARK.json), run on a TPU host.
 
-.PHONY: native test ci bench clean
+.PHONY: native test ci clean
 
 native:
 	$(MAKE) -C native
@@ -16,9 +17,6 @@ test: native
 
 ci: native
 	bash tools/ci.sh
-
-bench:
-	python bench.py
 
 clean:
 	$(MAKE) -C native clean

@@ -52,21 +52,15 @@
 #define Py_READONLY READONLY
 #endif
 
-static int64_t tm_sect[12];
-static int64_t tm_cnt[12];
-#ifdef COLCORE_TIMERS
 #include <time.h>
+
+/* the clock of the section timers (Python's perf_counter reads the same
+ * CLOCK_MONOTONIC) */
 static inline int64_t nsnow(void) {
   struct timespec ts;
   clock_gettime(CLOCK_MONOTONIC, &ts);
   return ts.tv_sec * 1000000000LL + ts.tv_nsec;
 }
-#define TM0(i) int64_t _t##i = nsnow()
-#define TM1(i) do { tm_sect[i] += nsnow() - _t##i; tm_cnt[i]++; } while (0)
-#else
-#define TM0(i) do {} while (0)
-#define TM1(i) do {} while (0)
-#endif
 
 #define NS_PER_SEC 1000000000LL
 #define MTU 1500
@@ -403,6 +397,16 @@ typedef struct {
   struct SpecHost *spec;
   int spec_on;
   int64_t spec_hits, spec_draws; /* drained by Core_spec_stats */
+  /* section timers and counts, drained by Core_fold_counters into the
+   * run's spans (core/spans.py): Python callables run_round invokes
+   * (outermost call only: py_depth), the barrier's hand-off of a batch
+   * to the window machinery, and counts of the per-unit sections */
+  int64_t py_ns, py_calls;
+  int py_depth;
+  int64_t handoff_ns, handoff_calls, handoff_units;
+  int64_t n_emit, n_gossip;
+  /* where the C barrier's sent units were drawn (devroute.ROUTES) */
+  int64_t route_inline, route_spec_hit, route_none;
   int32_t *spec_dq; /* demand queue: host ids awaiting a window */
   int spec_dq_n, spec_dq_cap;
   /* cached sorted snapshot of the active set (run_round's iteration
@@ -420,6 +424,18 @@ typedef struct {
   int64_t *act_pend;
   int64_t act_pend_n, act_pend_cap;
 } CoreObject;
+
+/* time the Python call a pair of these brackets (the outermost one, when
+ * a callable reaches back into another) */
+static inline int64_t py_enter(CoreObject *c) {
+  return c->py_depth++ ? 0 : nsnow();
+}
+static inline void py_leave(CoreObject *c, int64_t t0) {
+  if (--c->py_depth == 0) {
+    c->py_ns += nsnow() - t0;
+    c->py_calls++;
+  }
+}
 
 /* per-host speculative window + npkts class tracker. Two generations:
  * the live window [u0, u0+n) plus a staged continuation [nu0, nu0+nn)
@@ -661,17 +677,6 @@ static PyObject *heap_head(CHost *h, int *err) {
 }
 
 /* ---- emission (C gossip sendto -> egress row tuple) ------------------- */
-static int core_emit_dgram_inner(CoreObject *c, CHost *h, int64_t now,
-                           int dst, GossipState *g, int dst_port,
-                           int64_t nbytes, PyObject *payload);
-static int core_emit_dgram(CoreObject *c, CHost *h, int64_t now, int dst,
-                           GossipState *g, int dst_port, int64_t nbytes,
-                           PyObject *payload) {
-  TM0(3);
-  int r = core_emit_dgram_inner(c, h, now, dst, g, dst_port, nbytes, payload);
-  TM1(3);
-  return r;
-}
 /* packed emission core: append one ERow to the host's C egress buffer.
  * Mirrors Host.emit_msg's columnar branch without the tuple; payload is
  * INCREF'd (NULL/None accepted). */
@@ -711,9 +716,10 @@ static int core_emit_fields(CoreObject *c, CHost *h, int64_t now,
   return 0;
 }
 
-static int core_emit_dgram_inner(CoreObject *c, CHost *h, int64_t now,
-                           int dst, GossipState *g, int dst_port,
-                           int64_t nbytes, PyObject *payload) {
+static int core_emit_dgram(CoreObject *c, CHost *h, int64_t now, int dst,
+                           GossipState *g, int dst_port, int64_t nbytes,
+                           PyObject *payload) {
+  c->n_emit++;
   if (core_emit_fields(c, h, now, KIND_DGRAM, dst, nbytes + HEADER, nbytes,
                        payload, g->next_dgram++, g->port, dst_port, 0,
                        1) < 0)
@@ -869,7 +875,6 @@ static int dispatch_c(CoreObject *c, CHost *h, int hid, IRow *ir,
       if (h->port[i] == (int)ir->bport) { g = h->gs[i]; break; }
   }
   if (!g) {
-    TM0(1);
     /* Python fallback: unregistered ports, frags. host.dispatch_row
      * does its own clock/bucket/deliver work (13-tuple materialized
      * here — the fallback is off the hot path by construction). */
@@ -879,13 +884,14 @@ static int dispatch_c(CoreObject *c, CHost *h, int hid, IRow *ir,
     }
     PyObject *row = irow_tuple(h, ir, hid);
     if (!row) return -1;
+    int64_t t0 = py_enter(c);
     PyObject *r = PyObject_CallMethodObjArgs(h->host, S_dispatch_row,
                                              row, NULL);
+    py_leave(c, t0);
     Py_DECREF(row);
     if (!r) return -1;
     Py_DECREF(r);
     if (attr_i64(h->host, S_now, now) < 0) return -1;
-    TM1(1);
     return 0;
   }
   if (t > *now) { *now = t; *now_dirty = 1; }
@@ -909,22 +915,13 @@ static int dispatch_c(CoreObject *c, CHost *h, int hid, IRow *ir,
   }
   h->d_delivered++;
   h->d_dgrams_recv++;
-  TM0(2);
-  int rr = gossip_on_msg_c(c, h, g, *now,
-                           ir->payload ? ir->payload : Py_None, ir->peer);
-  TM1(2);
-  return rr;
+  c->n_gossip++;
+  return gossip_on_msg_c(c, h, g, *now,
+                         ir->payload ? ir->payload : Py_None, ir->peer);
 }
 
 /* ---- Host.run_events twin over the C inbox ---------------------------- */
-static int64_t run_host_inner(CoreObject *c, CHost *h, int hid, int64_t end);
 static int64_t run_host_c(CoreObject *c, CHost *h, int hid, int64_t end) {
-  TM0(4);
-  int64_t r = run_host_inner(c, h, hid, end);
-  TM1(4);
-  return r;
-}
-static int64_t run_host_inner(CoreObject *c, CHost *h, int hid, int64_t end) {
   /* no entry clock read: inbox rows satisfy t >= host._now (rows are
    * extracted with t >= round_start and the clock never passes a round
    * boundary), heap tasks write the attr themselves, and the Python
@@ -1003,7 +1000,9 @@ static int64_t run_host_inner(CoreObject *c, CHost *h, int hid, int64_t end) {
         now = tup_i64(ev, 0);
         now_dirty = 0;
         if (attr_set_i64(h->host, S_now, now) < 0) { Py_DECREF(ev); goto mdone; }
+        int64_t t0 = py_enter(c);
         PyObject *res = PyObject_CallNoArgs(PyTuple_GET_ITEM(ev, 4));
+        py_leave(c, t0);
         Py_DECREF(ev);
         if (!res) goto mdone;
         Py_DECREF(res);
@@ -1020,12 +1019,10 @@ static int64_t run_host_inner(CoreObject *c, CHost *h, int hid, int64_t end) {
   }
   err = 0;
 done:;
-  TM0(10);
   /* release the consumed prefix AND any unconsumed tail (error paths) */
   for (int i = 0; i < h->inbox_n; i++) Py_XDECREF(h->inbox[i].payload);
   h->inbox_n = 0;
   h->inbox_multi = 0;
-  TM1(10);
   if (err) return -1;
   if (now_dirty && attr_set_i64(h->host, S_now, now) < 0) return -1;
   /* counter deltas stay C-side until Core.fold_counters (plane.flush_all):
@@ -1760,6 +1757,9 @@ static PyObject *Core_barrier(CoreObject *c, PyObject *args) {
         if (floor_d == -1.0 && PyErr_Occurred()) goto done;
       }
       if (mesh_off || (double)keep >= floor_d) {
+        int64_t t_handoff = nsnow();
+        c->handoff_calls++;
+        c->handoff_units += keep;
         npy_intp dims[1] = {keep};
         PyObject *rows_l = PyList_New(keep);
         PyObject *src_l = PyList_New(keep);
@@ -1833,6 +1833,7 @@ static PyObject *Core_barrier(CoreObject *c, PyObject *args) {
         else
           result = Py_BuildValue("(NNNNNNNN)", rows_l, src_l, arr_t,
                                  keys_l, arr_lo, arr_hi, arr_npk, arr_th);
+        c->handoff_ns += nsnow() - t_handoff;
         if (!result) goto done;
         goto done; /* row refs now held by rows_l */
       }
@@ -1848,17 +1849,22 @@ static PyObject *Core_barrier(CoreObject *c, PyObject *args) {
       BRow *b = &c->brow[i];
       if (!b->th) {
         b->drop = 0;
+        c->route_none++;
         continue;
       }
       int sv = c->spec_on ? spec_consult(c, b) : -1;
       if (sv >= 0) {
         b->drop = (uint8_t)sv;
         c->spec_hits++;
+        c->route_spec_hit += !sv;
       } else {
         b->drop = (uint8_t)unit_dropped(c->seed, b->uid, b->npk, b->th);
         c->spec_draws += c->spec_on;
+        c->route_inline += !b->drop;
       }
     }
+  } else {
+    c->route_none += keep;
   }
   if (store_build(c, c->brow, keep, any_live, round_end) < 0) goto done;
   result = Py_True;
@@ -2519,6 +2525,9 @@ static PyObject *Core_gossip_register(CoreObject *c, PyObject *args) {
   return (PyObject *)g;
 }
 
+/* fold the per-host counter deltas into the hosts, and drain the section
+ * timers, counts and barrier routes (returned; plane.flush_all folds them
+ * into the run's spans and device summary) */
 static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
   (void)noarg;
   for (int64_t i = 0; i < c->H; i++) {
@@ -2563,7 +2572,25 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
     }
     Py_XDECREF(ctrs);
   }
-  Py_RETURN_NONE;
+  PyObject *out = Py_BuildValue(
+      "{s{s(dL)s(dL)}s{sLsL}s{sLsLsL}sL}",
+      "timers",
+      "events.py_callback", c->py_ns / 1e9, (long long)c->py_calls,
+      "barrier.handoff", c->handoff_ns / 1e9, (long long)c->handoff_calls,
+      "counts",
+      "events.emit", (long long)c->n_emit,
+      "events.gossip", (long long)c->n_gossip,
+      "routes",
+      "inline", (long long)c->route_inline,
+      "spec_hit", (long long)c->route_spec_hit,
+      "none", (long long)c->route_none,
+      "handoff_units", (long long)c->handoff_units);
+  if (!out) return NULL;
+  c->py_ns = c->py_calls = 0;
+  c->handoff_ns = c->handoff_calls = c->handoff_units = 0;
+  c->n_emit = c->n_gossip = 0;
+  c->route_inline = c->route_spec_hit = c->route_none = 0;
+  return out;
 }
 
 static PyObject *Core_make_endpoint(CoreObject *c, PyObject *args);
@@ -2854,7 +2881,8 @@ static PyMethodDef Core_methods[] = {
     {"spec_stats", (PyCFunction)Core_spec_stats, METH_NOARGS,
      "drain (speculative hits, inline draws since speculation enabled)"},
     {"fold_counters", (PyCFunction)Core_fold_counters, METH_NOARGS,
-     "flush outstanding per-host counter deltas into host attributes"},
+     "flush outstanding per-host counter deltas into host attributes; "
+     "drain and return the section timers, counts and barrier routes"},
     {"make_endpoint", (PyCFunction)Core_make_endpoint, METH_VARARGS,
      "(hid, lport, rhost, rport, initiator, sbuf, rbuf) -> Endpoint"},
     {"relay_new", (PyCFunction)Core_relay_new, METH_VARARGS,
@@ -4575,10 +4603,7 @@ static PyObject *Core_run_round(CoreObject *c, PyObject *args) {
    * set-size match proves the cache is exact and the per-round
    * iterate + qsort — the dominant cost at 10k mostly-parked hosts —
    * is skipped. */
-  TM0(6);
   if (act_refresh(c) < 0) return NULL;
-  TM1(6);
-  tm_cnt[7] += c->act_n;
   int64_t executed = 0;
   int64_t *ids = c->act_ids;
   int64_t k = c->act_n;
@@ -4615,8 +4640,10 @@ static PyObject *Core_run_round(CoreObject *c, PyObject *args) {
         ids[w++] = hid;
         continue;
       }
+      int64_t t0 = py_enter(c);
       PyObject *r = PyObject_CallMethodObjArgs(
           h->host, S_run_events, PyTuple_GET_ITEM(args, 0), NULL);
+      py_leave(c, t0);
       if (!r) goto fail;
       executed += PyLong_AsLongLong(r);
       Py_DECREF(r);
@@ -6687,23 +6714,6 @@ static PyObject *mod_unit_dropped(PyObject *self, PyObject *args) {
   return PyBool_FromLong(unit_dropped(seed, uid, npk, th));
 }
 
-static PyObject *mod_perf_dump(PyObject *self, PyObject *noarg) {
-  (void)self; (void)noarg;
-  PyObject *d = PyDict_New();
-  const char *names[12] = {"_", "py_fallback", "gossip", "emit", "run_host",
-                           "ctr_flush", "snapshot", "active_total",
-                           "now_entry", "dispatch", "inbox_free", ""};
-  for (int i = 0; i < 12; i++) {
-    if (!tm_cnt[i] && !tm_sect[i]) continue;
-    PyObject *v = Py_BuildValue("(dL)", tm_sect[i] / 1e9,
-                                (long long)tm_cnt[i]);
-    PyDict_SetItemString(d, names[i], v);
-    Py_DECREF(v);
-    tm_sect[i] = tm_cnt[i] = 0;
-  }
-  return d;
-}
-
 /* parse one packed cross-shard row block (parallel/shards.py wire
  * format: [n u64][numeric cols (n, 12) i64][payload lens (n,) i64]
  * [payload blobs]) straight into a CBatch — the packed ingest path that
@@ -6773,7 +6783,6 @@ done:
 static PyMethodDef module_methods[] = {
     {"cbatch_from_packed", mod_cbatch_from_packed, METH_O,
      "packed cross-shard row block (shards.py wire format) -> CBatch"},
-    {"perf_dump", mod_perf_dump, METH_NOARGS, "drain section timers"},
     {"unit_dropped", mod_unit_dropped, METH_VARARGS,
      "(seed, uid, npk, thresh) -> bool  (test hook: fluid.loss_flags twin)"},
     {"shell", mod_shell, METH_O,

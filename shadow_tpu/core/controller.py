@@ -26,12 +26,13 @@ from __future__ import annotations
 
 from functools import partial
 
-import time as _walltime  # detlint: ok(wallclock): phase_wall + heartbeat wall costs
+import time as _walltime  # detlint: ok(wallclock): loop wall + heartbeat wall costs
 from pathlib import Path
 
 import numpy as np
 
 from shadow_tpu.config.schema import ConfigOptions
+from shadow_tpu.core import spans as _spans
 from shadow_tpu.core.scheduler import make_scheduler
 from shadow_tpu.core.time import NS_PER_SEC, NS_PER_US, SimTime, T_NEVER, format_time
 from shadow_tpu.host.host import Host
@@ -56,7 +57,8 @@ _GC_EVERY_ROUNDS = 5000
 #: (parallel/shards.py): which partition executed a simulation is as
 #: immaterial to its results as which windows the device served
 VOLATILE_SUMMARY_KEYS = ("wall_seconds", "sim_sec_per_wall_sec",
-                         "phase_wall", "max_rss_mb", "device",
+                         "phase_wall", "phase_detail", "phase_calls",
+                         "max_rss_mb", "device",
                          "device_windows_dispatched", "sim_shards",
                          "shards", "device_transport",
                          "device_transport_engaged", "supervisor")
@@ -316,8 +318,9 @@ class Controller:
         self.rounds = 0
         self.events = 0
         self.wall_seconds = 0.0
-        self._events_wall = 0.0  # scheduler.run_round wall (phase timing)
-        self._ckpt_wall = 0.0  # save_checkpoint wall (phase timing)
+        #: the span recorder of the current (or last) run(); see
+        #: core/spans.py -- the summary's phase_wall is its partition
+        self.spans = _spans.Recorder(trace=False)
         # checkpoint/restore + determinism sentinel (shadow_tpu/checkpoint.py)
         self.ckpt_every: SimTime = cfg.general.checkpoint_every or 0
         self.ckpt_dir = (Path(cfg.general.checkpoint_dir)
@@ -384,6 +387,7 @@ class Controller:
         d = self.__dict__.copy()
         d["scheduler"] = None
         d["_c_core"] = None
+        d["spans"] = None  # each run() starts its own
         # live plane: the server, inspector hook, and replay cursor are
         # runtime plumbing rebuilt by _init_live from the RESUME
         # invocation's (volatile) config keys
@@ -655,39 +659,46 @@ class Controller:
 
             self._chaos = ChaosInjector.from_env(
                 self.data_dir, shard=self.shard_id, in_process=True)
-        t0 = _walltime.perf_counter()
-        dyn = cfg.experimental.use_dynamic_runahead
-        faults = self.faults
-        try:
-            now = self._round_loop(now, stop, w, dyn, faults, next_hb,
-                                   hb_interval, next_prog, prog_step,
-                                   next_gc, next_ckpt, ck_every, dig,
-                                   _ckpt, tel, t0)
-        finally:
-            for s, old in installed.items():
-                _signal.signal(s, old)
-        self._partial = self._interrupt is not None and now < stop
-        if self._partial:
-            self.log.warning(
-                f"{self._interrupt} received: stopped gracefully at round "
-                f"boundary {format_time(now)} ({self.rounds} rounds); "
-                f"summary is partial")
-            if ck_every:
-                path = _ckpt.save_checkpoint(self, now)
-                self.log.info(f"final checkpoint written: {path}")
-                if self.live is not None:
-                    self.live.publish({"type": "checkpoint",
-                                       "path": str(path), "t": now,
-                                       "round": self.rounds})
-        if gc_was_enabled:
-            _gc.enable()
-        _gc.collect()
-        self.engine.flush_all()  # finalize counters for in-flight batches
-        if cfg.general.progress:
-            import sys as _sys
+        # the spans of this run: phase_wall partitions wall_seconds. A
+        # resumed run starts a fresh recorder, as it starts a fresh t0
+        rec = self.spans = _spans.Recorder()
+        rec.declare(("events", "gc", *getattr(self.engine, "PHASES", ())))
+        with rec.installed():
+            t0 = _walltime.perf_counter()
+            dyn = cfg.experimental.use_dynamic_runahead
+            faults = self.faults
+            try:
+                now = self._round_loop(now, stop, w, dyn, faults, next_hb,
+                                       hb_interval, next_prog, prog_step,
+                                       next_gc, next_ckpt, ck_every, dig,
+                                       _ckpt, tel, t0)
+            finally:
+                for s, old in installed.items():
+                    _signal.signal(s, old)
+            self._partial = self._interrupt is not None and now < stop
+            if self._partial:
+                self.log.warning(
+                    f"{self._interrupt} received: stopped gracefully at "
+                    f"round boundary {format_time(now)} ({self.rounds} "
+                    f"rounds); summary is partial")
+                if ck_every:
+                    with rec.span("checkpoint"):
+                        path = _ckpt.save_checkpoint(self, now)
+                    self.log.info(f"final checkpoint written: {path}")
+                    if self.live is not None:
+                        self.live.publish({"type": "checkpoint",
+                                           "path": str(path), "t": now,
+                                           "round": self.rounds})
+            if gc_was_enabled:
+                _gc.enable()
+            with rec.span("gc"):
+                _gc.collect()
+            self.engine.flush_all()  # finalize counters for in-flight batches
+            if cfg.general.progress:
+                import sys as _sys
 
-            print(file=_sys.stderr)  # end the \r status line
-        self.wall_seconds = _walltime.perf_counter() - t0
+                print(file=_sys.stderr)  # end the \r status line
+            self.wall_seconds = _walltime.perf_counter() - t0
         self.scheduler.shutdown()
         result = self._finalize(min(now, stop))
         if self.live is not None:
@@ -709,6 +720,8 @@ class Controller:
         # back into `executed` so the skip-ahead decision, the events
         # total, and the round grid are identical to the scalar twin's
         devt = getattr(self.engine, "devt", None)
+        span = self.spans.span
+        sp_events = span("events")
         while now < stop:
             if self._chaos is not None:
                 self._chaos.maybe_fire(self.rounds, self)
@@ -744,10 +757,10 @@ class Controller:
                 self._verify_reexec(now)
             if now >= next_ckpt or self._ckpt_now:
                 self._ckpt_now = False
-                t_ck = _walltime.perf_counter()
-                if tel is not None:
-                    tel.sync(self)  # streams complete at the boundary
-                path = _ckpt.save_checkpoint(self, now)
+                with span("checkpoint"):
+                    if tel is not None:
+                        tel.sync(self)  # streams complete at the boundary
+                    path = _ckpt.save_checkpoint(self, now)
                 self.log.info(
                     f"checkpoint written: {path} "
                     f"(sim {format_time(now)}, round {self.rounds})")
@@ -761,11 +774,6 @@ class Controller:
                                        "round": self.rounds})
                 if ck_every:
                     next_ckpt = ((now // ck_every) + 1) * ck_every
-                # snapshot wall is attributed like any other phase: it is
-                # plane-independent (the pickler walks the same graph fast
-                # plane or slow), so naming it keeps the benchmark's
-                # robustness-tax decomposition honest
-                self._ckpt_wall += _walltime.perf_counter() - t_ck
             if faults is not None:
                 # fault transitions apply at round starts: an action at
                 # time t takes effect at the first boundary >= t — the
@@ -780,18 +788,17 @@ class Controller:
             round_end = min(now + w, stop)
             self.engine.start_of_round(now, round_end)
             hosts = self.hosts
-            t_ev = _walltime.perf_counter()
-            if self._c_core is not None:
-                # the C loop snapshots + sorts the active set, merges each
-                # host's inbox/heap, and discards drained hosts itself
-                executed = self._c_core.run_round(round_end)
-            else:
-                active = [hosts[i] for i in sorted(self._active)]
-                executed = self.scheduler.run_round(round_end, active)
-                for h in active:
-                    if not h.equeue._heap:
-                        self._active.discard(h.id)
-            self._events_wall += _walltime.perf_counter() - t_ev
+            with sp_events:
+                if self._c_core is not None:
+                    # the C loop snapshots + sorts the active set, merges
+                    # each host's inbox/heap, and discards drained hosts
+                    executed = self._c_core.run_round(round_end)
+                else:
+                    active = [hosts[i] for i in sorted(self._active)]
+                    executed = self.scheduler.run_round(round_end, active)
+                    for h in active:
+                        if not h.equeue._heap:
+                            self._active.discard(h.id)
             self.engine.end_of_round(now, round_end)
             if self.engine.device_error is not None:
                 # a background device attach failed: the run fails here
@@ -813,7 +820,8 @@ class Controller:
                 # so (the round grid is policy-independent, so the
                 # streams are too). One None check when off; idle rounds
                 # of a telemetry run skip the call entirely.
-                tel.on_round_end(self, round_end)
+                with span("telemetry"):
+                    tel.on_round_end(self, round_end)
             if (self.stop_after_round is not None
                     and self.rounds >= self.stop_after_round):
                 # time-travel inspection (shadow_tpu/live.py jump): halt
@@ -836,7 +844,8 @@ class Controller:
                 next_prog = round_end + prog_step
             if self.rounds >= next_gc:
                 next_gc = self.rounds + _GC_EVERY_ROUNDS
-                _gc.collect()
+                with span("gc"):
+                    _gc.collect()
             if executed == 0 and not self.engine.has_immediate_work():
                 # provably idle: materialize any in-flight draw batch that
                 # could produce an event before the next queued one, then
@@ -1002,11 +1011,8 @@ class Controller:
                 **({"dev": note()} if note is not None else {}),
                 "wall": {
                     "seconds": round(wall, 3), "rate": round(rate, 3),
-                    "phase": {
-                        "events": round(self._events_wall, 4),
-                        **{k: round(v, 4)
-                           for k, v in self.engine.phase_wall.items()},
-                    },
+                    "phase": {k: round(v, 4) for k, v in
+                              self.spans.phase_wall().items()},
                 },
             })
         self.log.info(
@@ -1071,8 +1077,7 @@ class Controller:
             **({"interrupt_signal": self._interrupt}
                if self._partial else {}),
             # linux ru_maxrss is KiB; the process-wide high-water mark, so
-            # it is only per-run when each run owns its process (bench.py's
-            # subprocess rows rely on this)
+            # it is only per-run when each run owns its process
             "max_rss_mb": round(
                 resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 1024, 1),
             "rounds": self.rounds,
@@ -1086,33 +1091,29 @@ class Controller:
             "bytes_sent": self.engine.bytes_sent,
             "counters": self.counters.as_dict(),
             "process_errors": errors,
-            # per-phase wall breakdown (VERDICT r2 item #7): events =
-            # host event execution; the engine contributes its own phases
-            # (columnar plane: barrier / draw_flush / extract / ...)
-            "phase_wall": {
-                "events": round(self._events_wall, 4),
-                **{k: round(v, 4)
-                   for k, v in self.engine.phase_wall.items()},
-                **({"telemetry": round(self.telemetry.wall, 4)}
-                   if self.telemetry is not None else {}),
-                **({"checkpoint": round(self._ckpt_wall, 4)}
-                   if self._ckpt_wall else {}),
-            },
+            # the loop wall by phase (core/spans.py): each phase's self
+            # time, so the phases add up to at most wall_seconds; events =
+            # host event execution, the engine's phases (columnar plane:
+            # barrier / draw_flush / extract / window_* ...), gc,
+            # telemetry and checkpoint where they ran. phase_detail splits
+            # a phase into its parts, phase_calls counts every span.
+            "phase_wall": self.spans.phase_wall(),
+            "phase_detail": self.spans.phase_detail(),
+            "phase_calls": self.spans.phase_calls(),
             # fused device windows (round-5 Weak #5): zero here on a
             # tpu_batch run means the device never serviced a window —
-            # the numpy/C twin carried the whole run. bench.py turns this
-            # into a loud per-config device_engaged verdict. Wall-clock
-            # routing telemetry only: never simulation state, so runs
-            # that differ here still produce identical output trees.
+            # the numpy/C twin carried the whole run (device.routes says
+            # where every sent unit's draw was made). Wall-clock routing
+            # telemetry only: never simulation state, so runs that differ
+            # here still produce identical output trees.
             "device_windows_dispatched": getattr(
                 self.engine, "dev_windows", 0),
             **({"device": self.engine.device_summary()}
                if hasattr(self.engine, "device_summary") else {}),
             # device transport (PR 11): wall-clock routing telemetry for
             # the columnar endpoint ticks; engaged = at least one cohort
-            # actually advanced through the batched kernel (bench.py
-            # turns a silent fallback into a loud warning, the
-            # device_engaged discipline)
+            # actually advanced through the batched kernel (a silent
+            # fallback shows, the device_engaged discipline)
             **(lambda dt: {} if dt is None else {
                 "device_transport_engaged": dt.cohorts > 0,
                 "device_transport": dt.summary(),

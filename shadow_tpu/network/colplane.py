@@ -7,7 +7,7 @@ packet-bundle, one scheduled closure per arrival, exactly like upstream
 Shadow's per-packet event flow (SURVEY.md §3.4) — and remains the
 ``thread_per_core`` / ``thread_per_host`` baseline. The columnar plane keeps
 the SAME simulation semantics (bit-identical results, enforced by the
-cross-policy determinism tests and bench.py's equality asserts) but
+cross-policy determinism tests and the benchmark's reference check) but
 represents traffic as batch-level data end-to-end:
 
 - **Emission** appends one plain tuple per unit to the host's egress-row
@@ -57,12 +57,13 @@ tgt/peer = dst/src of the unit.
 
 from __future__ import annotations
 
-import time as _walltime  # detlint: ok(wallclock): phase_wall routing telemetry
+import time as _walltime  # detlint: ok(wallclock): device routing telemetry
 from bisect import bisect_left
 from collections import deque
 
 import numpy as np
 
+from shadow_tpu.core.spans import current as _current_spans, span
 from shadow_tpu.core.time import SimTime, T_NEVER
 from shadow_tpu.network.fluid import (
     HARD_MAX_PKTS,
@@ -132,6 +133,16 @@ class _Outstanding:
 class ColumnarPlane(DeviceRoutedPlane):
     """Engine with the NetworkEngine public surface, columnar inside."""
 
+    #: the loop phases this plane's spans name (core/spans.py), reported
+    #: in every run summary's phase_wall. window_* attribute the fused
+    #: multi-round device windows: host-side array build vs async dispatch
+    #: vs realized readback stalls (a pipelined window shows
+    #: build+dispatch but ~zero readback); draw_flush is what resolving
+    #: due batches costs besides them.
+    PHASES = ("barrier", "draw_flush", "extract", "ingress_deferred",
+              "window_build", "window_dispatch", "window_readback",
+              "transport_tick")
+
     def __init__(self, graph: NetworkGraph, params: NetParams, hosts,
                  round_ns: SimTime, backend: str = "numpy",
                  tpu_options=None, bootstrap_end: SimTime = 0) -> None:
@@ -174,17 +185,9 @@ class ColumnarPlane(DeviceRoutedPlane):
         #: windows resolve on the numpy twin (identical flags)
         _mf = getattr(tpu_options, "tpu_mesh_floor", None)
         self.mesh_floor = 2048 if _mf is None else int(_mf)
-        #: per-phase wall-clock breakdown (VERDICT r2 item #7); merged into
-        #: the run summary by the controller. window_* phases attribute the
-        #: fused multi-round device windows: host-side array build vs
-        #: async dispatch vs realized readback stalls (a pipelined window
-        #: shows build+dispatch but ~zero readback).
-        self.phase_wall = {"barrier": 0.0, "draw_flush": 0.0,
-                           "extract": 0.0, "ingress_deferred": 0.0,
-                           "window_build": 0.0, "window_dispatch": 0.0,
-                           "window_readback": 0.0, "transport_tick": 0.0}
         for h in hosts:
             h.colplane = self
+        self._init_routes()
         self._init_device_routing(backend, tpu_options, params)
         #: C engine (native/colcore/colcore.c): same structures, C hot
         #: loops. Bit-identical to this file's Python paths (enforced by
@@ -283,17 +286,14 @@ class ColumnarPlane(DeviceRoutedPlane):
                 self.tokens_down += np.minimum(
                     add_down, p.cap_down - self.tokens_down)
         if self._deferred:
-            t0 = _walltime.perf_counter()
-            self._drain_deferred(round_start)
-            self.phase_wall["ingress_deferred"] += (
-                _walltime.perf_counter() - t0)
+            with span("ingress_deferred"):
+                self._drain_deferred(round_start)
         if self.pending:
-            t0 = _walltime.perf_counter()
-            if self._c is not None:
-                self._c.extract(round_end)
-            else:
-                self._extract(round_end)
-            self.phase_wall["extract"] += _walltime.perf_counter() - t0
+            with span("extract"):
+                if self._c is not None:
+                    self._c.extract(round_end)
+                else:
+                    self._extract(round_end)
 
     def _extract(self, round_end: SimTime) -> None:
         """Hand every store row with t < round_end to its destination
@@ -371,12 +371,12 @@ class ColumnarPlane(DeviceRoutedPlane):
             # emitters, so replayed emissions join this round's barrier
             # exactly as live-dispatched ones would have
             self.devt.flush_round(round_end)
-        self._barrier_round(round_start, round_end)
+        with span("barrier"):
+            self._barrier_round(round_start, round_end)
         self._window_tick(round_end)
 
     def _barrier_round(self, round_start: SimTime,
                        round_end: SimTime) -> None:
-        t0 = _walltime.perf_counter()
         acks = self.ack_hosts
         if acks:
             self.ack_hosts = []
@@ -410,7 +410,6 @@ class ColumnarPlane(DeviceRoutedPlane):
                     self._dispatch_device_batch(r, round_end)
             elif r and self.device is not None:
                 self._floor_cooldown_tick()
-            self.phase_wall["barrier"] += _walltime.perf_counter() - t0
             return
         if self._c is not None:
             # fault_filter rounds run the Python barrier below: flush the
@@ -460,7 +459,6 @@ class ColumnarPlane(DeviceRoutedPlane):
             self._barrier_scalar(rows, segs, round_start, round_end, uids_l)
         else:
             self._barrier_vector(rows, segs, round_start, round_end, uids_l)
-        self.phase_wall["barrier"] += _walltime.perf_counter() - t0
 
     def _mesh_materialize(self) -> None:
         """Resolve EVERY lazily-accumulated mesh barrier in one fused
@@ -570,8 +568,8 @@ class ColumnarPlane(DeviceRoutedPlane):
         if not keep_rows:
             return
         if not any_live:
-            self._store_resolved(keep_rows, src_l, arrival_l, keys_l,
-                                 None, round_end)
+            self._store_routed("none", keep_rows, src_l, arrival_l, keys_l,
+                               None, round_end)
             return
         ul = np.array(uid_keep, dtype=np.uint64)
         self.outstanding.append(_Outstanding(
@@ -664,8 +662,8 @@ class ColumnarPlane(DeviceRoutedPlane):
             if not live and forced is None:
                 # nothing can drop: straight to the store (the collective
                 # would only confirm all-false flags)
-                self._store_resolved(keep_rows, src_l, arrival_l, keys_l,
-                                     None, round_end)
+                self._store_routed("none", keep_rows, src_l, arrival_l,
+                                   keys_l, None, round_end)
                 return
             # LAZY collective batch: arrivals are known host-side, draws
             # are pure functions of unit identity, so the whole causal
@@ -685,8 +683,8 @@ class ColumnarPlane(DeviceRoutedPlane):
             return
         if not live and forced is None:
             # nothing can drop: skip draws entirely, straight to the store
-            self._store_resolved(keep_rows, src_l, arrival_l, keys_l, None,
-                                 round_end)
+            self._store_routed("none", keep_rows, src_l, arrival_l, keys_l,
+                               None, round_end)
             return
         uid_lo = (uid & np.uint64(0xFFFFFFFF)).astype(np.uint32)
         uid_hi = (uid >> np.uint64(32)).astype(np.uint32)
@@ -786,53 +784,55 @@ class ColumnarPlane(DeviceRoutedPlane):
         own causal deadline. Readback is deferred exactly as before; only
         the dispatch count changes (one per window, not one per barrier)."""
         t0 = _walltime.perf_counter()
-        mb = self.max_batch
-        groups: list = []
-        cur: list = []
-        cur_n = 0
-        for b in lazy:
-            n = len(b.keys)
-            if cur and cur_n + n > mb:
-                groups.append((cur, cur_n))
-                cur, cur_n = [], 0
-            cur.append(b)
-            cur_n += n
-        groups.append((cur, cur_n))
-        t1 = _walltime.perf_counter()
-        for batches, n_g in groups:
-            self._win_inflight += 1
-            if len(batches) == 1 and n_g > mb:
-                # one oversized batch: chunk it like the retired per-batch
-                # loop did, behind a concatenating handle
-                b = batches[0]
-                handles = [
-                    self.device.dispatch(b.uid_lo[i:i + mb],
-                                         b.uid_hi[i:i + mb],
-                                         b.npk[i:i + mb],
-                                         b.thresh[i:i + mb])
-                    for i in range(0, n_g, mb)]
-                b.handle = _ConcatHandle(self, handles)
-                continue
-            if len(batches) == 1:
-                b = batches[0]
-                lo, hi, npk, th = b.uid_lo, b.uid_hi, b.npk, b.thresh
-            else:
-                lo = np.concatenate([b.uid_lo for b in batches])
-                hi = np.concatenate([b.uid_hi for b in batches])
-                npk = np.concatenate([b.npk for b in batches])
-                th = np.concatenate([b.thresh for b in batches])
-            wh = _WindowHandle(self, self.device.dispatch(lo, hi, npk, th))
-            off = 0
-            for b in batches:
+        with span("window_build"):
+            mb = self.max_batch
+            groups: list = []
+            cur: list = []
+            cur_n = 0
+            for b in lazy:
                 n = len(b.keys)
-                b.handle = _WindowSlice(wh, off, n)
-                off += n
+                if cur and cur_n + n > mb:
+                    groups.append((cur, cur_n))
+                    cur, cur_n = [], 0
+                cur.append(b)
+                cur_n += n
+            groups.append((cur, cur_n))
+            # each group's draw arrays, concatenated (None: one oversized
+            # batch, launched in chunks)
+            arrays = []
+            for batches, n_g in groups:
+                if len(batches) == 1:
+                    b = batches[0]
+                    arrays.append(None if n_g > mb else
+                                  (b.uid_lo, b.uid_hi, b.npk, b.thresh))
+                else:
+                    arrays.append(tuple(
+                        np.concatenate([getattr(b, f) for b in batches])
+                        for f in ("uid_lo", "uid_hi", "npk", "thresh")))
+        with span("window_dispatch"):
+            for (batches, n_g), arr in zip(groups, arrays):
+                self._win_inflight += 1
+                if arr is None:
+                    # one oversized batch: chunk it like the retired
+                    # per-batch loop did, behind a concatenating handle
+                    b = batches[0]
+                    handles = [
+                        self.device.dispatch(b.uid_lo[i:i + mb],
+                                             b.uid_hi[i:i + mb],
+                                             b.npk[i:i + mb],
+                                             b.thresh[i:i + mb])
+                        for i in range(0, n_g, mb)]
+                    b.handle = _ConcatHandle(self, handles)
+                    continue
+                wh = _WindowHandle(self, self.device.dispatch(*arr))
+                off = 0
+                for b in batches:
+                    n = len(b.keys)
+                    b.handle = _WindowSlice(wh, off, n)
+                    off += n
         self._win_open_rounds = 0
-        t2 = _walltime.perf_counter()
-        self.phase_wall["window_build"] += t1 - t0
-        self.phase_wall["window_dispatch"] += t2 - t1
         self._note_window_units(units)
-        self._record_window(units, t2 - t0)
+        self._record_window(units, _walltime.perf_counter() - t0)
 
     def _window_done(self) -> None:
         """A dispatched window's last deferred readback was consumed: its
@@ -843,8 +843,7 @@ class ColumnarPlane(DeviceRoutedPlane):
     def _stall_sample(self, dt: float) -> None:
         """A window readback stalled for dt seconds: fold it into the
         break-even EMA (a stalling window costs host wall exactly like
-        dispatch does) and the phase attribution."""
-        self.phase_wall["window_readback"] += dt
+        dispatch does)."""
         if dt > 2e-5:
             self._win_cost_ema += 0.25 * dt
 
@@ -946,60 +945,59 @@ class ColumnarPlane(DeviceRoutedPlane):
 
     def _dispatch_spec_group(self, hosts, u0, n, npk_a, npk_b) -> None:
         t0 = _walltime.perf_counter()
-        n64 = n.astype(np.int64)
-        parts_lo: list = []
-        parts_hi: list = []
-        parts_npk: list = []
-        off_a = np.full(len(hosts), -1, dtype=np.int64)
-        off_b = np.full(len(hosts), -1, dtype=np.int64)
-        off = 0
-        for npk_c, offs in ((npk_a, off_a), (npk_b, off_b)):
-            use = np.flatnonzero(npk_c >= self.SPEC_MIN_NPK)
-            if use.size == 0:
-                continue
-            ns = n64[use]
-            total = int(ns.sum())
-            starts = np.cumsum(ns) - ns
-            uid = (np.repeat(u0[use], ns)
-                   + (np.arange(total, dtype=np.int64)
-                      - np.repeat(starts, ns)).astype(np.uint64))
-            parts_lo.append((uid & np.uint64(0xFFFFFFFF)).astype(np.uint32))
-            parts_hi.append((uid >> np.uint64(32)).astype(np.uint32))
-            parts_npk.append(
-                np.repeat(npk_c[use].astype(np.uint32), ns))
-            offs[use] = off + starts
-            off += total
+        with span("window_build"):
+            n64 = n.astype(np.int64)
+            parts_lo: list = []
+            parts_hi: list = []
+            parts_npk: list = []
+            off_a = np.full(len(hosts), -1, dtype=np.int64)
+            off_b = np.full(len(hosts), -1, dtype=np.int64)
+            off = 0
+            for npk_c, offs in ((npk_a, off_a), (npk_b, off_b)):
+                use = np.flatnonzero(npk_c >= self.SPEC_MIN_NPK)
+                if use.size == 0:
+                    continue
+                ns = n64[use]
+                total = int(ns.sum())
+                starts = np.cumsum(ns) - ns
+                uid = (np.repeat(u0[use], ns)
+                       + (np.arange(total, dtype=np.int64)
+                          - np.repeat(starts, ns)).astype(np.uint64))
+                parts_lo.append(
+                    (uid & np.uint64(0xFFFFFFFF)).astype(np.uint32))
+                parts_hi.append((uid >> np.uint64(32)).astype(np.uint32))
+                parts_npk.append(
+                    np.repeat(npk_c[use].astype(np.uint32), ns))
+                offs[use] = off + starts
+                off += total
         if off == 0:
             return
-        t1 = _walltime.perf_counter()
-        dh = self.device.dispatch_min(
-            np.concatenate(parts_lo), np.concatenate(parts_hi),
-            np.concatenate(parts_npk),
-            min_bucket=self.device.SPEC_BUCKET)
+        with span("window_dispatch"):
+            dh = self.device.dispatch_min(
+                np.concatenate(parts_lo), np.concatenate(parts_hi),
+                np.concatenate(parts_npk),
+                min_bucket=self.device.SPEC_BUCKET)
         self._spec_pending.append(
             (dh, (hosts, u0, n, npk_a, npk_b), off_a, off_b))
         self.dev_windows += 1
         self.dev_window_units += off
+        self.spec_rows += off
         self._spec_units += off
-        t2 = _walltime.perf_counter()
-        self.phase_wall["window_build"] += t1 - t0
-        self.phase_wall["window_dispatch"] += t2 - t1
         # the economics clamp compares speculation's OWN spend against its
         # hits; deferred-window walls must not be billed to it
-        self._spec_spend += t2 - t0
+        self._spec_spend += _walltime.perf_counter() - t0
 
     def _install_spec(self, wave) -> None:
         """A speculative wave's min-draws landed: hand them to the C
         consult table in one call (per-host slices by unit offset)."""
         t0 = _walltime.perf_counter()
-        dh, d, off_a, off_b = wave
-        mins = dh.read()
-        hosts, u0, n, npk_a, npk_b = d
-        self._c.spec_install(hosts, u0, n, npk_a, npk_b, off_a, off_b,
-                             np.ascontiguousarray(mins))
-        dt = _walltime.perf_counter() - t0
-        self.phase_wall["window_build"] += dt
-        self._spec_spend += dt
+        with span("window_build"):
+            dh, d, off_a, off_b = wave
+            mins = dh.read()
+            hosts, u0, n, npk_a, npk_b = d
+            self._c.spec_install(hosts, u0, n, npk_a, npk_b, off_a, off_b,
+                                 np.ascontiguousarray(mins))
+        self._spec_spend += _walltime.perf_counter() - t0
 
     # result consumption ----------------------------------------------------
     def flush_due(self, limit: SimTime) -> None:
@@ -1013,7 +1011,10 @@ class ColumnarPlane(DeviceRoutedPlane):
             return
         if not any(b.deadline < limit for b in self.outstanding):
             return
-        t0 = _walltime.perf_counter()
+        with span("draw_flush"):
+            self._flush_due(limit)
+
+    def _flush_due(self, limit: SimTime) -> None:
         if self.mesh_plane is not None:
             self._mesh_materialize()
         if self.device is not None:
@@ -1053,28 +1054,33 @@ class ColumnarPlane(DeviceRoutedPlane):
             self._win_open_rounds = 0  # flush truncated the open window
         it = None
         if lazy:
-            if len(lazy) == 1:
-                b = lazy[0]
-                lz = [loss_flags(self.params.seed, b.uid_lo, b.uid_hi,
-                                 b.npk, b.thresh)]
-            else:
-                lo = np.concatenate([b.uid_lo for b in lazy])
-                hi = np.concatenate([b.uid_hi for b in lazy])
-                npk = np.concatenate([b.npk for b in lazy])
-                th = np.concatenate([b.thresh for b in lazy])
-                flat = loss_flags(self.params.seed, lo, hi, npk, th)
-                lz = np.split(
-                    flat, np.cumsum([len(b.keys) for b in lazy])[:-1])
+            # the numpy twin draws the windows kept off the device
+            with span("draw_flush.twin"):
+                if len(lazy) == 1:
+                    b = lazy[0]
+                    lz = [loss_flags(self.params.seed, b.uid_lo, b.uid_hi,
+                                     b.npk, b.thresh)]
+                else:
+                    lo = np.concatenate([b.uid_lo for b in lazy])
+                    hi = np.concatenate([b.uid_hi for b in lazy])
+                    npk = np.concatenate([b.npk for b in lazy])
+                    th = np.concatenate([b.thresh for b in lazy])
+                    flat = loss_flags(self.params.seed, lo, hi, npk, th)
+                    lz = np.split(
+                        flat, np.cumsum([len(b.keys) for b in lazy])[:-1])
             it = iter(lz)
         for b in take:
             if b.handle is None:
+                route = "twin"
                 flags = next(it)
                 flags_l = flags.tolist() if flags.any() else None
             elif isinstance(b.handle, _MeshHandle):
+                route = "mesh"
                 arrival_a, mflags = b.handle.read()
                 b.arrival = arrival_a.tolist()
                 flags_l = mflags.tolist() if mflags.any() else None
             else:
+                route = "window"
                 r0 = _walltime.perf_counter()
                 flags = b.handle.read()
                 self._record_dev_read(_walltime.perf_counter() - r0,
@@ -1085,10 +1091,10 @@ class ColumnarPlane(DeviceRoutedPlane):
                     flags_l = b.forced
                 else:
                     flags_l = [a or f for a, f in zip(flags_l, b.forced)]
-            self._store_resolved(b.rows, b.src, b.arrival, b.keys, flags_l,
-                                 b.round_end)
+            with span("draw_flush.store"):
+                self._store_routed(route, b.rows, b.src, b.arrival, b.keys,
+                                   flags_l, b.round_end)
         self._floor_settle()
-        self.phase_wall["draw_flush"] += _walltime.perf_counter() - t0
 
     def flush_all(self) -> None:
         self.flush_due(T_NEVER + 1)
@@ -1104,13 +1110,26 @@ class ColumnarPlane(DeviceRoutedPlane):
             self.spec_draws += draws
             self._spec_pending = []
         if self._c is not None:
-            self._c.fold_counters()
-        if self.mesh_plane is not None:
-            # surface the collective's per-window wall attribution in the
-            # run summary (mesh_* keys in phase_wall; VERDICT r4 item #7)
-            for k, v in self.mesh_plane.phase.items():
-                self.phase_wall[f"mesh_{k}"] = (
-                    round(v, 4) if isinstance(v, float) else v)
+            # the C engine's section timers and counts join the run's
+            # spans; its barrier's routes join the Python ones
+            folded = self._c.fold_counters()
+            rec = _current_spans()
+            if rec is not None:
+                for name, (sec, calls) in folded["timers"].items():
+                    rec.add(name, sec, calls)
+                for name, calls in folded["counts"].items():
+                    rec.count(name, calls)
+            for route, n in folded["routes"].items():
+                self.routes[route] += n
+            self.handoff_units += folded["handoff_units"]
+
+    def _store_routed(self, route: str, rows, src_l, arrival, keys, flags,
+                      round_end: SimTime) -> None:
+        """_store_resolved, counting the units it sent under ``route``
+        (where their loss draw was made: devroute.ROUTES)."""
+        sent = self.units_sent
+        self._store_resolved(rows, src_l, arrival, keys, flags, round_end)
+        self.routes[route] += self.units_sent - sent
 
     def _store_resolved(self, rows, src_l, arrival, keys, flags,
                         round_end: SimTime) -> None:
@@ -1221,7 +1240,8 @@ class _WindowHandle:
     def read_full(self) -> np.ndarray:
         if self.flags is None:
             t0 = _walltime.perf_counter()
-            self.flags = self._dh.read()
+            with span("window_readback"):
+                self.flags = self._dh.read()
             self.plane._stall_sample(_walltime.perf_counter() - t0)
         return self.flags
 
@@ -1260,7 +1280,8 @@ class _ConcatHandle:
 
     def read(self) -> np.ndarray:
         t0 = _walltime.perf_counter()
-        flags = np.concatenate([h.read() for h in self.handles])
+        with span("window_readback"):
+            flags = np.concatenate([h.read() for h in self.handles])
         self.plane._stall_sample(_walltime.perf_counter() - t0)
         self.plane._window_done()
         return flags

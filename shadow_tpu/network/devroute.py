@@ -38,6 +38,11 @@ class DataPlaneError(RuntimeError):
     built). Under a device policy this ends the run: it never falls back
     to a host twin in silence."""
 
+#: where a sent unit's loss draw was made (device_summary "routes"): in C
+#: or numpy at the barrier, from a C-consulted speculative window, by the
+#: numpy twin at flush, in a deferred device window, in the mesh
+#: collective, or nowhere (no lossy path)
+ROUTES = ("inline", "spec_hit", "twin", "window", "mesh", "none")
 #: deferred windows in flight at once (double-buffered handles): window
 #: N's device execution overlaps the build of window N+1; a third window
 #: waits (stays lazy) rather than queueing unbounded device memory
@@ -53,7 +58,17 @@ _BE_RELEASE = 0.8
 
 class DeviceRoutedPlane:
     """Mixin state + helpers; subclasses populate graph/params/_deferred/
-    outstanding and call _init_device_routing() from __init__."""
+    outstanding and call _init_routes() and _init_device_routing() from
+    __init__."""
+
+    spec_rows = 0  # speculative rows dispatched (window_units has them)
+    handoff_units = 0  # units the C barrier handed to the window machinery
+
+    def _init_routes(self) -> None:
+        """Per-route counts of the units sent: they add up to units_sent,
+        and a checkpoint carries them (reattach_device does not reset
+        them)."""
+        self.routes = dict.fromkeys(ROUTES, 0)
 
     def _init_device_routing(self, backend: str, tpu_options,
                              params) -> None:
@@ -286,6 +301,8 @@ class DeviceRoutedPlane:
 
     def __setstate__(self, d):
         self.__dict__.update(d)
+        if "routes" not in d:  # a snapshot from before route counts
+            self._init_routes()
         self.device = None
         self.device_error = None
         self.mesh_plane = None
@@ -422,7 +439,10 @@ class DeviceRoutedPlane:
         if self._attach_wall is not None:
             extra["attach_wall_seconds"] = self._attach_wall
         if self.mesh_plane is not None:
-            extra["mesh_windows"] = self.mesh_plane.phase["windows"]
+            extra["mesh_windows"] = self.mesh_plane.windows
+        attach = getattr(self.device, "attach_stats", None)
+        if attach:
+            extra["attach"] = attach
         if self.draw_service is not None:
             extra["draw_service"] = self.draw_service
             if self.device is not None and self.device._dead:
@@ -432,6 +452,9 @@ class DeviceRoutedPlane:
             **extra,
             "windows_dispatched": self.dev_windows,
             "window_units": self.dev_window_units,
+            "spec_rows": self.spec_rows,
+            "handoff_units": self.handoff_units,
+            "routes": dict(self.routes),
             "spec_hits": self.spec_hits,
             "spec_draws": self.spec_draws,
             "break_even_units": self.break_even_units(),

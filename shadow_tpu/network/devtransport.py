@@ -58,11 +58,12 @@ gates and window-edge writeback.
 
 from __future__ import annotations
 
-import time as _walltime  # detlint: ok(wallclock): engagement economics + phase_wall
+import time as _walltime  # detlint: ok(wallclock): engagement economics
 from typing import Optional
 
 import numpy as np
 
+from shadow_tpu.core.spans import span
 from shadow_tpu.network import unit as U
 from shadow_tpu.network.transport import ESTABLISHED, StreamEndpoint
 from shadow_tpu.ops import transport_kernels as TK
@@ -278,6 +279,10 @@ class DeviceTransport:
             return
         self.staged = []
         self._flushes += 1
+        with span("transport_tick"):
+            self._flush(staged)
+
+    def _flush(self, staged) -> None:
         t0 = _walltime.perf_counter()
         probe = (self._flushes & 15) == 0 and self._warm
         # both EMAs divide the whole-flush wall by the SAME denominator —
@@ -320,8 +325,6 @@ class DeviceTransport:
             elif nacks:
                 self._batch_ema = _ema(self._batch_ema, dt / nacks)
         self._decide()
-        self.plane.phase_wall["transport_tick"] += (
-            _walltime.perf_counter() - t0)
 
     def _decide(self) -> None:
         """Engage/release with the devroute hysteresis bands: both paths

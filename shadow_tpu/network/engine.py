@@ -31,7 +31,7 @@ counted, then silently discarded — matching IP semantics for no-route.
 
 from __future__ import annotations
 
-import time as _walltime  # detlint: ok(wallclock): phase_wall + device break-even routing
+import time as _walltime  # detlint: ok(wallclock): device break-even routing
 from collections import deque
 from dataclasses import dataclass
 from functools import partial
@@ -39,6 +39,7 @@ from functools import partial
 import numpy as np
 
 from shadow_tpu.core.events import BAND_NET
+from shadow_tpu.core.spans import span
 from shadow_tpu.core.time import SimTime, T_NEVER
 from shadow_tpu.network.fluid import (
     NetParams,
@@ -93,7 +94,6 @@ class NetworkEngine(DeviceRoutedPlane):
         #: a faults: config section exists (shadow_tpu/faults.py): hosts
         #: may crash, links may cut; enables per-host blackhole accounting
         self.faults_active = False
-        self.phase_wall: dict = {}  # per-phase timing lives in colplane
 
         self._deferred: set = set()  # hosts with ingress backlog
         #: multi-process sharding (parallel/shards.py): when bound, rows
@@ -113,6 +113,7 @@ class NetworkEngine(DeviceRoutedPlane):
                          or "fifo")
         # device attach/calibration + adaptive routing floor (shared with
         # the columnar plane: network/devroute.py)
+        self._init_routes()
         self._init_device_routing(backend, tpu_options, params)
 
     def pending_head(self) -> SimTime:
@@ -242,7 +243,8 @@ class NetworkEngine(DeviceRoutedPlane):
             flags = loss_flags(self.params.seed, *_uid_arrays(units, n), thresh)
             if forced is not None:
                 flags = flags | forced
-            self._schedule_batch(units, arrival, flags, keys, round_end)
+            self._schedule_batch(units, arrival, flags, keys, round_end,
+                                 "inline" if (thresh > 0).any() else "none")
             return
         for i in range(0, n, self.max_batch):
             j = min(n, i + self.max_batch)
@@ -269,18 +271,19 @@ class NetworkEngine(DeviceRoutedPlane):
         self.outstanding = deque(b for b in self.outstanding if b.deadline >= limit)
         for b in due:
             t0 = _walltime.perf_counter()
-            flags = b.handle.read()
+            with span("window_readback"):
+                flags = b.handle.read()
             self._record_dev_read(_walltime.perf_counter() - t0,
                                   len(b.units))
             self._schedule_batch(b.units, b.arrival,
-                                 flags, b.keys, b.round_end)
+                                 flags, b.keys, b.round_end, "window")
         self._floor_settle()
 
     def flush_all(self) -> None:
         self.flush_due(T_NEVER + 1)
 
     def _schedule_batch(self, units, arrival, dropped, keys,
-                        round_end: SimTime) -> None:
+                        round_end: SimTime, route: str) -> None:
         # bulk numpy->Python conversions (tolist is C-speed; per-element
         # int() boxing dominated this loop at 10k-host scale). The clamps
         # keep causality when experimental.runahead widens the round
@@ -318,6 +321,7 @@ class NetworkEngine(DeviceRoutedPlane):
         self.units_sent += sent
         self.units_dropped += dropped_ct
         self.bytes_sent += nbytes
+        self.routes[route] += sent
 
     # -- multi-process sharding (parallel/shards.py) ------------------------
     def bind_shard(self, shard_id: int, shard_n: int) -> None:

@@ -39,6 +39,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from shadow_tpu.core.spans import Recorder, rss_bytes, span
 from shadow_tpu.network.fluid import MAX_PKTS, PKT_SHIFT, loss_flags
 
 #: padded-bucket floor; buckets are powers of two between MIN and the cap
@@ -121,8 +122,11 @@ class DrawHandle:
         self._n = n
 
     def read(self) -> np.ndarray:
-        packed = np.asarray(self._arr)
-        return np.unpackbits(packed, bitorder="little")[: self._n].astype(bool)
+        with span("window_readback.wait"):
+            packed = np.asarray(self._arr)
+        with span("window_readback.unpack"):
+            return np.unpackbits(packed,
+                                 bitorder="little")[: self._n].astype(bool)
 
     def is_ready(self) -> bool:
         """True when the device result has landed host-side — read() will
@@ -155,6 +159,9 @@ class DeviceDrawPlane:
     """
 
     name = "tpu"
+    #: {span: {seconds, rss_mb_before, rss_mb_after}} of the attach that
+    #: built this plane (attach_cached); None for a plane built directly
+    attach_stats = None
 
     def __init__(self, seed: int, max_batch: int = 65536,
                  n_shards: int = 0, max_pkts: int = MAX_PKTS) -> None:
@@ -198,19 +205,23 @@ class DeviceDrawPlane:
         overrides the plane's seed for this batch (the fleet draw server
         serves many member sims' windows from one attach)."""
         n = uid_lo.shape[0]
-        p = _bucket(n, self.max_batch)
-        if self._sharding is not None:
-            q = 8 * self._n_shards  # packbits + even split across shards
-            p = -(-max(p, q) // q) * q
-        packed = np.zeros((4, p), dtype=np.uint32)
-        packed[0, :n] = uid_lo
-        packed[1, :n] = uid_hi
-        packed[2, :n] = npkts
-        packed[3, :n] = thresh
-        dev_in = (jax.device_put(packed, self._sharding)
-                  if self._sharding is not None else jnp.asarray(packed))
-        out = _draw_kernel(dev_in, self._seed_key(seed), width=self.max_pkts)
-        out.copy_to_host_async()
+        with span("window_dispatch.pack"):
+            p = _bucket(n, self.max_batch)
+            if self._sharding is not None:
+                q = 8 * self._n_shards  # packbits + even split over shards
+                p = -(-max(p, q) // q) * q
+            packed = np.zeros((4, p), dtype=np.uint32)
+            packed[0, :n] = uid_lo
+            packed[1, :n] = uid_hi
+            packed[2, :n] = npkts
+            packed[3, :n] = thresh
+        with span("window_dispatch.put"):
+            dev_in = (jax.device_put(packed, self._sharding)
+                      if self._sharding is not None else jnp.asarray(packed))
+        with span("window_dispatch.launch"):
+            out = _draw_kernel(dev_in, self._seed_key(seed),
+                               width=self.max_pkts)
+            out.copy_to_host_async()
         return DrawHandle(out, n)
 
     #: every speculative wave pads to this one bucket so exactly ONE
@@ -227,19 +238,22 @@ class DeviceDrawPlane:
         mid-run compiles; padded rows carry npkts 0 and can never hit);
         ``seed`` overrides the plane's seed (fleet draw server)."""
         n = uid_lo.shape[0]
-        p = max(_bucket(n, self.max_batch), min_bucket)
-        if self._sharding is not None:
-            q = 8 * self._n_shards
-            p = -(-max(p, q) // q) * q
-        packed = np.zeros((3, p), dtype=np.uint32)
-        packed[0, :n] = uid_lo
-        packed[1, :n] = uid_hi
-        packed[2, :n] = npkts
-        dev_in = (jax.device_put(packed, self._sharding)
-                  if self._sharding is not None else jnp.asarray(packed))
-        out = _min_draw_kernel(dev_in, self._seed_key(seed),
-                               width=self.max_pkts)
-        out.copy_to_host_async()
+        with span("window_dispatch.pack"):
+            p = max(_bucket(n, self.max_batch), min_bucket)
+            if self._sharding is not None:
+                q = 8 * self._n_shards
+                p = -(-max(p, q) // q) * q
+            packed = np.zeros((3, p), dtype=np.uint32)
+            packed[0, :n] = uid_lo
+            packed[1, :n] = uid_hi
+            packed[2, :n] = npkts
+        with span("window_dispatch.put"):
+            dev_in = (jax.device_put(packed, self._sharding)
+                      if self._sharding is not None else jnp.asarray(packed))
+        with span("window_dispatch.launch"):
+            out = _min_draw_kernel(dev_in, self._seed_key(seed),
+                                   width=self.max_pkts)
+            out.copy_to_host_async()
         return MinDrawHandle(out, n)
 
     _cache: dict = {}  # (seed, max_batch, n_shards, max_pkts) -> entry
@@ -266,22 +280,45 @@ class DeviceDrawPlane:
         if hit is None:
             if should_abort is not None and should_abort():
                 return None
-            plane = cls(seed, max_batch, n_shards=n_shards,
-                        max_pkts=max_pkts)
-            if should_abort is not None and should_abort():
-                return None
-            dev_s, np_per_unit = plane.calibrate()
-            # warm EVERY program shape this plane can ever dispatch
-            # (VERDICT r5 item #7): calibrate() compiles only its probe
-            # bucket, so the remaining power-of-two buckets (and the
-            # speculative min-draw shape) used to compile lazily INSIDE
-            # the first run's measured round loop — the warm-up leak that
-            # made the first tpu rep ~2.1x slow in interleaved raws.
-            # ~log2(max_batch) shapes, on the attach thread, amortized by
-            # the persistent compile cache across processes.
-            plane.warm_shapes(should_abort=should_abort)
+            # the attach's own spans (attach.plane / attach.calibrate /
+            # attach.warm_shapes) and the host RSS around each, kept on
+            # the plane for every run's device summary
+            rec = Recorder()
+            rss = {}
+            with rec.installed():
+                rss["before"] = rss_bytes()
+                with span("attach.plane"):
+                    plane = cls(seed, max_batch, n_shards=n_shards,
+                                max_pkts=max_pkts)
+                rss["attach.plane"] = rss_bytes()
+                if should_abort is not None and should_abort():
+                    return None
+                with span("attach.calibrate"):
+                    dev_s, np_per_unit = plane.calibrate()
+                rss["attach.calibrate"] = rss_bytes()
+                # warm EVERY program shape this plane can ever dispatch
+                # (VERDICT r5 item #7): calibrate() compiles only its
+                # probe bucket, so the remaining power-of-two buckets (and
+                # the speculative min-draw shape) used to compile lazily
+                # INSIDE the first run's measured round loop — the warm-up
+                # leak that made the first tpu rep ~2.1x slow in
+                # interleaved raws. ~log2(max_batch) shapes, on the attach
+                # thread, amortized by the persistent compile cache.
+                with span("attach.warm_shapes"):
+                    plane.warm_shapes(should_abort=should_abort)
+                rss["attach.warm_shapes"] = rss_bytes()
             if should_abort is not None and should_abort():
                 return None  # partially warmed: do not cache
+            prev = rss["before"]
+            seconds = rec.phase_detail()
+            plane.attach_stats = {}
+            for name in ("attach.plane", "attach.calibrate",
+                         "attach.warm_shapes"):
+                plane.attach_stats[name] = {
+                    "seconds": seconds[name],
+                    "rss_mb_before": prev / 2**20,
+                    "rss_mb_after": rss[name] / 2**20}
+                prev = rss[name]
             if len(cls._cache) >= 4:  # a handful of configs per process
                 cls._cache.pop(next(iter(cls._cache)))
             hit = cls._cache[key] = (plane, dev_s, np_per_unit)

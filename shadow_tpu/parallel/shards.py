@@ -77,6 +77,7 @@ from pathlib import Path
 import numpy as np
 
 from shadow_tpu.core.controller import Controller, _GC_EVERY_ROUNDS
+from shadow_tpu.core.spans import Recorder
 from shadow_tpu.core.time import NS_PER_SEC, NS_PER_US, T_NEVER, format_time
 from shadow_tpu.host.process import PluginProcess
 from shadow_tpu.supervise import (STALL_CEILING_S, ChaosInjector,
@@ -361,8 +362,6 @@ class _ShardWorker:
                 continue
             self.rings_out[j] = ShmRing(_ring_name(ring_tag, shard_id, j))
             self.rings_in[j] = ShmRing(_ring_name(ring_tag, j, shard_id))
-        self._exchange_wall = 0.0
-        self._sync_wall = 0.0
         self._next_gc = _GC_EVERY_ROUNDS
         #: liveness (shadow_tpu/supervise.py): this shard's slot on the
         #: shared progress page — stamped at every round top, inside the
@@ -442,11 +441,17 @@ class _ShardWorker:
             "tel_partials": (tel.drain_partials()
                             if tel is not None else []),
         }))
+        # this shard's loop phases (core/spans.py); exchange = the round
+        # edge's flush + ship + marker, sync = the cross-shard barrier
+        rec = ctl.spans = Recorder()
+        rec.declare(("events", "gc", "exchange", "sync",
+                     *getattr(ctl.engine, "PHASES", ())))
         try:
             op, m = self.conn.recv()
             if op != "run":
                 raise RuntimeError(f"expected run command, got {op!r}")
-            self._free_run(m)
+            with rec.installed():
+                self._free_run(m)
             while True:
                 msg = self.conn.recv()
                 if msg[0] == "finalize":
@@ -454,7 +459,9 @@ class _ShardWorker:
                 if msg[0] != "stop":  # a stop racing our normal finish
                     raise RuntimeError(
                         f"expected finalize, got {msg[0]!r}")
-            self.conn.send(("final", self._finalize(msg[1])))
+            with rec.installed():
+                final = self._finalize(msg[1])
+            self.conn.send(("final", final))
         finally:
             if gc_was_enabled:
                 _gc.enable()
@@ -488,6 +495,7 @@ class _ShardWorker:
         interrupted = False
         from shadow_tpu import checkpoint as _ckpt
 
+        span = ctl.spans.span
         while now < stop:
             # liveness: stamp the progress word FIRST (round + wall),
             # then update the round-wall EMA the stall deadline derives
@@ -530,17 +538,16 @@ class _ShardWorker:
                 w = max(base_w, min(mul, 10 * base_w))
             round_end = min(now + w, stop)
             eng.start_of_round(now, round_end)
-            t_ev = _walltime.perf_counter()
-            if ctl._c_core is not None:
-                executed = ctl._c_core.run_round(round_end)
-            else:
-                hosts = ctl.hosts
-                active = [hosts[i] for i in sorted(ctl._active)]
-                executed = ctl.scheduler.run_round(round_end, active)
-                for h in active:
-                    if not h.equeue._heap:
-                        ctl._active.discard(h.id)
-            ctl._events_wall += _walltime.perf_counter() - t_ev
+            with span("events"):
+                if ctl._c_core is not None:
+                    executed = ctl._c_core.run_round(round_end)
+                else:
+                    hosts = ctl.hosts
+                    active = [hosts[i] for i in sorted(ctl._active)]
+                    executed = ctl.scheduler.run_round(round_end, active)
+                    for h in active:
+                        if not h.equeue._heap:
+                            ctl._active.discard(h.id)
             eng.end_of_round(now, round_end)
             devt = getattr(eng, "devt", None)
             if devt is not None:
@@ -556,74 +563,73 @@ class _ShardWorker:
             # functions of unit identity — and a cross-shard row must be
             # on the wire before its arrival round starts anywhere),
             # ship the diverted rows, then publish this round's marker
-            t1 = _walltime.perf_counter()
-            eng.flush_due(T_NEVER + 1)
-            xmin = T_NEVER
-            packed = (self._take_packed(self._max_block)
-                      if self._take_packed is not None else None)
-            if packed is not None:
-                # C send-side packer: blocks are already (t, key)-sorted
-                # wire bytes chunked to fit the ring; the first numeric
-                # column of row 0 (offset 8) is the block's min t
-                for j, blocks in enumerate(packed):
-                    if j == self.k:
-                        continue
-                    for data in blocks:
-                        (bt,) = struct.unpack_from("<q", data, 8)
-                        if bt < xmin:
-                            xmin = bt
-                        self._write_packed(j, data)
-            else:
-                xout = eng.take_xout()
-                for j, rows in enumerate(xout):
-                    if j == self.k or not rows:
-                        continue
-                    if rows[0][0] < xmin:
-                        xmin = rows[0][0]  # (t, key)-sorted: [0] is min t
-                    self._write_rows(j, rows)
-            # the next-event minimum is only consumed by the global
-            # skip-ahead reduction, which requires EVERY shard to have
-            # executed zero events — so a shard that executed anything
-            # can ship a placeholder and skip the active-set scan (the
-            # single-process loop likewise only scans on quiet rounds;
-            # scanning every round cost ~13 ms/round at 100k hosts)
-            if executed == 0:
-                if ctl._c_core is not None:
-                    nq = ctl._c_core.next_time()
+            with span("exchange"):
+                eng.flush_due(T_NEVER + 1)
+                xmin = T_NEVER
+                packed = (self._take_packed(self._max_block)
+                          if self._take_packed is not None else None)
+                if packed is not None:
+                    # C send-side packer: blocks are already (t, key)-sorted
+                    # wire bytes chunked to fit the ring; the first numeric
+                    # column of row 0 (offset 8) is the block's min t
+                    for j, blocks in enumerate(packed):
+                        if j == self.k:
+                            continue
+                        for data in blocks:
+                            (bt,) = struct.unpack_from("<q", data, 8)
+                            if bt < xmin:
+                                xmin = bt
+                            self._write_packed(j, data)
                 else:
-                    nq = min((ctl.hosts[i].equeue.next_time()
-                              for i in ctl._active), default=T_NEVER)
-                nq = min(nq, eng.pending_head())
-            else:
-                nq = T_NEVER
-            while self.conn.poll(0):
-                pm = self.conn.recv()
-                if pm[0] == "stop":
-                    self._stop_req = True
-                elif pm[0] == "cmd":
-                    self._pending_cmds.append(pm[1])
-                elif pm[0] == "abort":
-                    raise _PeerDied("parent aborted the run")
-            stats = {
-                "executed": executed,
-                "imm": bool(eng.has_immediate_work()),
-                "next": nq,
-                "xmin": xmin,
-                "fnext": (faults.next_time() if faults is not None
-                          else T_NEVER),
-                "mul": eng.min_used_latency,
-                "stop": self._stop_req,
-            }
-            if self.k == 0 and self._pending_cmds:
-                # live commands ride shard 0's marker: every worker reads
-                # the SAME list at the same round and applies it at the
-                # next boundary (the parent only ever feeds shard 0)
-                stats["cmds"] = self._pending_cmds
-                self._pending_cmds = []
-            for j in self.rings_out:
-                self._write_block(j, b"M" + marshal.dumps(
-                    (ctl.rounds, self.k, stats)))
-            self._exchange_wall += _walltime.perf_counter() - t1
+                    xout = eng.take_xout()
+                    for j, rows in enumerate(xout):
+                        if j == self.k or not rows:
+                            continue
+                        if rows[0][0] < xmin:
+                            xmin = rows[0][0]  # (t, key)-sorted: [0] is min t
+                        self._write_rows(j, rows)
+                # the next-event minimum is only consumed by the global
+                # skip-ahead reduction, which requires EVERY shard to have
+                # executed zero events — so a shard that executed anything
+                # can ship a placeholder and skip the active-set scan (the
+                # single-process loop likewise only scans on quiet rounds;
+                # scanning every round cost ~13 ms/round at 100k hosts)
+                if executed == 0:
+                    if ctl._c_core is not None:
+                        nq = ctl._c_core.next_time()
+                    else:
+                        nq = min((ctl.hosts[i].equeue.next_time()
+                                  for i in ctl._active), default=T_NEVER)
+                    nq = min(nq, eng.pending_head())
+                else:
+                    nq = T_NEVER
+                while self.conn.poll(0):
+                    pm = self.conn.recv()
+                    if pm[0] == "stop":
+                        self._stop_req = True
+                    elif pm[0] == "cmd":
+                        self._pending_cmds.append(pm[1])
+                    elif pm[0] == "abort":
+                        raise _PeerDied("parent aborted the run")
+                stats = {
+                    "executed": executed,
+                    "imm": bool(eng.has_immediate_work()),
+                    "next": nq,
+                    "xmin": xmin,
+                    "fnext": (faults.next_time() if faults is not None
+                              else T_NEVER),
+                    "mul": eng.min_used_latency,
+                    "stop": self._stop_req,
+                }
+                if self.k == 0 and self._pending_cmds:
+                    # live commands ride shard 0's marker: every worker reads
+                    # the SAME list at the same round and applies it at the
+                    # next boundary (the parent only ever feeds shard 0)
+                    stats["cmds"] = self._pending_cmds
+                    self._pending_cmds = []
+                for j in self.rings_out:
+                    self._write_block(j, b"M" + marshal.dumps(
+                        (ctl.rounds, self.k, stats)))
 
             # asynchronous streams to the parent (never round-gating)
             if dig and ctl.rounds % dig == 0:
@@ -631,7 +637,8 @@ class _ShardWorker:
                                 _ckpt.shard_digest_partial(ctl, round_end)))
             if tel is not None and (tel.dirty
                                     or round_end >= tel.next_sample):
-                tel.on_round_end(ctl, round_end)
+                with span("telemetry"):
+                    tel.on_round_end(ctl, round_end)
                 parts = tel.drain_partials()
                 if parts:
                     self.conn.send(("tel", ctl.rounds, parts))
@@ -643,22 +650,21 @@ class _ShardWorker:
                     "units_dropped": eng.units_dropped,
                     **({"dev": note()} if note is not None else {}),
                     "phase_wall": {
-                        "events": round(ctl._events_wall, 4),
-                        **{pk: round(pv, 4)
-                           for pk, pv in eng.phase_wall.items()}}}))
+                        pk: round(pv, 4)
+                        for pk, pv in ctl.spans.phase_wall().items()}}))
                 # grid-snap (not +=): skip-ahead can jump several
                 # heartbeat periods; the next beat lands on the grid so
                 # every shard fires on the same sim-time cadence
                 next_hb = ((round_end // hb) + 1) * hb
             if ctl.rounds >= self._next_gc:
                 self._next_gc = ctl.rounds + _GC_EVERY_ROUNDS
-                _gc.collect()
+                with span("gc"):
+                    _gc.collect()
 
             # the cross-shard barrier + the global reduction: identical
             # inputs on every worker -> identical decisions
-            t2 = _walltime.perf_counter()
-            peers = self._wait_markers(ctl.rounds)
-            self._sync_wall += _walltime.perf_counter() - t2
+            with span("sync"):
+                peers = self._wait_markers(ctl.rounds)
             s0 = stats if self.k == 0 else peers[0]
             cmds = s0.get("cmds")
             if cmds:
@@ -902,11 +908,10 @@ class _ShardWorker:
         # its store already)
         if ctl.telemetry is not None:
             ctl.telemetry.sync(ctl)
-        t0 = _walltime.perf_counter()
         self._prog.stamp(self.k, ctl.rounds)  # a long save is not a stall
-        path = _ckpt.save_checkpoint(ctl, now)
+        with ctl.spans.span("checkpoint"):
+            path = _ckpt.save_checkpoint(ctl, now)
         self._prog.stamp(self.k, ctl.rounds)
-        ctl._ckpt_wall += _walltime.perf_counter() - t0
         self.conn.send(("ckpt_done", ctl.rounds, now, str(path)))
 
     def _finalize(self, end_time: int) -> dict:
@@ -945,16 +950,7 @@ class _ShardWorker:
         ctl.log.flush()
         import resource
 
-        phase = {
-            "events": round(ctl._events_wall, 4),
-            **{k: round(v, 4) for k, v in eng.phase_wall.items()},
-            "exchange": round(self._exchange_wall, 4),
-            "sync": round(self._sync_wall, 4),
-            **({"telemetry": round(ctl.telemetry.wall, 4)}
-               if ctl.telemetry is not None else {}),
-            **({"checkpoint": round(ctl._ckpt_wall, 4)}
-               if ctl._ckpt_wall else {}),
-        }
+        phase = ctl.spans.phase_wall()
         return {
             "events": ctl.events,
             "rounds": ctl.rounds,

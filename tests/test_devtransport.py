@@ -159,7 +159,7 @@ def test_identity_matrix_web(tmp_path):
     assert runs["c-on"][0].engine.devt is None
     assert getattr(runs["tpc"][0].engine, "devt", None) is None
     # attribution satellite: the columnar path's wall is split out
-    assert "transport_tick" in ctl_on.engine.phase_wall
+    assert "transport_tick" in ctl_on.spans.phase_wall()
 
 
 def test_identity_tor(tmp_path):
