@@ -404,6 +404,14 @@ typedef struct {
   int64_t py_ns, py_calls;
   int py_depth;
   int64_t handoff_ns, handoff_calls, handoff_units;
+  /* device batches the barrier handed off: their kept rows stay here
+   * (payload refs owned) until store_pending applies the window's flags.
+   * res_units counts units stored from them (drained by fold_counters),
+   * res_held the units held now, res_peak the most held at once. */
+  struct PBatch *pb;
+  int pb_n, pb_cap;
+  int64_t pb_next;
+  int64_t res_units, res_held, res_peak;
   int64_t n_emit, n_gossip;
   /* where the C barrier's sent units were drawn (devroute.ROUTES) */
   int64_t route_inline, route_spec_hit, route_none;
@@ -482,6 +490,24 @@ typedef struct BRow {
   int32_t kind, sport, dport, frag, nfrags;
   uint8_t drop;
 } BRow;
+
+/* one handed-off device batch (CoreObject.pb): its kept BRows, copied
+ * out of the barrier's scratch with their payload refs */
+typedef struct PBatch {
+  int64_t id;
+  BRow *rows;
+  int n;
+} PBatch;
+
+/* release pending slot i: its payload refs and rows; the last slot takes
+ * its place */
+static void pbatch_release(CoreObject *c, int i) {
+  PBatch *p = &c->pb[i];
+  for (int j = 0; j < p->n; j++) Py_XDECREF(p->rows[j].payload);
+  free(p->rows);
+  c->res_held -= p->n;
+  c->pb[i] = c->pb[--c->pb_n];
+}
 
 /* ---- GossipState ------------------------------------------------------- */
 typedef struct GossipState_s {
@@ -1247,8 +1273,9 @@ done:
   return rc;
 }
 
-/* Python-callable twin of colplane._store_resolved: used by the device
- * flush path (flags arrive from a DrawHandle readback). */
+/* Python-callable twin of colplane._store_resolved: used by the flush
+ * path for batches the Python barrier or the mesh hand-off built (flags
+ * arrive as a list). */
 static PyObject *Core_store_resolved(CoreObject *c, PyObject *args) {
   PyObject *rows, *src_l, *arrival_l, *keys_l, *flags;
   long long round_end;
@@ -1303,6 +1330,113 @@ static PyObject *Core_store_resolved(CoreObject *c, PyObject *args) {
   free(br);
   if (rc < 0) return NULL;
   Py_RETURN_NONE;
+}
+
+/* the device flush path for a batch the barrier handed off: (batch_id,
+ * flags, round_end). flags is the window's (n,) bool array (any
+ * contiguous buffer of n one-byte items; None = all survive). Runs
+ * store_build on the slot's rows, then releases the slot. */
+static PyObject *Core_store_pending(CoreObject *c, PyObject *args) {
+  long long bid, round_end;
+  PyObject *flags;
+  if (!PyArg_ParseTuple(args, "LOL", &bid, &flags, &round_end)) return NULL;
+  int i = 0;
+  while (i < c->pb_n && c->pb[i].id != bid) i++;
+  if (i == c->pb_n) {
+    PyErr_Format(PyExc_KeyError, "no pending device batch %lld", bid);
+    return NULL;
+  }
+  PBatch *p = &c->pb[i];
+  int have_flags = flags != Py_None;
+  if (have_flags) {
+    Py_buffer view;
+    if (PyObject_GetBuffer(flags, &view, PyBUF_C_CONTIGUOUS) < 0)
+      return NULL;
+    if (view.itemsize != 1 || view.len != p->n) {
+      PyBuffer_Release(&view);
+      PyErr_Format(PyExc_ValueError,
+                   "flags must be %d one-byte items for batch %lld", p->n,
+                   bid);
+      return NULL;
+    }
+    const uint8_t *f = view.buf;
+    for (int j = 0; j < p->n; j++) p->rows[j].drop = f[j] != 0;
+    PyBuffer_Release(&view);
+  }
+  int rc = store_build(c, p->rows, p->n, have_flags, round_end);
+  if (rc == 0) c->res_units += p->n;
+  pbatch_release(c, i);
+  if (rc < 0) return NULL;
+  Py_RETURN_NONE;
+}
+
+/* (device hand-off) -> (batch_id, n, min_arrival, uid_lo, uid_hi, npk,
+ * thresh): move brow[0..keep) into a new pending slot, and fill the four
+ * uint32 draw arrays from it. On failure nothing moved. */
+static PyObject *handoff_resident(CoreObject *c, int keep) {
+  npy_intp dims[1] = {keep};
+  PyObject *arr_lo = PyArray_SimpleNew(1, dims, NPY_UINT32);
+  PyObject *arr_hi = PyArray_SimpleNew(1, dims, NPY_UINT32);
+  PyObject *arr_npk = PyArray_SimpleNew(1, dims, NPY_UINT32);
+  PyObject *arr_th = PyArray_SimpleNew(1, dims, NPY_UINT32);
+  BRow *rows = malloc(sizeof(BRow) * (size_t)keep);
+  if (c->pb_n == c->pb_cap && rows) {
+    int ncap = c->pb_cap ? c->pb_cap * 2 : 8;
+    PBatch *np2 = realloc(c->pb, sizeof(PBatch) * (size_t)ncap);
+    if (np2) {
+      c->pb = np2;
+      c->pb_cap = ncap;
+    } else {
+      free(rows);
+      rows = NULL;
+    }
+  }
+  if (!arr_lo || !arr_hi || !arr_npk || !arr_th || !rows) {
+    Py_XDECREF(arr_lo); Py_XDECREF(arr_hi);
+    Py_XDECREF(arr_npk); Py_XDECREF(arr_th);
+    free(rows);
+    if (!PyErr_Occurred()) PyErr_NoMemory();
+    return NULL;
+  }
+  uint32_t *plo = PyArray_DATA((PyArrayObject *)arr_lo);
+  uint32_t *phi = PyArray_DATA((PyArrayObject *)arr_hi);
+  uint32_t *pnp = PyArray_DATA((PyArrayObject *)arr_npk);
+  uint32_t *pth = PyArray_DATA((PyArrayObject *)arr_th);
+  int64_t amin = INT64_MAX;
+  for (int i = 0; i < keep; i++) {
+    BRow *b = &c->brow[i];
+    plo[i] = (uint32_t)(b->uid & 0xFFFFFFFFu);
+    phi[i] = (uint32_t)(b->uid >> 32);
+    pnp[i] = (uint32_t)b->npk;
+    pth[i] = b->th;
+    if (b->arrival < amin) amin = b->arrival;
+  }
+  int64_t id = c->pb_next;
+  PyObject *r = Py_BuildValue("(LiLNNNN)", (long long)id, keep,
+                              (long long)amin, arr_lo, arr_hi, arr_npk,
+                              arr_th);
+  if (!r) { free(rows); return NULL; }
+  memcpy(rows, c->brow, sizeof(BRow) * (size_t)keep);
+  c->pb[c->pb_n].id = id;
+  c->pb[c->pb_n].rows = rows;
+  c->pb[c->pb_n].n = keep;
+  c->pb_n++;
+  c->pb_next++;
+  c->res_held += keep;
+  if (c->res_held > c->res_peak) c->res_peak = c->res_held;
+  return r;
+}
+
+/* release every pending slot (teardown before the batches were stored) */
+static PyObject *Core_release_pending(CoreObject *c, PyObject *noarg) {
+  (void)noarg;
+  while (c->pb_n) pbatch_release(c, c->pb_n - 1);
+  Py_RETURN_NONE;
+}
+
+static PyObject *Core_pending_batches(CoreObject *c, PyObject *noarg) {
+  (void)noarg;
+  return PyLong_FromLong(c->pb_n);
 }
 
 /* ---- the round barrier (colplane end_of_round twin, fifo qdisc) -------- */
@@ -1735,10 +1869,11 @@ static PyObject *Core_barrier(CoreObject *c, PyObject *args) {
    * that actually bypassed the device — matching the vector twin, which
    * never ticks on empty rounds */
 
-  /* hand-off paths: the Python machinery takes over with arrays we
-   * build — mesh mode hands EVERY post-bootstrap batch to the lazy
-   * collective (plus src/dst arrays); device mode hands big live
-   * batches to the draw plane */
+  /* hand-off paths: device mode keeps a big live batch's rows here in a
+   * pending slot and hands the draw plane four uint32 arrays
+   * (handoff_resident; the flags come back through store_pending); mesh
+   * mode hands EVERY post-bootstrap batch to the lazy collective as row
+   * tuples plus arrays */
   /* dead batches (no loss anywhere) store inline even in mesh mode —
    * the collective would only confirm all-false flags */
   int mesh_off = c->mesh_mode && round_start >= c->bootstrap_end && any_live;
@@ -1760,6 +1895,12 @@ static PyObject *Core_barrier(CoreObject *c, PyObject *args) {
         int64_t t_handoff = nsnow();
         c->handoff_calls++;
         c->handoff_units += keep;
+        if (!mesh_off) {
+          result = handoff_resident(c, keep);
+          c->handoff_ns += nsnow() - t_handoff;
+          if (result) nown = 0; /* the slot owns the payload refs now */
+          goto done;
+        }
         npy_intp dims[1] = {keep};
         PyObject *rows_l = PyList_New(keep);
         PyObject *src_l = PyList_New(keep);
@@ -1769,13 +1910,10 @@ static PyObject *Core_barrier(CoreObject *c, PyObject *args) {
         PyObject *arr_hi = PyArray_SimpleNew(1, dims, NPY_UINT32);
         PyObject *arr_npk = PyArray_SimpleNew(1, dims, NPY_UINT32);
         PyObject *arr_th = PyArray_SimpleNew(1, dims, NPY_UINT32);
-        PyObject *arr_src = NULL, *arr_dst = NULL;
-        if (mesh_off) {
-          arr_src = PyArray_SimpleNew(1, dims, NPY_INT32);
-          arr_dst = PyArray_SimpleNew(1, dims, NPY_INT32);
-        }
+        PyObject *arr_src = PyArray_SimpleNew(1, dims, NPY_INT32);
+        PyObject *arr_dst = PyArray_SimpleNew(1, dims, NPY_INT32);
         if (!rows_l || !src_l || !keys_l || !arr_t || !arr_lo || !arr_hi ||
-            !arr_npk || !arr_th || (mesh_off && (!arr_src || !arr_dst))) {
+            !arr_npk || !arr_th || !arr_src || !arr_dst) {
           Py_XDECREF(rows_l); Py_XDECREF(src_l); Py_XDECREF(keys_l);
           Py_XDECREF(arr_t); Py_XDECREF(arr_lo); Py_XDECREF(arr_hi);
           Py_XDECREF(arr_npk); Py_XDECREF(arr_th);
@@ -1787,15 +1925,12 @@ static PyObject *Core_barrier(CoreObject *c, PyObject *args) {
         uint32_t *phi = PyArray_DATA((PyArrayObject *)arr_hi);
         uint32_t *pnp = PyArray_DATA((PyArrayObject *)arr_npk);
         uint32_t *pth = PyArray_DATA((PyArrayObject *)arr_th);
-        int32_t *psrc = mesh_off
-            ? PyArray_DATA((PyArrayObject *)arr_src) : NULL;
-        int32_t *pdst = mesh_off
-            ? PyArray_DATA((PyArrayObject *)arr_dst) : NULL;
+        int32_t *psrc = PyArray_DATA((PyArrayObject *)arr_src);
+        int32_t *pdst = PyArray_DATA((PyArrayObject *)arr_dst);
         int fail = 0;
         for (int i = 0; i < keep && !fail; i++) {
           BRow *b = &c->brow[i];
-          /* egress-format tuple for the Python device/mesh machinery
-           * (amortized by the batch's >= device_floor size) */
+          /* egress-format tuple for the Python mesh machinery */
           PyObject *row_t = Py_BuildValue(
               "(iiLLiiLLiiO)", (int)b->kind, (int)b->dst,
               (long long)b->size, (long long)b->t_emit, (int)b->sport,
@@ -1814,27 +1949,20 @@ static PyObject *Core_barrier(CoreObject *c, PyObject *args) {
           phi[i] = (uint32_t)(b->uid >> 32);
           pnp[i] = (uint32_t)b->npk;
           pth[i] = b->th;
-          if (mesh_off) {
-            psrc[i] = b->src;
-            pdst[i] = b->dst;
-          }
+          psrc[i] = b->src;
+          pdst[i] = b->dst;
         }
         if (fail) {
           Py_DECREF(rows_l); Py_DECREF(src_l); Py_DECREF(keys_l);
           Py_DECREF(arr_t); Py_DECREF(arr_lo); Py_DECREF(arr_hi);
           Py_DECREF(arr_npk); Py_DECREF(arr_th);
-          Py_XDECREF(arr_src); Py_XDECREF(arr_dst);
+          Py_DECREF(arr_src); Py_DECREF(arr_dst);
           goto done;
         }
-        if (mesh_off)
-          result = Py_BuildValue("(NNNNNNNNNN)", rows_l, src_l, arr_t,
-                                 keys_l, arr_lo, arr_hi, arr_npk, arr_th,
-                                 arr_src, arr_dst);
-        else
-          result = Py_BuildValue("(NNNNNNNN)", rows_l, src_l, arr_t,
-                                 keys_l, arr_lo, arr_hi, arr_npk, arr_th);
+        result = Py_BuildValue("(NNNNNNNNNN)", rows_l, src_l, arr_t,
+                               keys_l, arr_lo, arr_hi, arr_npk, arr_th,
+                               arr_src, arr_dst);
         c->handoff_ns += nsnow() - t_handoff;
-        if (!result) goto done;
         goto done; /* row refs now held by rows_l */
       }
     }
@@ -2264,6 +2392,8 @@ static void Core_dealloc(CoreObject *c) {
     }
     free(c->hs);
   }
+  while (c->pb_n) pbatch_release(c, c->pb_n - 1);
+  free(c->pb);
   free(c->brow);
   free(c->act_ids);
   free(c->act_pend);
@@ -2573,7 +2703,7 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
     Py_XDECREF(ctrs);
   }
   PyObject *out = Py_BuildValue(
-      "{s{s(dL)s(dL)}s{sLsL}s{sLsLsL}sL}",
+      "{s{s(dL)s(dL)}s{sLsL}s{sLsLsL}sLsLsL}",
       "timers",
       "events.py_callback", c->py_ns / 1e9, (long long)c->py_calls,
       "barrier.handoff", c->handoff_ns / 1e9, (long long)c->handoff_calls,
@@ -2584,10 +2714,14 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
       "inline", (long long)c->route_inline,
       "spec_hit", (long long)c->route_spec_hit,
       "none", (long long)c->route_none,
-      "handoff_units", (long long)c->handoff_units);
+      "handoff_units", (long long)c->handoff_units,
+      "resident_units", (long long)c->res_units,
+      "resident_peak", (long long)c->res_peak);
   if (!out) return NULL;
   c->py_ns = c->py_calls = 0;
   c->handoff_ns = c->handoff_calls = c->handoff_units = 0;
+  c->res_units = 0;
+  c->res_peak = c->res_held;
   c->n_emit = c->n_gossip = 0;
   c->route_inline = c->route_spec_hit = c->route_none = 0;
   return out;
@@ -2867,6 +3001,13 @@ static PyMethodDef Core_methods[] = {
      "rounds: fault_filter)"},
     {"store_resolved", (PyCFunction)Core_store_resolved, METH_VARARGS,
      "(rows, src_l, arrival_l, keys_l, flags|None, round_end)"},
+    {"store_pending", (PyCFunction)Core_store_pending, METH_VARARGS,
+     "(batch_id, flags|None, round_end): store a handed-off device batch "
+     "with its (n,) bool flags and release its slot"},
+    {"release_pending", (PyCFunction)Core_release_pending, METH_NOARGS,
+     "release every handed-off batch not yet stored (teardown)"},
+    {"pending_batches", (PyCFunction)Core_pending_batches, METH_NOARGS,
+     "number of handed-off device batches the core holds"},
     {"bind_active", (PyCFunction)Core_bind_active, METH_O,
      "bind the controller's active-host-id set"},
     {"gossip_register", (PyCFunction)Core_gossip_register, METH_VARARGS,

@@ -368,7 +368,7 @@ def save_checkpoint(controller, now: int) -> Path:
     #                                 the same clear refusal the CLI gets
     eng = controller.engine
     eng.flush_all()  # resolve in-flight draws: result-identical, device-free
-    if eng.outstanding:
+    if eng.outstanding or eng.pending_batches():
         raise CheckpointError(
             "engine still holds outstanding draw batches after flush_all()")
     ckpt_dir = Path(controller.ckpt_dir)

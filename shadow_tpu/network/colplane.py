@@ -108,18 +108,23 @@ class _Outstanding:
     """One barrier's units awaiting loss flags. ``handle`` is a device
     DrawHandle, or None for a lazily-coalesced numpy batch. ``rows`` are
     the egress row tuples (post blackhole filter), ``src`` the per-row
-    source host ids."""
+    source host ids. A batch the C barrier handed off keeps its rows in
+    the core instead: ``batch`` is its id there, and rows, src, arrival
+    and keys are None. ``n`` counts the units either way."""
 
     __slots__ = ("rows", "src", "arrival", "keys", "uid_lo", "uid_hi",
                  "npk", "thresh", "forced", "round_end", "deadline",
-                 "handle")
+                 "handle", "batch", "n")
 
     def __init__(self, rows, src, arrival, keys, uid_lo, uid_hi, npk,
-                 thresh, forced, round_end, deadline, handle):
+                 thresh, forced, round_end, deadline, handle, batch=None,
+                 n=None):
         self.rows = rows
         self.src = src  # list[int]
         self.arrival = arrival  # list[int]
         self.keys = keys  # list[int]
+        self.batch = batch  # C pending-slot id, or None
+        self.n = len(keys) if n is None else n
         self.uid_lo = uid_lo  # np.uint32 array
         self.uid_hi = uid_hi
         self.npk = npk
@@ -398,7 +403,8 @@ class ColumnarPlane(DeviceRoutedPlane):
                             ep.receiver.flush_ack()
         if self._c is not None and self.fault_filter is None:
             # C barrier protocol: tuple = big live batch for the device
-            # dispatch machinery; True = kept rows stored inline (tick the
+            # dispatch machinery (its rows stay in the core) or the mesh
+            # collective; True = kept rows stored inline (tick the
             # floor cooldown, like the vector twin's non-device branch);
             # None = nothing survived (no tick — the twin never ticks on
             # empty rounds)
@@ -715,15 +721,17 @@ class ColumnarPlane(DeviceRoutedPlane):
                       thresh.astype(np.int64))))
 
     def _dispatch_device_batch(self, r, round_end: SimTime) -> None:
-        """A C barrier handed back a big live batch: it joins the open
+        """A C barrier handed off a big live batch: it joins the open
         device window as a lazy batch (the window state machine owns all
         device dispatch — one fused program per window, two-slot async
-        pipeline — instead of the retired one-dispatch-per-barrier loop)."""
-        keep_rows, src_l, arrival, keys_l, uid_lo, uid_hi, npk, thresh = r
-        deadline = max(round_end, int(arrival.min()))
+        pipeline — instead of the retired one-dispatch-per-barrier loop).
+        Its rows stay in the core's pending slot ``batch_id`` until
+        store_pending gives them their flags."""
+        batch_id, n, arrival_min, uid_lo, uid_hi, npk, thresh = r
         self.outstanding.append(_Outstanding(
-            keep_rows, src_l, arrival.tolist(), keys_l, uid_lo, uid_hi,
-            npk, thresh, None, round_end, deadline, None))
+            None, None, None, None, uid_lo, uid_hi, npk, thresh, None,
+            round_end, max(round_end, arrival_min), None, batch=batch_id,
+            n=n))
 
     # -- fused multi-round device windows -----------------------------------
     def _window_tick(self, round_end: SimTime) -> None:
@@ -758,7 +766,7 @@ class ColumnarPlane(DeviceRoutedPlane):
             self._win_open_rounds = 0
             return
         self._win_open_rounds += 1
-        units = sum(len(b.keys) for b in lazy)
+        units = sum(b.n for b in lazy)
         k = self.window_rounds
         if k > 0:
             if self._win_open_rounds >= k:
@@ -790,12 +798,11 @@ class ColumnarPlane(DeviceRoutedPlane):
             cur: list = []
             cur_n = 0
             for b in lazy:
-                n = len(b.keys)
-                if cur and cur_n + n > mb:
+                if cur and cur_n + b.n > mb:
                     groups.append((cur, cur_n))
                     cur, cur_n = [], 0
                 cur.append(b)
-                cur_n += n
+                cur_n += b.n
             groups.append((cur, cur_n))
             # each group's draw arrays, concatenated (None: one oversized
             # batch, launched in chunks)
@@ -827,9 +834,8 @@ class ColumnarPlane(DeviceRoutedPlane):
                 wh = _WindowHandle(self, self.device.dispatch(*arr))
                 off = 0
                 for b in batches:
-                    n = len(b.keys)
-                    b.handle = _WindowSlice(wh, off, n)
-                    off += n
+                    b.handle = _WindowSlice(wh, off, b.n)
+                    off += b.n
         self._win_open_rounds = 0
         self._note_window_units(units)
         self._record_window(units, _walltime.perf_counter() - t0)
@@ -1027,7 +1033,7 @@ class ColumnarPlane(DeviceRoutedPlane):
             # fall through to the coalesced host twin
             lazy_all = [b for b in self.outstanding if b.handle is None]
             if lazy_all:
-                units = sum(len(b.keys) for b in lazy_all)
+                units = sum(b.n for b in lazy_all)
                 self._note_window_units(units)
                 # both pipeline slots busy -> the host twin resolves this
                 # window (the documented two-slot bound: never queue
@@ -1067,25 +1073,29 @@ class ColumnarPlane(DeviceRoutedPlane):
                     th = np.concatenate([b.thresh for b in lazy])
                     flat = loss_flags(self.params.seed, lo, hi, npk, th)
                     lz = np.split(
-                        flat, np.cumsum([len(b.keys) for b in lazy])[:-1])
+                        flat, np.cumsum([b.n for b in lazy])[:-1])
             it = iter(lz)
         for b in take:
             if b.handle is None:
                 route = "twin"
                 flags = next(it)
-                flags_l = flags.tolist() if flags.any() else None
             elif isinstance(b.handle, _MeshHandle):
                 route = "mesh"
-                arrival_a, mflags = b.handle.read()
+                arrival_a, flags = b.handle.read()
                 b.arrival = arrival_a.tolist()
-                flags_l = mflags.tolist() if mflags.any() else None
             else:
                 route = "window"
                 r0 = _walltime.perf_counter()
                 flags = b.handle.read()
-                self._record_dev_read(_walltime.perf_counter() - r0,
-                                      len(b.keys))
-                flags_l = flags.tolist() if flags.any() else None
+                self._record_dev_read(_walltime.perf_counter() - r0, b.n)
+            if b.batch is not None:
+                # a C-resident batch: the bool array goes to the core as is
+                with span("draw_flush.store"):
+                    sent = self.units_sent
+                    self._c.store_pending(b.batch, flags, b.round_end)
+                    self.routes[route] += self.units_sent - sent
+                continue
+            flags_l = flags.tolist() if flags.any() else None
             if b.forced is not None:
                 if flags_l is None:
                     flags_l = b.forced
@@ -1122,6 +1132,21 @@ class ColumnarPlane(DeviceRoutedPlane):
             for route, n in folded["routes"].items():
                 self.routes[route] += n
             self.handoff_units += folded["handoff_units"]
+            self.resident_units += folded["resident_units"]
+            self.resident_peak = max(self.resident_peak,
+                                     folded["resident_peak"])
+
+    def pending_batches(self) -> int:
+        """Handed-off device batches the C core holds (0 after
+        flush_all)."""
+        return self._c.pending_batches() if self._c is not None else 0
+
+    def close(self) -> None:
+        """Release the C core's handed-off batches that were never
+        stored (a run torn down mid-window), then the device plumbing."""
+        if self._c is not None:
+            self._c.release_pending()
+        super().close()
 
     def _store_routed(self, route: str, rows, src_l, arrival, keys, flags,
                       round_end: SimTime) -> None:

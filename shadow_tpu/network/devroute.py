@@ -63,6 +63,8 @@ class DeviceRoutedPlane:
 
     spec_rows = 0  # speculative rows dispatched (window_units has them)
     handoff_units = 0  # units the C barrier handed to the window machinery
+    resident_units = 0  # of them, units stored from the C core's slots
+    resident_peak = 0  # most handed-off units the C core held at once
 
     def _init_routes(self) -> None:
         """Per-route counts of the units sent: they add up to units_sent,
@@ -454,6 +456,8 @@ class DeviceRoutedPlane:
             "window_units": self.dev_window_units,
             "spec_rows": self.spec_rows,
             "handoff_units": self.handoff_units,
+            "resident_units": self.resident_units,
+            "resident_peak": self.resident_peak,
             "routes": dict(self.routes),
             "spec_hits": self.spec_hits,
             "spec_draws": self.spec_draws,
@@ -509,3 +513,7 @@ class DeviceRoutedPlane:
     def earliest_outstanding(self) -> SimTime:
         """Earliest event time any in-flight draw batch can produce."""
         return min((b.deadline for b in self.outstanding), default=T_NEVER)
+
+    def pending_batches(self) -> int:
+        """Draw batches whose rows a native core holds (none here)."""
+        return 0

@@ -15,6 +15,7 @@ import hashlib
 import os
 
 import numpy as np
+import pytest
 
 from shadow_tpu.config.schema import load_config
 from shadow_tpu.core.controller import Controller
@@ -163,6 +164,75 @@ def test_blackhole_compaction_identical(tmp_path):
         return s
 
     assert go(True) == go(False)
+
+
+LOSSY_PAIR = """
+general:
+  stop_time: 1s
+network:
+  graph:
+    type: gml
+    inline: |
+      graph [
+        directed 0
+        node [ id 0 host_bandwidth_up "50 Mbit" host_bandwidth_down "50 Mbit" ]
+        node [ id 1 host_bandwidth_up "50 Mbit" host_bandwidth_down "50 Mbit" ]
+        edge [ source 0 target 1 latency "25 ms" packet_loss 0.01 ]
+        edge [ source 0 target 0 latency "5 ms" ]
+        edge [ source 1 target 1 latency "5 ms" ]
+      ]
+hosts:
+  a:
+    network_node_id: 0
+  b:
+    network_node_id: 1
+"""
+
+
+def test_handoff_teardown_releases_payloads(tmp_path):
+    """A device batch the C barrier handed off holds its rows' payload
+    refs until store_pending; a run torn down before that (close(), or
+    the core simply dropped) must give every ref back."""
+    import gc
+    import sys
+
+    import yaml
+
+    from shadow_tpu.config import parse_config
+
+    cfg = parse_config(yaml.safe_load(LOSSY_PAIR), {
+        "experimental.scheduler_policy": "tpu_batch",
+        "experimental.tpu_device_floor": -1,
+        "general.data_directory": str(tmp_path / "d"),
+    })
+    eng = Controller(cfg, mirror_log=False).engine
+    payload = bytes(bytearray(b"p" * 64))  # a fresh object of our own
+    base = sys.getrefcount(payload)
+    eng.device = object()  # stands in for an attached draw plane
+    eng.device_floor = 1
+
+    def hand_off(k):
+        for i in range(k):
+            eng._c.emit_row(0, 0, 1, 100, 0, 1, 2, 64, i, 0, 1, payload)
+        r = eng._c.barrier(0, 10**6)
+        assert isinstance(r, tuple) and r[1] == k
+        eng._dispatch_device_batch(r, 10**6)
+        return r[0]
+
+    bid = hand_off(3)
+    assert sys.getrefcount(payload) == base + 3
+    assert eng.pending_batches() == 1
+    with pytest.raises(ValueError):
+        eng._c.store_pending(bid, np.zeros(2, dtype=bool), 10**6)
+    eng.close()
+    assert eng.pending_batches() == 0
+    assert sys.getrefcount(payload) == base
+
+    hand_off(5)
+    assert sys.getrefcount(payload) == base + 5
+    del eng  # the plane, its hosts and the core go: the core's teardown
+    gc.collect()
+    assert sys.getrefcount(payload) == base
 
 
 def test_deferred_ingress_reentry(tmp_path):

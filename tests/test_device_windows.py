@@ -214,6 +214,112 @@ def test_fault_churn_with_windows_bit_identical(tmp_path):
         assert s == base_s, f"churn summary diverged at K={k}"
 
 
+GOSSIP = str(ROOT / "examples" / "gossip_churn.yaml")
+
+
+def _run_gossip(tmp_path, tag, **overrides):
+    over = {"general.data_directory": str(tmp_path / tag),
+            "general.stop_time": "10s",
+            "experimental.scheduler_policy": "tpu_batch"}
+    over.update(overrides)
+    cfg = load_config(GOSSIP, over)
+    ctl = Controller(cfg, mirror_log=False)
+    summary = ctl.run()
+    return summary, _tree(tmp_path / tag)
+
+
+@pytest.fixture(scope="module")
+def gossip_base(tmp_path_factory):
+    """The pure-Python plane with device draws off: the reference every
+    C-plane hand-off route must reproduce."""
+    s, t = _run_gossip(tmp_path_factory.mktemp("gbase"), "base",
+                       **{"experimental.tpu_device_floor": -1,
+                          "experimental.native_colcore": False})
+    return _strip(s), t
+
+
+@pytest.mark.parametrize("route", ["window", "twin", "chunk", "off"])
+def test_c_handoff_routes_bit_identical(tmp_path, monkeypatch, gossip_base,
+                                        route):
+    """Batches the C barrier hands off keep their rows in the core until
+    their flags come back: through a device window, through the numpy
+    twin when no window slot is free at flush, through an oversized batch
+    dispatched in tpu_max_batch chunks — or never, with draws off. Each
+    route stores every handed-off unit from the core
+    (resident_units == handoff_units) and reproduces the reference."""
+    from shadow_tpu.network import colplane
+
+    pytest.importorskip("shadow_tpu.native._colcore")
+    ov = {"experimental.tpu_device_floor": -1 if route == "off" else 1}
+    if route == "twin":
+        monkeypatch.setattr(colplane, "WINDOW_SLOTS", 0)
+    if route == "chunk":
+        ov["experimental.tpu_max_batch"] = 32
+        chunked = []
+        init = colplane._ConcatHandle.__init__
+
+        def spy(self, plane, handles):
+            chunked.append(len(handles))
+            init(self, plane, handles)
+
+        monkeypatch.setattr(colplane._ConcatHandle, "__init__", spy)
+    s, t = _run_gossip(tmp_path, route, **ov)
+    dev, routes = s["device"], s["device"]["routes"]
+    assert dev["resident_units"] == dev["handoff_units"]
+    if route == "off":
+        assert dev["handoff_units"] == 0 and dev["resident_peak"] == 0
+    else:
+        assert dev["handoff_units"] > 0 and dev["resident_peak"] > 0
+        assert routes["inline"] == 0  # floor 1: every live batch handed off
+    if route == "window":
+        assert routes["window"] > 0
+    if route == "twin":
+        assert s["device_windows_dispatched"] == 0
+        assert routes["twin"] > 0 and routes["window"] == 0
+    if route == "chunk":
+        assert chunked and max(chunked) > 1
+    assert t == gossip_base[1]
+    assert _strip(s) == gossip_base[0]
+
+
+def test_c_handoff_checkpoint_mid_window(tmp_path, monkeypatch, gossip_base):
+    """Checkpoints land while handed-off batches wait for their windows:
+    flush_all stores them all before the state walk, so every snapshot is
+    taken with no batch left in the C core, and a resume reproduces the
+    uninterrupted run."""
+    pytest.importorskip("shadow_tpu.native._colcore")
+    held = []
+    save = ckpt.save_checkpoint
+
+    def spy(controller, now):
+        before = controller.engine.pending_batches()
+        path = save(controller, now)
+        held.append((before, controller.engine.pending_batches()))
+        return path
+
+    monkeypatch.setattr(ckpt, "save_checkpoint", spy)
+    ov = {"experimental.tpu_device_floor": 1}
+    src_s, src_t = _run_gossip(tmp_path, "src",
+                               **{"general.checkpoint_every": "3s", **ov})
+    assert held and any(b > 0 for b, _ in held), held
+    assert all(a == 0 for _, a in held), held
+    assert src_t == gossip_base[1]
+    paths = sorted((tmp_path / "src" / "checkpoints").glob("*.ckpt"))
+    cfg = load_config(GOSSIP, {
+        "general.data_directory": str(tmp_path / "res"),
+        "general.stop_time": "10s",
+        "experimental.scheduler_policy": "tpu_batch",
+        **{k: str(v) for k, v in ov.items()},
+    })
+    ctl, resume_at = ckpt.load_checkpoint(paths[0], cfg, mirror_log=False)
+    res_s = ctl.run(resume_at=resume_at)
+    dev = res_s["device"]
+    assert dev["handoff_units"] > 0
+    assert dev["resident_units"] == dev["handoff_units"]
+    assert _tree(tmp_path / "res") == gossip_base[1]
+    assert _strip(dict(res_s)) == gossip_base[0]
+
+
 def test_device_window_rounds_config_parse():
     doc = {"general": {"stop_time": "1s"},
            "hosts": {"h": {"network_node_id": 0}}}
