@@ -5,6 +5,11 @@ with the ``flood`` traffic and seed 4, ``text`` is
 ``examples/gossip_10k.yaml`` byte for byte. The traffic mix supplies the
 originators, transactions per originator, their interval and the stop
 time; ``general.seed`` is the run's ``--seed``.
+
+What the harness reads of a configuration module: ``doc``, ``REFERENCE``
+(its plain reference, a file under ``benchmark/``), ``results`` (the
+compared readings the summary lacks, taken before the data directory is
+removed) and ``TINY`` (the sizes of the CPU tests).
 """
 
 from __future__ import annotations
@@ -13,6 +18,9 @@ import numpy as np
 import yaml
 
 from graphgen import indent, random_gml
+from reference import nodes_digest
+
+REFERENCE = "reference.py"
 
 SOURCE = ("Bitcoin Core's documented defaults: 8 outbound full-relay peers, "
           "inv/getdata/tx relay; 10k is the order of reachable Bitcoin nodes")
@@ -48,6 +56,7 @@ ASSUMED = {
 
 SIZES = {"n_nodes": 10_000, "k_peers": 8, "graph_nodes": 64,
          "graph_seed": 20260704, "port": 7000}
+TINY = {**SIZES, "n_nodes": 400}
 
 
 def text(seed: int, traffic: dict, sizes: dict = SIZES) -> str:
@@ -102,3 +111,27 @@ def text(seed: int, traffic: dict, sizes: dict = SIZES) -> str:
 def doc(seed: int, traffic: dict, sizes: dict = SIZES) -> dict:
     """The config document of one experiment."""
     return yaml.safe_load(text(seed, traffic, sizes))
+
+
+def node_results(ctl) -> tuple[list, list]:
+    """Each gossip node's (received, known), in host order: the program
+    keeps them in the node (or its C twin) and logs them only at exit.
+    A node that never started has (0, 0)."""
+    received, known = [], []
+    for h in ctl.hosts:
+        app = h.processes[0].app
+        if app is None:
+            r, k = 0, 0
+        elif app._c is not None:
+            r, k = app._c.stats()
+        else:
+            r, k = app.received_tx, len(app.seen)
+        received.append(r)
+        known.append(k)
+    return received, known
+
+
+def results(ctl, data_dir) -> dict:
+    """The compared readings the summary lacks: a digest of every node's
+    (received, known)."""
+    return {"nodes_digest": nodes_digest(*node_results(ctl))}

@@ -1,9 +1,10 @@
 #!/usr/bin/env python3
 """The control of the comparison that decides ``correct``, at a cell's own
-size: the reference put in the program's place with its loss draws one
-step below the configured Threefry-2x32-20 (13 rounds), compared with the
-reference as a window experiment is. Each seed runs in its own process;
-none touches JAX.
+size: the cell's reference (the one its config module's ``REFERENCE``
+names) put in the program's place with its loss draws one step below the
+configured Threefry-2x32-20 (``simulate(doc, rounds=13)``), compared with
+the reference as a window experiment is. Each seed runs in its own
+process; none touches JAX.
 
     python3 benchmark/control.py --workload <cell> --seeds <s1,s2,...>
 
@@ -28,10 +29,10 @@ BENCH = Path(__file__).resolve().parent
 def job(args) -> dict:
     cell_name, seed = args
     sys.path.insert(0, str(BENCH))
-    import reference
-    from run import derive_seeds, load_cell
+    from run import derive_seeds, load_cell, load_reference
 
     cell = load_cell(cell_name)
+    reference = load_reference(cell["config"])
     general = derive_seeds(seed, cell["traffic"]["seeds_per_run"])[0]
     doc = cell["config"].doc(general, cell["traffic"])
     t0 = time.perf_counter()

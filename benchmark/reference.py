@@ -40,6 +40,11 @@ takes nothing it made. It reads the document itself:
 The result of a run is what the program reports of the same experiment:
 its events, messages sent, lost and their bytes, the per-host counters,
 process errors, and each node's (received, known) pair.
+
+A configuration names its reference in ``REFERENCE``; the harness reads
+``IDENTITY_KEYS``, ``KEYS``, ``simulate``, ``mismatches`` and
+``describe`` from it. ``simulate(doc, rounds=13)`` is the control
+(``benchmark/control.py``).
 """
 
 from __future__ import annotations
@@ -399,3 +404,10 @@ def nodes_digest(received: list, known: list) -> str:
 def mismatches(results: list, ref: dict) -> dict:
     """Per compared key, how many of ``results`` differ from ``ref``."""
     return {k: sum(r[k] != ref[k] for r in results) for k in KEYS}
+
+
+def describe(ref: dict) -> str:
+    """The reference's part of the harness's ``reference:`` line."""
+    return (f"events {ref['events']}, units_sent {ref['units_sent']}, "
+            f"dropped {ref['units_dropped']}, bucket waits "
+            f"{ref['bucket_waits']}, nodes reached per tx {ref['tx_reach']}")

@@ -6,25 +6,29 @@
 
 Set-up (``setup_s``: from the start of this script to the window's start)
 builds the C engine, checks that JAX found the chip the cell asks for,
-and generates the cell's configs from ``benchmark/configs/<config>.py``
-and ``benchmark/traffic/<traffic>.json``: one for each of the traffic's
-``seeds_per_run`` values of ``general.seed``, derived from ``--seed``. It
-runs each of them for a moment (``stop_time`` cut to 1 ms), which
-attaches and calibrates that seed's draw plane in the program's process
-cache, then the first one whole: that serves or fills the compile cache.
+and generates the cell's configs with ``doc`` of the config module
+(``BENCHMARK.json``'s ``file``) from ``benchmark/traffic/<traffic>.json``:
+one for each of the traffic's ``seeds_per_run`` values of
+``general.seed``, derived from ``--seed``. It runs each of them for a
+moment (``stop_time`` cut to 1 ms), which attaches and calibrates that
+seed's draw plane in the program's process cache, then the first one
+whole: that serves or fills the compile cache.
 
 The window runs experiments back to back, cycling through the seeds --
 parse, ``Controller`` build, ``run()`` (round loop and finalize), the
-nodes' results read, teardown -- each in a fresh data directory, and
-ends at the end of the first one that finishes at or after
-``--seconds``. Every metric is all the work of its completed experiments
-over all the wall time of the window.
+config module's ``results`` read, teardown -- each in a fresh data
+directory, and ends at the end of the first one that finishes at or
+after ``--seconds``. Every metric is all the work of its completed
+experiments over all the wall time of the window.
 
 After the window, peak RSS and device memory are read, the program's
-state is freed, and the plain reference (``benchmark/reference.py``)
-simulates one of the window's seeds, drawn from ``--seed``: each of its
-experiments must equal the reference, and every experiment the first of
-its own seed.
+state is freed, and the config's plain reference (the file under
+``benchmark/`` that the config module's ``REFERENCE`` names) simulates
+one of the window's seeds, drawn from ``--seed``: each of its
+experiments must equal the reference on the reference's ``KEYS``, and
+every experiment the first of its own seed. The harness knows no model:
+what is compared, and how the reference's line reads, come from those
+two modules.
 
 With ``--trace 1`` the window runs under ``jax.profiler``, and the
 per-layer metrics (``benchmark/metrics/<name>.py``) are printed instead of
@@ -60,7 +64,9 @@ for _p in (BENCH, BENCH / "metrics"):
     if str(_p) not in sys.path:
         sys.path.insert(0, str(_p))
 
-import reference  # noqa: E402
+#: the device counters of the summary a record keeps
+DEVICE_KEYS = ("platform", "kind", "count", "routes", "handoff_units",
+               "resident_units", "resident_peak", "spec_rows", "attach")
 
 
 class BenchError(Exception):
@@ -96,6 +102,11 @@ def load_cell(name: str) -> dict:
             "traffic": json.loads(
                 (BENCH / "traffic" / f"{cell['traffic']}.json").read_text()),
             "per_layer": per_layer, "end_to_end": end_to_end}
+
+
+def load_reference(config):
+    """The plain reference that the config module names."""
+    return load_module(BENCH / config.REFERENCE)
 
 
 class CompileCounter:
@@ -176,24 +187,6 @@ def instrument(jax, Controller, clock: SpanClock) -> None:
     wrap("_finalize", "finalize")
 
 
-def node_results(ctl) -> tuple[list, list]:
-    """Each gossip node's (received, known), in host order: the program
-    keeps them in the node (or its C twin) and logs them only at exit.
-    A node that never started has (0, 0)."""
-    received, known = [], []
-    for h in ctl.hosts:
-        app = h.processes[0].app
-        if app is None:
-            r, k = 0, 0
-        elif app._c is not None:
-            r, k = app._c.stats()
-        else:
-            r, k = app.received_tx, len(app.seen)
-        received.append(r)
-        known.append(k)
-    return received, known
-
-
 def derive_seeds(seed: int, k: int) -> list:
     """The ``general.seed`` of each of a run's ``k`` configs: 31-bit
     numbers drawn from ``--seed``."""
@@ -201,8 +194,12 @@ def derive_seeds(seed: int, k: int) -> list:
                            .digest()[:4], "little") >> 1 for j in range(k)]
 
 
-def run_experiment(jax, doc: dict, data_dir: Path, clock: SpanClock) -> dict:
-    """One experiment through the program's normal entry; its record."""
+def run_experiment(jax, doc: dict, data_dir: Path, clock: SpanClock,
+                   config, reference) -> dict:
+    """One experiment through the program's normal entry; its record:
+    the compared identity (the reference's ``IDENTITY_KEYS`` of the
+    summary and the config's ``results``), timings, and the summary's
+    spans and device counters."""
     from shadow_tpu.config.schema import parse_config
     from shadow_tpu.core.controller import Controller
 
@@ -223,7 +220,7 @@ def run_experiment(jax, doc: dict, data_dir: Path, clock: SpanClock) -> dict:
     t2 = time.perf_counter()
     with ann("bench.teardown"):
         ident = {k: summary[k] for k in reference.IDENTITY_KEYS}
-        ident["nodes_digest"] = reference.nodes_digest(*node_results(ctl))
+        ident.update(config.results(ctl, data_dir))
         del ctl
         shutil.rmtree(data_dir, ignore_errors=True)
         gc.collect()
@@ -239,15 +236,18 @@ def run_experiment(jax, doc: dict, data_dir: Path, clock: SpanClock) -> dict:
         "loop_s": summary["wall_seconds"],
         "sim_s": summary["sim_seconds"],
         "phase_wall": summary["phase_wall"],
+        "phase_detail": summary["phase_detail"],
+        "phase_calls": summary["phase_calls"],
+        "process_errors": summary["process_errors"],
         "units_sent": summary["units_sent"],
         "window_units": dev.get("window_units", 0),
         "windows": summary.get("device_windows_dispatched", 0),
-        "device": {k: dev.get(k) for k in ("platform", "kind", "count")},
+        "device": {k: dev.get(k) for k in DEVICE_KEYS},
     }
 
 
-def window(jax, docs: list, seconds: float, counter,
-           clock: SpanClock) -> dict:
+def window(jax, docs: list, seconds: float, counter, clock: SpanClock,
+           config, reference) -> dict:
     """Experiments back to back, cycling through ``docs``, until the
     first that ends at or after ``seconds``."""
     exps, failed = [], 0
@@ -258,7 +258,8 @@ def window(jax, docs: list, seconds: float, counter,
             i = len(exps) + failed
             try:
                 rec = run_experiment(jax, docs[i % len(docs)],
-                                     WORK / f"exp{i}", clock)
+                                     WORK / f"exp{i}", clock, config,
+                                     reference)
             except Exception as exc:  # counted, and the run is not correct
                 failed += 1
                 say(f"experiment {i} raised {type(exc).__name__}: {exc}")
@@ -285,14 +286,15 @@ def repeats_differ(exps: list) -> list:
     return out
 
 
-def checks_of(compared: list, ref: dict, exps: list, raised: int) -> dict:
+def checks_of(reference, compared: list, ref: dict, exps: list,
+              raised: int) -> dict:
     """Each number compared, with its limit."""
     checks = {f"{k}_mismatch": {"value": v, "limit": 0}
               for k, v in reference.mismatches(compared, ref).items()}
     checks["repeat_mismatch"] = {"value": sum(repeats_differ(exps)),
                                  "limit": 0}
     checks["process_errors_seen"] = {
-        "value": sum(bool(e["identity"]["process_errors"]) for e in exps),
+        "value": sum(bool(e["process_errors"]) for e in exps),
         "limit": 0}
     checks["experiments_raised"] = {"value": raised, "limit": 0}
     checks["experiments_compared"] = {"value": len(compared), "limit": 1,
@@ -336,24 +338,30 @@ def main(argv=None) -> int:
     import shadow_tpu
     from shadow_tpu.core.controller import Controller
 
-    if not Path(shadow_tpu.__file__).resolve().is_relative_to(ROOT):
+    # absolute, not resolved: a checkout may reach the program through a
+    # link
+    if not Path(os.path.abspath(shadow_tpu.__file__)).is_relative_to(ROOT):
         raise BenchError(f"shadow_tpu imported from {shadow_tpu.__file__}")
     clock = SpanClock()
     instrument(jax, Controller, clock)
+    config = cell["config"]
+    reference = load_reference(config)
     traffic = cell["traffic"]
     seeds = derive_seeds(args.seed, traffic["seeds_per_run"])
-    docs = [cell["config"].doc(s, traffic) for s in seeds]
+    docs = [config.doc(s, traffic) for s in seeds]
     say(f"setup: general.seed of each config {seeds}")
 
     t = time.perf_counter()
     for d in docs:
         brief = copy.deepcopy(d)
         brief["general"]["stop_time"] = "1 ms"
-        run_experiment(jax, brief, WORK / "warmup", clock)
+        run_experiment(jax, brief, WORK / "warmup", clock, config,
+                       reference)
     say(f"setup: {len(docs)} draw planes attached in "
         f"{time.perf_counter() - t:.3f} s")
     t = time.perf_counter()
-    warm = run_experiment(jax, docs[0], WORK / "warmup", clock)
+    warm = run_experiment(jax, docs[0], WORK / "warmup", clock, config,
+                          reference)
     say(f"setup: warm-up experiment {time.perf_counter() - t:.3f} s, "
         f"device {json.dumps(warm['device'])}, c_engine {warm['c_engine']}, "
         f"compiles {json.dumps(counter.snapshot())}")
@@ -373,7 +381,8 @@ def main(argv=None) -> int:
         tracer = bench_trace.Tracer(jax, WORK / "trace")
         tracer.start()
     try:
-        w = window(jax, docs, args.seconds, counter, clock)
+        w = window(jax, docs, args.seconds, counter, clock, config,
+                   reference)
     finally:
         reduced = tracer.stop_and_reduce() if tracer is not None else None
     exps = w["experiments"]
@@ -394,7 +403,9 @@ def main(argv=None) -> int:
             f"{e['finalize_s']:.3f}), teardown {e['teardown_s']:.3f}; "
             f"device windows {e['windows']}, "
             f"window units {e['window_units']} of {e['units_sent']}, "
-            f"phase_wall {json.dumps(e['phase_wall'])}")
+            f"routes {json.dumps(e['device']['routes'])}, "
+            f"phase_wall {json.dumps(e['phase_wall'])}, "
+            f"phase_detail {json.dumps(e['phase_detail'])}")
 
     shutil.rmtree(WORK / "warmup", ignore_errors=True)
     raised = w["raised"]
@@ -409,12 +420,10 @@ def main(argv=None) -> int:
     compared = [e["identity"] for e in exps if e["seed"] == target]
     t = time.perf_counter()
     ref = reference.simulate(docs[seeds.index(target)])
-    checks = checks_of(compared, ref, exps, raised)
+    checks = checks_of(reference, compared, ref, exps, raised)
     drew = [e["window_units"] for e in exps if e["seed"] == target]
     say(f"reference: seed {target} in {time.perf_counter() - t:.3f} s: "
-        f"events {ref['events']}, units_sent {ref['units_sent']}, dropped "
-        f"{ref['units_dropped']}, bucket waits {ref['bucket_waits']}, "
-        f"nodes reached per tx {ref['tx_reach']}; verdict "
+        f"{reference.describe(ref)}; verdict "
         f"{'equal' if passed(checks) else 'DIFFERENT'} over "
         f"{len(compared)} experiments, whose device drew {drew} units")
 
@@ -437,7 +446,7 @@ def main(argv=None) -> int:
     result = {"correct": passed(checks),
               "attempted": len(exps) + raised,
               "failed": raised + sum(
-                  bad or bool(e["identity"]["process_errors"])
+                  bad or bool(e["process_errors"])
                   or (e["seed"] == target and any(
                       e["identity"][k] != ref[k] for k in reference.KEYS))
                   for e, bad in zip(exps, wrong)),

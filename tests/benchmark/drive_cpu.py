@@ -1,6 +1,7 @@
 """Drive benchmark/run.py on the CPU at a tiny size: the look for a chip
-is skipped and the configs are shrunk, the rest of a run is not. With a
-fault named, the timed path is broken underneath first.
+is skipped and the configs are built at their config module's ``TINY``
+sizes, the rest of a run is not. With a fault named, the timed path is
+broken underneath first.
 
     python drive_cpu.py <cell> <fault> <seconds> <work dir>
 
@@ -20,10 +21,6 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT / "benchmark"))
 sys.path.insert(0, str(ROOT))
 
-TINY = {
-    "gossip_10k.flood": {"n_nodes": 400, "k_peers": 8, "graph_nodes": 64,
-                         "graph_seed": 20260704, "port": 7000},
-}
 SEED = 2147483659
 
 
@@ -75,18 +72,20 @@ def plant(fault: str) -> None:
         raise SystemExit(f"unknown fault {fault!r}")
 
 
-def tiny_doc(mod, seed: int, traffic: dict, cell: str) -> dict:
-    """The cell's config at TINY, with every draw window sent to the
-    device (here the CPU), as most are on the chip: the C engine's own
-    host draws are out of Python's reach."""
-    doc = mod.doc(seed, traffic, TINY[cell])
+def tiny_doc(mod, seed: int, traffic: dict) -> dict:
+    """The config at its module's TINY, with every draw window sent to
+    the device (here the CPU), as most are on the chip: the C engine's
+    own host draws are out of Python's reach."""
+    doc = mod.doc(seed, traffic, mod.TINY)
     doc.setdefault("experimental", {})["tpu_device_floor"] = 1
     return doc
 
 
-def shrink(run, cell: str, work: str) -> None:
+def shrink(run, work: str) -> None:
     """Skip the look for a chip, build the cell's configs small, and work
-    in a directory of this run's own (tests drive runs side by side)."""
+    in a directory of this run's own (tests drive runs side by side). The
+    config module's ``REFERENCE``, ``results`` and ``TINY`` pass through;
+    only ``doc`` is made small."""
     def check_device(jax, chips):
         d = jax.devices()[0]
         peaks = json.loads((run.BENCH / "peaks.json").read_text())
@@ -99,7 +98,8 @@ def shrink(run, cell: str, work: str) -> None:
         c = load_cell(name)
         mod = c["config"]
         c["config"] = types.SimpleNamespace(
-            doc=lambda seed, traffic: tiny_doc(mod, seed, traffic, cell))
+            REFERENCE=mod.REFERENCE, results=mod.results, TINY=mod.TINY,
+            doc=lambda seed, traffic: tiny_doc(mod, seed, traffic))
         return c
 
     run.check_device = check_device
@@ -111,7 +111,7 @@ if __name__ == "__main__":
     cell, fault, seconds, work = sys.argv[1:5]
     import run
 
-    shrink(run, cell, work)
+    shrink(run, work)
     plant(fault)
     sys.exit(run.main(["--workload", cell, "--seed", str(SEED),
                        "--seconds", seconds, "--trace", "0"]))

@@ -1,6 +1,6 @@
 """The per-layer readers on a recorded summary: the tpu_batch auto phase of
-PR 21's chip smoke (Tor 1/10, one TPU v5 lite; chiprun_out/chip_smoke_1.json
-then), as one experiment of a window, and the chip probe trace."""
+a chip smoke run (Tor 1/10, one TPU v5 lite), as one experiment of a
+window, and the chip probe trace."""
 
 import importlib.util
 
@@ -12,6 +12,8 @@ PHASE_WALL = {"events": 3.0837, "barrier": 0.3935, "draw_flush": 0.0,
               "extract": 0.3774, "ingress_deferred": 0.0012,
               "window_build": 0.0019, "window_dispatch": 0.0035,
               "window_readback": 0.0, "transport_tick": 0.0}
+#: the C engine's timed parts (a gossip experiment's on the chip)
+PHASE_DETAIL = {"events.py_callback": 0.2712, "barrier.handoff": 0.0171}
 TRACE = {"busy_s": 5.9506e-05, "window_s": 0.039968907,
          "kernels": {"_draw_kernel": {"seconds": 51437e-9, "calls": 3,
                                       "bytes": 1197144},
@@ -22,7 +24,8 @@ TRACE = {"busy_s": 5.9506e-05, "window_s": 0.039968907,
 def ctx(trace=TRACE):
     # build 1.371 s + run 6.197 s of PR 21's row, as one window
     exp = {"wall_s": 1.371346348 + 6.196586143, "loop_s": 5.235110836,
-           "sim_s": 8.0, "phase_wall": PHASE_WALL, "units_sent": 226095,
+           "sim_s": 8.0, "phase_wall": PHASE_WALL,
+           "phase_detail": PHASE_DETAIL, "units_sent": 226095,
            "window_units": 20608}
     return {"experiments": [exp], "window_s": exp["wall_s"], "sim_s": 8.0,
             "trace": trace, "peak": {"hbm_bytes_per_s": 819e9}}
@@ -49,6 +52,7 @@ EXPECTED = {
     "draw_kernel_roofline":
         100 * ((1197144 + 262152) / 819e9) / (51437e-9 + 10944e-9),
     "device_idle_share": 100 * (1 - 5.9506e-05 / 0.039968907),
+    "py_callback_s_per_sim_s": 0.2712 / 8,
 }
 
 
@@ -70,8 +74,17 @@ def test_roofline_without_kernel_time_reads_nothing():
 
 @pytest.mark.parametrize("name", ["dispatch_s_per_sim_s",
                                   "device_unit_share",
-                                  "build_finalize_share"])
+                                  "build_finalize_share",
+                                  "py_callback_s_per_sim_s"])
 def test_empty_window_reads_nothing(name):
     c = ctx()
     c.update(experiments=[], sim_s=0.0)
     assert read(name, c) is None
+
+
+def test_py_callback_without_the_detail_reads_nothing():
+    c = ctx()
+    c["experiments"][0]["phase_detail"] = {"barrier.handoff": 0.0171}
+    assert read("py_callback_s_per_sim_s", c) is None
+    del c["experiments"][0]["phase_detail"]
+    assert read("py_callback_s_per_sim_s", c) is None

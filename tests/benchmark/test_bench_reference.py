@@ -13,7 +13,6 @@ from _bench_paths import BENCH, ROOT  # noqa: F401
 import gossip_10k  # noqa: E402
 import reference  # noqa: E402
 import run  # noqa: E402
-from drive_cpu import TINY  # noqa: E402
 
 TRAFFIC = json.loads((BENCH / "traffic" / "flood.json").read_text())
 
@@ -84,14 +83,14 @@ def test_equals_the_per_unit_plane(seed, tmp_path):
     from shadow_tpu.config.schema import parse_config
     from shadow_tpu.core.controller import Controller
 
-    doc = gossip_10k.doc(seed, TRAFFIC, TINY["gossip_10k.flood"])
+    doc = gossip_10k.doc(seed, TRAFFIC, gossip_10k.TINY)
     cfg = parse_config(copy.deepcopy(doc), {
         "general.data_directory": str(tmp_path),
         "experimental.scheduler_policy": "thread_per_core"})
     ctl = Controller(cfg, mirror_log=False)
     summary = ctl.run()
     prog = {k: summary[k] for k in reference.IDENTITY_KEYS}
-    prog["nodes_digest"] = reference.nodes_digest(*run.node_results(ctl))
+    prog.update(gossip_10k.results(ctl, tmp_path))
     ref = reference.simulate(doc)
     assert not any(reference.mismatches([prog], ref).values())
     assert ref["bucket_waits"] == 0

@@ -23,10 +23,9 @@ def test_broken_timed_path_is_not_correct(fault):
 @pytest.mark.parametrize("seed", [1, 2147483659])
 def test_control_differs_from_the_reference(seed):
     import gossip_10k
-    from drive_cpu import TINY
 
     traffic = json.loads((BENCH / "traffic" / "flood.json").read_text())
-    doc = gossip_10k.doc(seed, traffic, TINY["gossip_10k.flood"])
+    doc = gossip_10k.doc(seed, traffic, gossip_10k.TINY)
     ref = reference.simulate(doc)
     again = reference.simulate(doc)
     ctl = reference.simulate(doc, rounds=13)
