@@ -413,6 +413,15 @@ typedef struct {
   int64_t pb_next;
   int64_t res_units, res_held, res_peak;
   int64_t n_emit, n_gossip;
+  /* self time of the endpoint and tor-relay sections (sect_enter/leave):
+   * events.stream (CEp arrival handling) and events.relay (relay, exit
+   * and client-sink framing and forwarding); a Python call bracketed by
+   * py_enter inside them (a control cell handed to the model) is charged
+   * to neither. Counts of control cells handed to Python and of client
+   * circuits (C sinks) made. */
+  int64_t sect_ns[3], sect_calls[3], sect_t;
+  int sect_cur;
+  int64_t n_ctrl_py, n_circuits;
   /* where the C barrier's sent units were drawn (devroute.ROUTES) */
   int64_t route_inline, route_spec_hit, route_none;
   int32_t *spec_dq; /* demand queue: host ids awaiting a window */
@@ -434,15 +443,41 @@ typedef struct {
 } CoreObject;
 
 /* time the Python call a pair of these brackets (the outermost one, when
- * a callable reaches back into another) */
+ * a callable reaches back into another); a section running around it
+ * pauses for its length, on the same clock reads */
 static inline int64_t py_enter(CoreObject *c) {
-  return c->py_depth++ ? 0 : nsnow();
+  if (c->py_depth++) return 0;
+  int64_t t = nsnow();
+  if (c->sect_cur) c->sect_ns[c->sect_cur] += t - c->sect_t;
+  return t;
 }
 static inline void py_leave(CoreObject *c, int64_t t0) {
   if (--c->py_depth == 0) {
-    c->py_ns += nsnow() - t0;
+    int64_t t = nsnow();
+    c->py_ns += t - t0;
     c->py_calls++;
+    c->sect_t = t;
   }
+}
+
+/* section self time: each switch reads the clock once and charges the
+ * elapsed time to the section it leaves (sections nest: the relay's
+ * forwarding runs inside the endpoint's arrival handling) */
+enum { SECT_NONE, SECT_STREAM, SECT_RELAY };
+static inline int sect_enter(CoreObject *c, int sect) {
+  int64_t t = nsnow();
+  int prev = c->sect_cur;
+  if (prev) c->sect_ns[prev] += t - c->sect_t;
+  c->sect_t = t;
+  c->sect_cur = sect;
+  c->sect_calls[sect]++;
+  return prev;
+}
+static inline void sect_leave(CoreObject *c, int prev) {
+  int64_t t = nsnow();
+  c->sect_ns[c->sect_cur] += t - c->sect_t;
+  c->sect_t = t;
+  c->sect_cur = prev;
 }
 
 /* per-host speculative window + npkts class tracker. Two generations:
@@ -2703,13 +2738,15 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
     Py_XDECREF(ctrs);
   }
   PyObject *out = Py_BuildValue(
-      "{s{s(dL)s(dL)}s{sLsL}s{sLsLsL}sLsLsL}",
+      "{s{s(dL)s(dL)}s{sLsLsLsL}s{sLsLsL}sLsLsL}",
       "timers",
       "events.py_callback", c->py_ns / 1e9, (long long)c->py_calls,
       "barrier.handoff", c->handoff_ns / 1e9, (long long)c->handoff_calls,
       "counts",
       "events.emit", (long long)c->n_emit,
       "events.gossip", (long long)c->n_gossip,
+      "tor.ctrl_to_py", (long long)c->n_ctrl_py,
+      "tor.circuits", (long long)c->n_circuits,
       "routes",
       "inline", (long long)c->route_inline,
       "spec_hit", (long long)c->route_spec_hit,
@@ -2718,11 +2755,28 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
       "resident_units", (long long)c->res_units,
       "resident_peak", (long long)c->res_peak);
   if (!out) return NULL;
+  /* the endpoint and relay sections only where they ran (a gossip run
+   * has neither) */
+  static const char *sect_names[3] = {NULL, "events.stream", "events.relay"};
+  PyObject *timers = PyDict_GetItemString(out, "timers");
+  for (int j = SECT_STREAM; j <= SECT_RELAY; j++) {
+    if (!c->sect_calls[j]) continue;
+    PyObject *v = Py_BuildValue("(dL)", c->sect_ns[j] / 1e9,
+                                (long long)c->sect_calls[j]);
+    if (!v || PyDict_SetItemString(timers, sect_names[j], v) < 0) {
+      Py_XDECREF(v);
+      Py_DECREF(out);
+      return NULL;
+    }
+    Py_DECREF(v);
+  }
   c->py_ns = c->py_calls = 0;
   c->handoff_ns = c->handoff_calls = c->handoff_units = 0;
   c->res_units = 0;
   c->res_peak = c->res_held;
   c->n_emit = c->n_gossip = 0;
+  for (int j = 0; j < 3; j++) c->sect_ns[j] = c->sect_calls[j] = 0;
+  c->n_ctrl_py = c->n_circuits = 0;
   c->route_inline = c->route_spec_hit = c->route_none = 0;
   return out;
 }
@@ -3656,12 +3710,14 @@ static int cs_on_ack(CEp *e, int64_t now, int64_t cum_ack, int64_t wnd,
       }
     }
     cc_on_ack(e, newly, now);
-    if (e->sink && e->buffered < e->send_buffer) {
-      if (relay_drain(e->sink, now) < 0) return -1;
-    } else if (e->tsink && e->buffered < e->send_buffer) {
-      /* the tor-client control plane's pending-write queue (the
-       * _WriteConn on_drain pump twin) */
-      if (tsink_pump(e->tsink, now) < 0) return -1;
+    if ((e->sink || e->tsink) && e->buffered < e->send_buffer) {
+      /* a relay conn's drain, or the tor-client control plane's
+       * pending-write queue (the _WriteConn on_drain pump twin) */
+      CoreObject *c = e->core;
+      int prev = sect_enter(c, SECT_RELAY);
+      int r = e->sink ? relay_drain(e->sink, now) : tsink_pump(e->tsink, now);
+      sect_leave(c, prev);
+      if (r < 0) return -1;
     } else if (e->tgen_mode == 1 && e->buffered < e->send_buffer) {
       /* TGenServer on_drain twin (push is a no-op with no backlog,
        * exactly like the Python closure called with room) */
@@ -3832,10 +3888,15 @@ static int cr_deliver(CEp *e, int64_t now, int64_t nbytes,
                       PyObject *payload) {
   e->rcv_nxt += nbytes;
   e->bytes_received += nbytes;
-  if (e->tsink)
-    return tsink_feed(e->tsink, nbytes, payload);
-  if (e->xsink)
-    return exit_feed((struct CExitStream *)e->xsink, now, nbytes);
+  if (e->tsink || e->xsink || (e->sink && e->tgen_mode == 0)) {
+    CoreObject *c = e->core;
+    int prev = sect_enter(c, SECT_RELAY);
+    int r = e->tsink ? tsink_feed(e->tsink, nbytes, payload)
+            : e->xsink ? exit_feed((struct CExitStream *)e->xsink, now, nbytes)
+                       : relay_feed(e->sink, now, nbytes, payload);
+    sect_leave(c, prev);
+    return r;
+  }
   if (e->tgen_mode == 2) {
     if (e->tgen_t_first < 0) e->tgen_t_first = now;
     e->tgen_pending += nbytes;
@@ -3850,8 +3911,6 @@ static int cr_deliver(CEp *e, int64_t now, int64_t nbytes,
   }
   if (e->tgen_mode == 1)
     return tgen_srv_data(e, now, payload);
-  if (e->sink)
-    return relay_feed(e->sink, now, nbytes, payload);
   if (e->on_data && e->on_data != Py_None) {
     PyObject *nb = PyLong_FromLongLong(nbytes);
     PyObject *tn = PyLong_FromLongLong(now);
@@ -4890,8 +4949,12 @@ static int dispatch_stream(CoreObject *c, CHost *h, int hid, IRow *ir,
     return 0;
   }
   Py_DECREF(key);
-  if (Py_TYPE(ep) == &CEp_Type)
-    return ce_handle_fields((CEp *)ep, *now, k, ir->nbytes, pl, ir->seq);
+  if (Py_TYPE(ep) == &CEp_Type) {
+    int prev = sect_enter(c, SECT_STREAM);
+    int r = ce_handle_fields((CEp *)ep, *now, k, ir->nbytes, pl, ir->seq);
+    sect_leave(c, prev);
+    return r;
+  }
   /* Python endpoint on a C-dispatched host (shouldn't happen in
    * practice, but stay correct): sync the clock and delegate */
   if (*now_dirty) {
@@ -5185,8 +5248,12 @@ static int relay_on_cell(CRelayObj *r, CRelayConn *rc, int64_t now,
      * plane — connecting through the simulated network — is Python's */
     PyObject *plo = PyBytes_FromStringAndSize(pl, plen);
     if (!plo) return -1;
+    CoreObject *c = r->core;
+    c->n_ctrl_py++;
+    int64_t t0 = py_enter(c);
     PyObject *res = PyObject_CallFunction(r->on_ctrl, "(iiiO)", rc->cid,
                                           ctype, circ, plo);
+    py_leave(c, t0);
     Py_DECREF(plo);
     if (!res) return -1;
     Py_DECREF(res);
@@ -5734,8 +5801,12 @@ static int tsink_feed(CTorSink *s, int64_t nbytes, PyObject *payload) {
           PyObject *pl = PyBytes_FromStringAndSize(
               s->buf + off + TCELL_HDR, (Py_ssize_t)ln);
           if (!pl) { rcod = -1; break; }
+          CoreObject *c = s->ep->core;
+          c->n_ctrl_py++;
+          int64_t t0 = py_enter(c);
           PyObject *r = PyObject_CallFunction(s->on_cell, "iiNL", ctype,
                                               circ, pl, (long long)s->got);
+          py_leave(c, t0);
           if (!r) { rcod = -1; break; }
           Py_DECREF(r);
         }
@@ -5757,8 +5828,12 @@ static int tsink_feed(CTorSink *s, int64_t nbytes, PyObject *payload) {
     PyObject *pl = PyBytes_FromStringAndSize(s->buf + off + TCELL_HDR,
                                              (Py_ssize_t)ln);
     if (!pl) { rcod = -1; break; }
+    CoreObject *c = s->ep->core;
+    c->n_ctrl_py++;
+    int64_t t0 = py_enter(c);
     PyObject *r = PyObject_CallFunction(s->on_cell, "iiNL", ctype, circ,
                                         pl, (long long)s->got);
+    py_leave(c, t0);
     if (!r) { rcod = -1; break; }
     Py_DECREF(r);
     off += TCELL_HDR + ln;
@@ -5858,7 +5933,6 @@ static PyTypeObject CTorSink_Type = {
 };
 
 static PyObject *Core_tor_client_sink(CoreObject *c, PyObject *args) {
-  (void)c;
   PyObject *ep_o, *on_cell, *frames = Py_None;
   if (!PyArg_ParseTuple(args, "OO|O", &ep_o, &on_cell, &frames))
     return NULL;
@@ -5890,6 +5964,7 @@ static PyObject *Core_tor_client_sink(CoreObject *c, PyObject *args) {
   }
   s->pend.esz = sizeof(PendEnt);
   s->ep->tsink = s;
+  c->n_circuits++;
   PyObject_GC_Track((PyObject *)s);
   return (PyObject *)s;
 }

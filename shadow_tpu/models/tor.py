@@ -22,6 +22,12 @@ Cell types: CREATE, CREATED, EXTEND, EXTENDED, BEGIN, CONNECTED, DATA, END.
 
 from __future__ import annotations
 
+import math
+from bisect import bisect_left, bisect_right
+from functools import lru_cache
+from itertools import accumulate
+
+from shadow_tpu.core.time import NS_PER_MS
 from shadow_tpu.utils.units import parse_size
 
 HDR = 12
@@ -343,7 +349,7 @@ class TorExit(TorRelay):
 
 class TorClient:
     """args: [n_relays, relay_port, server, server_port, size, circuits,
-              n_exits?]
+              n_exits?, relays?, schedule?]
 
     Relay hosts must be named relay0..relayN-1; when ``n_exits`` is given,
     relay0..relay{n_exits-1} are the exit-capable population (the
@@ -352,6 +358,29 @@ class TorClient:
     it, every relay is assumed exit-capable (the pre-round-4 behavior).
     The client telescopes guard->middle->exit, BEGINs a fetch of `size`
     bytes from `server`, and records completion.
+
+    ``relays`` ("<weight>:<flags>,..." for relay0..relayN-1, flags the
+    letters G and E; ``n_exits`` must then be 0, the E flags naming the
+    exits) switches path choice to Tor's
+    bandwidth weighting by position: the exit from the E relays, then the
+    guard from the G relays, then the middle from all relays, each among
+    the relays not yet on the circuit, with probability proportional to
+    the integer weight: ``rng.integers(0, total)`` over the cumulative
+    weights, then a bisect.
+
+    ``schedule`` (";"-separated ``key=value``) turns the circuits into
+    fetches made one after another, each on a fresh circuit:
+    ``delay=uniform:<lo>:<hi>`` waits a drawn [lo, hi) ms before the
+    first; ``sizes=<b>:<b>:...`` fixes each fetch's size (their count is
+    the number of fetches), or ``size=lognormal:<median>:<sigma>:<lo>:<hi>``
+    draws one per fetch (``circuits`` of them), clipped to [lo, hi];
+    ``think=uniform:<lo>:<hi>`` or ``think=fixed:<ms>`` separates them.
+    A fetch draws its size, then its path; a finished fetch draws the
+    think time. Every fetch appends ``(guard, middle, exit, size, got,
+    build_ns, done_ns)`` to ``fetches`` (times from the fetch's start;
+    build_ns -1 if telescoping never finished). ``fetch_records()`` adds
+    the fetches still open, as they stand, with done_ns -1: a run's stop
+    can cut them.
     """
 
     def __init__(self, api, args, env):
@@ -363,15 +392,44 @@ class TorClient:
         self.size = parse_size(args[4]) if len(args) > 4 else 100_000
         self.n_circuits = int(args[5]) if len(args) > 5 else 1
         self.n_exits = int(args[6]) if len(args) > 6 else self.n_relays
+        self.weights = relay_table(args[7]) if len(args) > 7 else None
+        if self.weights is not None and (
+                self.n_exits or len(self.weights.weight) != self.n_relays):
+            raise ValueError("a relay table takes n_exits 0 and one entry "
+                             "per relay")
+        self.schedule = fetch_schedule(args[8]) if len(args) > 8 else None
+        if self.schedule is not None and self.schedule["sizes"]:
+            self.n_circuits = len(self.schedule["sizes"])
         self.completed = 0
         self.failed = 0
         self.attempted = 0
         self.completion_times = []  # ns, fetch end-to-end (incl. build)
         self.build_times = []  # ns, telescoping (CREATE..last EXTENDED)
+        self.fetches = []  # per-fetch records (see the class docstring)
+        self._open = {}  # attempt -> its open fetch's record as it stands
 
     def start(self):
-        for _ in range(self.n_circuits):
-            self._build_circuit()
+        sch = self.schedule
+        if sch is None:
+            for _ in range(self.n_circuits):
+                self._build_circuit()
+            return
+        lo, hi = sch["delay"]
+        if hi > lo:
+            self.api.after(int(self.api.rng.integers(lo, hi)), self._fetch)
+        else:
+            self._fetch()
+
+    def _fetch(self):
+        """The next fetch of the schedule, on a fresh circuit."""
+        sch = self.schedule
+        if sch["sizes"]:
+            size = sch["sizes"][self.attempted]
+        else:
+            median, sigma, lo, hi = sch["size"]
+            size = int(self.api.rng.lognormal(math.log(median), sigma))
+            size = min(max(size, lo), hi)
+        self._build_circuit(size)
 
     def _pick_hops(self):
         # exit drawn FIRST (from the exit-capable population), then
@@ -379,18 +437,26 @@ class TorClient:
         # other order can spin forever when every exit is already a
         # guard/middle (e.g. n_exits=1)
         rng = self.api.rng
+        if self.weights is not None:
+            ex = self.weights.draw(rng, "E", ())
+            guard = self.weights.draw(rng, "G", (ex,))
+            middle = self.weights.draw(rng, "", (ex, guard))
+            return [guard, middle, ex]
         exit_r = int(rng.integers(0, self.n_exits))
         hops = [exit_r]
         while len(hops) < 3:
             r = int(rng.integers(0, self.n_relays))
             if r not in hops:
                 hops.append(r)
-        return [f"relay{hops[1]}", f"relay{hops[2]}", f"relay{exit_r}"]
+        return [hops[1], hops[2], exit_r]
 
-    def _build_circuit(self):
+    def _build_circuit(self, size=None):
         api = self.api
-        hops = self._pick_hops()
+        size = self.size if size is None else size
+        idx = self._pick_hops()
+        hops = [f"relay{i}" for i in idx]
         self.attempted += 1
+        attempt = self.attempted
         t0 = api.now
         circ = 1
         # stage: hops established so far (guard = 1); bd: telescoping-done
@@ -410,11 +476,18 @@ class TorClient:
             else:
                 conn.write(cell(
                     BEGIN, circ,
-                    f"{self.server}:{self.server_port}:{self.size}".encode()))
+                    f"{self.server}:{self.server_port}:{size}".encode()))
+
+        def record(got, elapsed):
+            bd = state["bd"]
+            self._open.pop(attempt, None)
+            self.fetches.append((*idx, size, got,
+                                 -1 if bd is None else bd - t0, elapsed))
 
         def finish_fetch(got):
             elapsed = api.now - t0
-            if got >= self.size:
+            record(got, elapsed)
+            if got >= size:
                 self.completed += 1
                 self.completion_times.append(elapsed)
                 api.log(f"circuit-complete hops={hops} bytes={got} "
@@ -424,7 +497,7 @@ class TorClient:
             if tel is not None:
                 api._host.record_flow(
                     "tor_fetch", self.server, t0, state["bd"], got,
-                    "ok" if got >= self.size else "error",
+                    "ok" if got >= size else "error",
                     retx=int(ep.sender.loss_events))
             ep.close()
             self._finish()
@@ -455,7 +528,7 @@ class TorClient:
                 cell(EXTEND, circ, f"{hops[1]}:{self.relay_port}".encode()),
                 cell(EXTEND, circ, f"{hops[2]}:{self.relay_port}".encode()),
                 cell(BEGIN, circ,
-                     f"{self.server}:{self.server_port}:{self.size}"
+                     f"{self.server}:{self.server_port}:{size}"
                      .encode()),
             )
 
@@ -467,6 +540,9 @@ class TorClient:
                     state["bd"] = api.now
 
             conn = make_sink(ep, on_ctrl_c, frames)
+
+            def received():
+                return conn.bytes_received()
         else:
             got = {"n": 0}
 
@@ -478,10 +554,21 @@ class TorClient:
 
             conn = _Conn(ep, on_cell, on_body)
 
+            def received():
+                return got["n"]
+
+        def open_record():
+            bd = state["bd"]
+            return (*idx, size, received(), -1 if bd is None else bd - t0,
+                    -1)
+
+        self._open[attempt] = open_record
+
         def on_connected(now):
             conn.write(cell(CREATE, circ))
 
         def on_error(msg):
+            record(0, api.now - t0)
             self.failed += 1
             api.log(f"circuit-failed hops={hops}: {msg}")
             if tel is not None:
@@ -500,6 +587,85 @@ class TorClient:
             self.api.log(
                 f"tor client done: {self.completed}/{self.n_circuits} ok")
             self.api.exit(0 if self.failed == 0 else 1)
+        elif self.schedule is not None:
+            kind, lo, hi = self.schedule["think"]
+            wait = lo if kind == "fixed" else int(
+                self.api.rng.integers(lo, hi))
+            self.api.after(wait, self._fetch)
+
+    def fetch_records(self) -> list:
+        """``fetches``, then each open fetch's record as it stands, in
+        the order they began."""
+        return [*self.fetches, *(f() for f in self._open.values())]
 
     def stop(self):
         pass
+
+
+class RelayTable:
+    """Integer path-selection weights and Guard/Exit flags of
+    relay0..relayN-1, with the cumulative weights of each position's
+    eligible relays (E: exits, G: guards, "": every relay)."""
+
+    def __init__(self, weights: list, flags: list) -> None:
+        self.weight = weights
+        self.elig = {}
+        self.cum = {}
+        for pos in ("E", "G", ""):
+            ids = [i for i, f in enumerate(flags) if pos in f]
+            if not ids:
+                raise ValueError(f"no relay carries flag {pos!r}")
+            self.elig[pos] = ids
+            self.cum[pos] = list(accumulate(weights[i] for i in ids))
+
+    def draw(self, rng, pos: str, chosen: tuple) -> int:
+        """One relay of position ``pos`` not in ``chosen``, with
+        probability proportional to its weight."""
+        ids, cum, w = self.elig[pos], self.cum[pos], self.weight
+        out = []  # the chosen relays eligible here, by place in ``ids``
+        for c in chosen:
+            j = bisect_left(ids, c)
+            if j < len(ids) and ids[j] == c:
+                out.append(j)
+        out.sort()
+        r = int(rng.integers(0, cum[-1] - sum(w[ids[j]] for j in out)))
+        for j in out:  # step over each chosen relay's span of weight
+            if r >= cum[j] - w[ids[j]]:
+                r += w[ids[j]]
+        return ids[bisect_right(cum, r)]
+
+
+@lru_cache(maxsize=4)
+def relay_table(spec: str) -> RelayTable:
+    """The ``relays`` argument, parsed once per process (every client of
+    a run passes the same string; the table is never changed)."""
+    pairs = [e.split(":") for e in spec.split(",")]
+    return RelayTable([int(w) for w, _ in pairs], [f for _, f in pairs])
+
+
+def fetch_schedule(spec: str) -> dict:
+    """The ``schedule`` argument: delays and think times in ns."""
+    kv = dict(item.split("=", 1) for item in spec.split(";") if item)
+    out = {"delay": (0, 0), "sizes": None, "size": None,
+           "think": ("fixed", 0, 0)}
+    for key, val in kv.items():
+        parts = val.split(":")
+        if key == "delay":
+            out["delay"] = (int(parts[1]) * NS_PER_MS,
+                            int(parts[2]) * NS_PER_MS)
+        elif key == "sizes":
+            out["sizes"] = [int(p) for p in parts]
+        elif key == "size":
+            out["size"] = (int(parts[1]), float(parts[2]), int(parts[3]),
+                           int(parts[4]))
+        elif key == "think":
+            if parts[0] == "fixed":
+                out["think"] = ("fixed", int(parts[1]) * NS_PER_MS, 0)
+            else:
+                out["think"] = ("uniform", int(parts[1]) * NS_PER_MS,
+                                int(parts[2]) * NS_PER_MS)
+        else:
+            raise ValueError(f"unknown schedule key {key!r}")
+    if out["sizes"] is None and out["size"] is None:
+        raise ValueError("a schedule needs sizes= or size=")
+    return out

@@ -202,3 +202,57 @@ def test_thread_policy_routes_add_up(tmp_path):
         "experimental.scheduler_policy": "thread_per_core"})
     _check_partition(s)
     assert set(s["phase_wall"]) >= {"events", "gc"}
+
+
+def _bench_doc(config: str, traffic: str, **sizes) -> dict:
+    """A benchmark configuration's document at its CPU size."""
+    import importlib.util
+    import json
+    import sys
+
+    bench = ROOT / "benchmark"
+    if str(bench) not in sys.path:
+        sys.path.append(str(bench))
+    spec = importlib.util.spec_from_file_location(
+        config, bench / "configs" / f"{config}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    tr = json.loads((bench / "traffic" / f"{traffic}.json").read_text())
+    return mod.doc(5, tr, {**mod.TINY, **sizes})
+
+
+def _run_doc(tmp_path, doc, tag):
+    from shadow_tpu.config.schema import parse_config
+
+    return Controller(parse_config(doc, {
+        "general.data_directory": str(tmp_path / tag),
+        "experimental.scheduler_policy": "tpu_batch"}),
+        mirror_log=False).run()
+
+
+def test_gossip_runs_no_stream_or_relay_section(tmp_path):
+    """Datagrams only: neither C section runs, and neither is reported."""
+    doc = _bench_doc("gossip_10k", "flood")
+    doc["general"]["stop_time"] = "5s"
+    s = _run_doc(tmp_path, doc, "gossip")
+    _check_partition(s)
+    assert not {"events.stream", "events.relay"} & set(s["phase_detail"])
+    assert "tor.circuits" not in s["phase_calls"]
+
+
+def test_tor_sections_partition_the_loop(tmp_path):
+    """A small Tor run of the benchmark's configuration: the C endpoints'
+    and relays' sections join phase_detail as parts of ``events``, with
+    the control cells handed to Python and the circuits counted, and the
+    phases still partition the loop."""
+    s = _run_doc(tmp_path, _bench_doc("tor_1_10", "web20k", web_clients=12,
+                                      perf_clients=0), "tor")
+    assert s["process_errors"] == []
+    _check_partition(s)
+    pd, calls = s["phase_detail"], s["phase_calls"]
+    assert pd["events.stream"] > 0 and pd["events.relay"] > 0
+    assert calls["events.stream"] > calls["events.relay"] > 0
+    # 24 circuits: each hands Python its telescoping's end and its END
+    # at the client, and its EXTENDs and BEGIN at the relays
+    assert calls["tor.circuits"] == 24
+    assert calls["tor.ctrl_to_py"] == 24 * 5

@@ -63,11 +63,11 @@ hosts:
 """
 
 
-def _run(tmp_path, tag, **overrides):
+def _run(tmp_path, tag, text=TOR_CFG, **overrides):
     dd = tmp_path / tag
     ov = {"general.data_directory": str(dd), "telemetry": {}}
     ov.update(overrides)
-    cfg = parse_config(yaml.safe_load(TOR_CFG), ov)
+    cfg = parse_config(yaml.safe_load(text), ov)
     ctl = Controller(cfg, mirror_log=False)
     summary = ctl.run()
     assert summary["process_errors"] == []
@@ -80,14 +80,55 @@ def _run(tmp_path, tag, **overrides):
     return ctl, _strip(summary), _tree(dd), streams
 
 
+#: the same network with bandwidth-weighted paths (Guard/Exit flags on
+#: relay0..relay5) and a schedule of three sequential fetches per client
+SCHEDULE_CFG = TOR_CFG.replace(
+    'args: ["6", "9001", web, "80", "150 kB", "2"]',
+    'args: ["6", "9001", web, "80", "0", "3", "0", '
+    '"5000:GE,20000:G,8000:E,12000:GE,3000:,9000:E", '
+    '"delay=uniform:0:2000;size=lognormal:20000:1.0:1024:200000;'
+    'think=uniform:500:1500"]')
+
+
 def test_tor_identity_across_policies_and_planes(tmp_path):
     """Output trees, summaries, and telemetry streams byte-identical with
     the C tor control plane on vs off and across all scheduler policies.
     The C-sink run must actually have exercised the C control plane
     (guards against a silent fallback making this test vacuous)."""
+    _identity_across_policies_and_planes(tmp_path, TOR_CFG)
+
+
+def test_tor_schedule_identity_across_policies_and_planes(tmp_path):
+    """The same gate for weighted paths and fetch schedules: every
+    client's per-fetch records are identical too."""
+    records = _identity_across_policies_and_planes(tmp_path, SCHEDULE_CFG)
+    for rec in records[1:]:
+        assert rec == records[0]
+    assert all(len(f) == 3 and all(r[4] == r[3] for r in f)
+               for f in records[0])
+
+
+def test_tor_schedule_cut_at_stop_identical(tmp_path):
+    """A stop that cuts fetches: each open fetch's record as it stands
+    (DATA bytes counted so far, done_ns -1) is identical on every plane,
+    the C sink's count included."""
+    text = SCHEDULE_CFG.replace("stop_time: 30s", "stop_time: 4s")
+    text = text.replace("        expected_final_state: {exited: 0}\n", "")
+    text = text.replace("size=lognormal:20000:1.0:1024:200000",
+                        "size=lognormal:2000000:0.5:500000:4000000")
+    records = _identity_across_policies_and_planes(tmp_path, text)
+    for rec in records[1:]:
+        assert rec == records[0]
+    cut = [r for f in records[0] for r in f if r[6] == -1]
+    assert any(0 < r[4] < r[3] for r in cut), records[0]
+
+
+def _identity_across_policies_and_planes(tmp_path, text):
+    """Run ``text`` under every plane and policy; the clients' per-fetch
+    records of each run."""
     ctl_c, s_c, t_c, f_c = _run(
-        tmp_path, "c", **{"experimental.scheduler_policy": "tpu_batch",
-                          "experimental.native_colcore": True})
+        tmp_path, "c", text, **{"experimental.scheduler_policy": "tpu_batch",
+                                "experimental.native_colcore": True})
     # the C control plane really ran: the model's engagement gate is
     # exactly (core exposes tor_client_sink) and (host.pcap is None) —
     # assert both so a silent fallback to the Python closures cannot
@@ -100,18 +141,21 @@ def test_tor_identity_across_policies_and_planes(tmp_path):
     assert clients, "no tor clients found"
 
     runs = [
-        _run(tmp_path, "py",
+        _run(tmp_path, "py", text,
              **{"experimental.scheduler_policy": "tpu_batch",
                 "experimental.native_colcore": False}),
-        _run(tmp_path, "tpc",
+        _run(tmp_path, "tpc", text,
              **{"experimental.scheduler_policy": "thread_per_core"}),
-        _run(tmp_path, "tph",
+        _run(tmp_path, "tph", text,
              **{"experimental.scheduler_policy": "thread_per_host"}),
     ]
     for _ctl, s, t, f in runs:
         assert s == s_c
         assert t == t_c
         assert f == f_c
+    return [[p.app.fetch_records() for h in ctl.hosts for p in h.processes
+             if type(p.app).__name__ == "TorClient"]
+            for ctl, *_ in [(ctl_c,), *runs]]
 
 
 def test_tor_digest_stream_identical_across_policies(tmp_path):

@@ -103,12 +103,14 @@ def tornet_gml(rng, g, path) -> None:
 
 def tor_doc(n_relays: int, n_clients: int, stop_s: int,
              fetch: str = "20 kB") -> dict:
-    """Config #5 generator (BASELINE.md): onion-routing at tornettools
-    shape — TorRelay/TorExit relays, TGen web servers, TorClients
-    building 3-hop circuits and fetching through them, on a 64-node
-    random graph. Deterministic from the fixed seed. ``tor_doc(700,
-    10_000, 8)`` is "tor 1/10": 10,720 hosts, the chip_smoke.py
-    workload."""
+    """Config #5 generator (BASELINE.md): the uniform smoke shape of
+    onion routing — TorRelay/TorExit relays, TGen web servers, TorClients
+    building one 3-hop circuit each, hops drawn uniformly (exits from the
+    first n_relays // 8 relays), one fetch at start, on a 64-node random
+    graph. Deterministic from the fixed seed. ``tor_doc(700, 10_000, 8)``
+    is the chip_smoke.py workload: 10,720 hosts. The tornettools-shaped
+    deployment (bandwidth-weighted Guard/Exit relays, web and OnionPerf
+    fetch schedules) is ``benchmark/configs/tor_1_10.py``."""
     rng = np.random.default_rng(42)
     g = 64
     gml = random_gml(rng, g, min_lat_ms=10, max_lat_ms=120, max_loss=0.002,
