@@ -33,6 +33,7 @@ import numpy as np
 
 from shadow_tpu.config.schema import ConfigOptions
 from shadow_tpu.core import spans as _spans
+from shadow_tpu.core.loop_gc import LoopGC
 from shadow_tpu.core.scheduler import make_scheduler
 from shadow_tpu.core.time import NS_PER_SEC, NS_PER_US, SimTime, T_NEVER, format_time
 from shadow_tpu.host.host import Host
@@ -45,8 +46,6 @@ from shadow_tpu.utils.logging import SimLogger
 from shadow_tpu.utils.units import parse_bandwidth
 
 DEFAULT_BANDWIDTH = parse_bandwidth("1 Gbit")
-#: rounds between explicit gc.collect() calls while auto-GC is suspended
-_GC_EVERY_ROUNDS = 5000
 
 #: run-summary keys that are wall-clock / routing telemetry rather than
 #: simulation state — strip these when diffing summaries for determinism
@@ -639,16 +638,6 @@ class Controller:
                     installed[s] = _signal.signal(s, self._on_signal)
                 except (ValueError, OSError):
                     pass
-        # the round loop allocates millions of short-lived objects (units,
-        # arrival closures, heap entries); generational GC scanning them
-        # costs ~40% of wall at 10k-host scale (measured, gossip config).
-        # Collect at fixed round intervals instead — reference cycles (e.g.
-        # endpoint<->sender) from closed connections still get reclaimed.
-        import gc as _gc
-
-        gc_was_enabled = _gc.isenabled()
-        _gc.disable()
-        next_gc = _GC_EVERY_ROUNDS
         # chaos harness (shadow_tpu/supervise.py): deterministic-round
         # fault injection, armed only through the environment — one dict
         # probe per run when off, one int compare per round when on
@@ -667,32 +656,31 @@ class Controller:
             t0 = _walltime.perf_counter()
             dyn = cfg.experimental.use_dynamic_runahead
             faults = self.faults
-            try:
-                now = self._round_loop(now, stop, w, dyn, faults, next_hb,
-                                       hb_interval, next_prog, prog_step,
-                                       next_gc, next_ckpt, ck_every, dig,
-                                       _ckpt, tel, t0)
-            finally:
-                for s, old in installed.items():
-                    _signal.signal(s, old)
-            self._partial = self._interrupt is not None and now < stop
-            if self._partial:
-                self.log.warning(
-                    f"{self._interrupt} received: stopped gracefully at "
-                    f"round boundary {format_time(now)} ({self.rounds} "
-                    f"rounds); summary is partial")
-                if ck_every:
-                    with rec.span("checkpoint"):
-                        path = _ckpt.save_checkpoint(self, now)
-                    self.log.info(f"final checkpoint written: {path}")
-                    if self.live is not None:
-                        self.live.publish({"type": "checkpoint",
-                                           "path": str(path), "t": now,
-                                           "round": self.rounds})
-            if gc_was_enabled:
-                _gc.enable()
-            with rec.span("gc"):
-                _gc.collect()
+            # core/loop_gc.py: automatic GC off and the pre-loop heap
+            # frozen; the last collect runs as the block ends
+            with LoopGC(rec, self.rounds) as lgc:
+                try:
+                    now = self._round_loop(now, stop, w, dyn, faults,
+                                           next_hb, hb_interval, next_prog,
+                                           prog_step, lgc, next_ckpt,
+                                           ck_every, dig, _ckpt, tel, t0)
+                finally:
+                    for s, old in installed.items():
+                        _signal.signal(s, old)
+                self._partial = self._interrupt is not None and now < stop
+                if self._partial:
+                    self.log.warning(
+                        f"{self._interrupt} received: stopped gracefully at "
+                        f"round boundary {format_time(now)} ({self.rounds} "
+                        f"rounds); summary is partial")
+                    if ck_every:
+                        with rec.span("checkpoint"):
+                            path = _ckpt.save_checkpoint(self, now)
+                        self.log.info(f"final checkpoint written: {path}")
+                        if self.live is not None:
+                            self.live.publish({"type": "checkpoint",
+                                               "path": str(path), "t": now,
+                                               "round": self.rounds})
             self.engine.flush_all()  # finalize counters for in-flight batches
             if cfg.general.progress:
                 import sys as _sys
@@ -709,12 +697,10 @@ class Controller:
         return result
 
     def _round_loop(self, now, stop, w, dyn, faults, next_hb, hb_interval,
-                    next_prog, prog_step, next_gc, next_ckpt, ck_every,
+                    next_prog, prog_step, lgc, next_ckpt, ck_every,
                     dig, _ckpt, tel, t0) -> SimTime:
         """The conservative round loop (split from run() so the signal
         try/finally stays readable). Returns the final sim time."""
-        import gc as _gc
-
         # device transport (network/devtransport.py): deferred host
         # rounds replay inside end_of_round; their event counts fold
         # back into `executed` so the skip-ahead decision, the events
@@ -842,10 +828,7 @@ class Controller:
             if round_end >= next_prog:
                 self._progress(round_end, stop, t0)
                 next_prog = round_end + prog_step
-            if self.rounds >= next_gc:
-                next_gc = self.rounds + _GC_EVERY_ROUNDS
-                with span("gc"):
-                    _gc.collect()
+            lgc.tick(self.rounds)
             if executed == 0 and not self.engine.has_immediate_work():
                 # provably idle: materialize any in-flight draw batch that
                 # could produce an event before the next queued one, then

@@ -76,7 +76,8 @@ from pathlib import Path
 
 import numpy as np
 
-from shadow_tpu.core.controller import Controller, _GC_EVERY_ROUNDS
+from shadow_tpu.core.controller import Controller
+from shadow_tpu.core.loop_gc import LoopGC
 from shadow_tpu.core.spans import Recorder
 from shadow_tpu.core.time import NS_PER_SEC, NS_PER_US, T_NEVER, format_time
 from shadow_tpu.host.process import PluginProcess
@@ -362,7 +363,6 @@ class _ShardWorker:
                 continue
             self.rings_out[j] = ShmRing(_ring_name(ring_tag, shard_id, j))
             self.rings_in[j] = ShmRing(_ring_name(ring_tag, j, shard_id))
-        self._next_gc = _GC_EVERY_ROUNDS
         #: liveness (shadow_tpu/supervise.py): this shard's slot on the
         #: shared progress page — stamped at every round top, inside the
         #: marker wait, and inside blocked ring writes, so only a shard
@@ -409,7 +409,6 @@ class _ShardWorker:
 
     # -- lifecycle ---------------------------------------------------------
     def serve(self, resume_at=None) -> None:
-        import gc as _gc
         import signal as _signal
 
         # the parent owns signal policy: a terminal Ctrl-C reaches the
@@ -430,8 +429,6 @@ class _ShardWorker:
             while (ctl._replay_idx < len(ctl._replay_cmds)
                    and ctl._replay_cmds[ctl._replay_idx]["t"] <= resume_at):
                 ctl._replay_idx += 1
-        gc_was_enabled = _gc.isenabled()
-        _gc.disable()
         self.conn.send(("ready", {
             "round_ns": ctl.round_ns,
             "n_hosts": len(ctl.hosts),
@@ -463,8 +460,6 @@ class _ShardWorker:
                 final = self._finalize(msg[1])
             self.conn.send(("final", final))
         finally:
-            if gc_was_enabled:
-                _gc.enable()
             for r in self.rings_out.values():
                 r.close()
             for r in self.rings_in.values():
@@ -473,8 +468,13 @@ class _ShardWorker:
 
     # -- the free-running round loop ---------------------------------------
     def _free_run(self, m: dict) -> None:
-        import gc as _gc
+        # core/loop_gc.py: automatic GC off and the pre-loop heap frozen;
+        # the last collect runs as the block ends
+        with LoopGC(self.ctl.spans, self.ctl.rounds) as lgc:
+            done = self._free_loop(m, lgc)
+        self.conn.send(("done", done))
 
+    def _free_loop(self, m: dict, lgc: LoopGC) -> dict:
         ctl = self.ctl
         eng = ctl.engine
         cfg = ctl.cfg
@@ -656,10 +656,7 @@ class _ShardWorker:
                 # heartbeat periods; the next beat lands on the grid so
                 # every shard fires on the same sim-time cadence
                 next_hb = ((round_end // hb) + 1) * hb
-            if ctl.rounds >= self._next_gc:
-                self._next_gc = ctl.rounds + _GC_EVERY_ROUNDS
-                with span("gc"):
-                    _gc.collect()
+            lgc.tick(ctl.rounds)
 
             # the cross-shard barrier + the global reduction: identical
             # inputs on every worker -> identical decisions
@@ -705,10 +702,9 @@ class _ShardWorker:
             self._drain_rings()
             self._ingest_ready(ctl.rounds)
             self._checkpoint(now)
-        self.conn.send(("done", {
-            "now": now, "rounds": ctl.rounds, "events": ctl.events,
-            "interrupted": interrupted,
-            **({"stop_reason": "live_stop"} if self._cmd_stop else {})}))
+        return {"now": now, "rounds": ctl.rounds, "events": ctl.events,
+                "interrupted": interrupted,
+                **({"stop_reason": "live_stop"} if self._cmd_stop else {})}
 
     def _apply_boundary_cmds(self, now: int, faults):
         """Apply round-boundary commands exactly like the single-process
