@@ -169,7 +169,7 @@ def draw_plane_checks(jax, counter, seed: int) -> dict:
     """On the draw plane the tpu_batch phases attached (process cache):
     calibrate() again with nothing else running — during the auto run
     it shares the GIL with the round loop — then flip jax_enable_x64 on
-    (what MeshDataPlane and DeviceAckKernel do in mid-run) and dispatch
+    (what MeshDataPlane does in mid-run) and dispatch
     the warmed programs once each: the compiles this causes are the ones
     a mid-run flip would put inside a round loop."""
     import numpy as np

@@ -3015,23 +3015,7 @@ static PyObject *Core_host_boot(CoreObject *c, PyObject *arg) {
   Py_RETURN_NONE;
 }
 
-/* transport column snapshot/adopt ABI (PR 11; defined after CEp below) */
-static PyObject *Core_transport_columns(CoreObject *c, PyObject *noarg);
-static PyObject *Core_adopt_transport_columns(CoreObject *c,
-                                              PyObject *cols);
-
 static PyMethodDef Core_methods[] = {
-    {"transport_columns", (PyCFunction)Core_transport_columns,
-     METH_NOARGS,
-     "struct-of-arrays int64 snapshot of every C stream endpoint "
-     "(network/devtransport.py COLUMNS twin; canonical host-id + "
-     "sorted-connection-key order; pcap hosts' endpoints stay Python "
-     "and are omitted — compare snapshots on pcap-free configs)"},
-    {"adopt_transport_columns",
-     (PyCFunction)Core_adopt_transport_columns, METH_O,
-     "(cols dict) -> window-edge writeback of the ADOPT_COLUMNS subset "
-     "(cwnd/ssthresh/cubic epoch/backoff) into live C endpoints; "
-     "refuses by name when a row matches no live endpoint"},
     {"barrier", (PyCFunction)Core_barrier, METH_VARARGS,
      "end_of_round twin: (round_start, round_end) -> None | device batch"},
     {"extract", (PyCFunction)Core_extract, METH_VARARGS,
@@ -6652,207 +6636,6 @@ static PyObject *mod_shell(PyObject *self, PyObject *arg) {
                       k);
 }
 
-/* ======================================================================
- * Transport column snapshot/adopt ABI (PR 11, colcore ABI 4): the C
- * half of the device-resident columnar transport's three-surface
- * contract.  transport_columns exports every C stream endpoint's hot
- * integer state as struct-of-arrays int64 numpy columns — the EXACT
- * field set and canonical order of network/devtransport.py's
- * export_columns (hosts in id order, connections in sorted-key order),
- * so the cross-plane identity gates can diff a C run's columns against
- * a Python run's byte for byte.  adopt_transport_columns is the
- * window-edge writeback: only the pure window/CC arithmetic columns
- * (devtransport.ADOPT_COLUMNS) are writable — never sequence/buffer
- * state, whose ring invariants are owned by the scalar machinery.
- * ====================================================================== */
-
-#define N_TCOLS 28
-static const char *TCOL_NAMES[N_TCOLS] = {
-    "hid",        "local_port", "remote_host",    "remote_port",
-    "state",      "cwnd",       "ssthresh",       "snd_nxt",
-    "snd_una",    "adv_wnd",    "buffered",       "bytes_acked",
-    "rto_backoff", "retries",   "dup_acks",       "loss_events",
-    "cc_id",      "in_recovery", "recover",       "sack_high",
-    "w_max",      "epoch_start", "sacked_n",      "rtx_done_n",
-    "rcv_nxt",    "ooo_bytes",  "bytes_received", "last_wnd"};
-
-static PyObject *Core_transport_columns(CoreObject *c, PyObject *noarg) {
-  (void)noarg;
-  /* collect C endpoints in canonical order */
-  int cap = 256, n = 0;
-  CEp **eps = malloc(sizeof(CEp *) * (size_t)cap);
-  if (!eps) return PyErr_NoMemory();
-  for (int64_t hid = 0; hid < c->H; hid++) {
-    CHost *h = &c->hs[hid];
-    if (!h->conns) continue;
-    PyObject *keys = PyDict_Keys(h->conns);
-    if (!keys || PyList_Sort(keys) < 0) {
-      Py_XDECREF(keys);
-      free(eps);
-      return NULL;
-    }
-    Py_ssize_t nk = PyList_GET_SIZE(keys);
-    for (Py_ssize_t i = 0; i < nk; i++) {
-      PyObject *v = PyDict_GetItem(h->conns, PyList_GET_ITEM(keys, i));
-      if (!v || Py_TYPE(v) != &CEp_Type) continue; /* pcap hosts stay py */
-      if (n == cap) {
-        cap *= 2;
-        CEp **ne = realloc(eps, sizeof(CEp *) * (size_t)cap);
-        if (!ne) {
-          Py_DECREF(keys);
-          free(eps);
-          return PyErr_NoMemory();
-        }
-        eps = ne;
-      }
-      eps[n++] = (CEp *)v;
-    }
-    Py_DECREF(keys);
-  }
-  PyObject *out = PyDict_New();
-  int64_t *p[N_TCOLS];
-  if (!out) {
-    free(eps);
-    return NULL;
-  }
-  npy_intp dims[1] = {n};
-  for (int k = 0; k < N_TCOLS; k++) {
-    PyObject *a = PyArray_SimpleNew(1, dims, NPY_INT64);
-    if (!a || PyDict_SetItemString(out, TCOL_NAMES[k], a) < 0) {
-      Py_XDECREF(a);
-      Py_DECREF(out);
-      free(eps);
-      return NULL;
-    }
-    p[k] = (int64_t *)PyArray_DATA((PyArrayObject *)a);
-    Py_DECREF(a); /* the dict holds it */
-  }
-  for (int i = 0; i < n; i++) {
-    CEp *e = eps[i];
-    int k = 0;
-    p[k++][i] = e->hid;
-    p[k++][i] = e->local_port;
-    p[k++][i] = e->remote_host;
-    p[k++][i] = e->remote_port;
-    p[k++][i] = e->state;
-    p[k++][i] = e->cwnd;
-    p[k++][i] = e->ssthresh;
-    p[k++][i] = e->snd_nxt;
-    p[k++][i] = e->snd_una;
-    p[k++][i] = e->adv_wnd;
-    p[k++][i] = e->buffered;
-    p[k++][i] = e->bytes_acked;
-    p[k++][i] = e->rto_backoff;
-    p[k++][i] = e->retries;
-    p[k++][i] = e->dup_acks;
-    p[k++][i] = e->loss_events;
-    p[k++][i] = e->cc_kind;
-    p[k++][i] = e->in_recovery ? 1 : 0;
-    p[k++][i] = e->recover;
-    p[k++][i] = e->sack_high;
-    p[k++][i] = e->w_max;
-    p[k++][i] = e->epoch_start;
-    p[k++][i] = e->sacked.count;
-    p[k++][i] = e->rtx_done.count;
-    p[k++][i] = e->rcv_nxt;
-    p[k++][i] = e->ooo_bytes;
-    p[k++][i] = e->bytes_received;
-    p[k++][i] = e->last_wnd;
-  }
-  free(eps);
-  return out;
-}
-
-/* the ADOPT_COLUMNS subset (devtransport.py twin) in writeback order */
-#define N_TADOPT 7
-static const char *TADOPT_NAMES[N_TADOPT] = {
-    "cwnd", "ssthresh", "w_max", "epoch_start",
-    "rto_backoff", "retries", "dup_acks"};
-
-static PyObject *Core_adopt_transport_columns(CoreObject *c,
-                                              PyObject *cols) {
-  if (!PyDict_Check(cols)) {
-    PyErr_SetString(PyExc_TypeError,
-                    "adopt_transport_columns expects the column dict");
-    return NULL;
-  }
-  PyObject *arrs[4 + N_TADOPT];
-  const int64_t *dat[4 + N_TADOPT];
-  const char *want[4 + N_TADOPT];
-  for (int k = 0; k < 4; k++) want[k] = TCOL_NAMES[k]; /* identity join */
-  for (int k = 0; k < N_TADOPT; k++) want[4 + k] = TADOPT_NAMES[k];
-  Py_ssize_t n = -1;
-  for (int k = 0; k < 4 + N_TADOPT; k++) {
-    PyObject *a = PyDict_GetItemString(cols, want[k]);
-    if (!a) {
-      for (int j = 0; j < k; j++) Py_DECREF(arrs[j]);
-      return PyErr_Format(PyExc_ValueError,
-                          "adopt_transport_columns: missing column %s",
-                          want[k]);
-    }
-    arrs[k] = PyArray_FROM_OTF(a, NPY_INT64, NPY_ARRAY_IN_ARRAY);
-    if (!arrs[k]) {
-      for (int j = 0; j < k; j++) Py_DECREF(arrs[j]);
-      return NULL;
-    }
-    Py_ssize_t len = PyArray_SIZE((PyArrayObject *)arrs[k]);
-    if (n < 0) n = len;
-    if (len != n) {
-      for (int j = 0; j <= k; j++) Py_DECREF(arrs[j]);
-      return PyErr_Format(PyExc_ValueError,
-                          "adopt_transport_columns: column %s length %zd"
-                          " != %zd", want[k], len, n);
-    }
-    dat[k] = (const int64_t *)PyArray_DATA(
-        (PyArrayObject *)arrs[k]);
-  }
-  /* two-pass validate-then-write: refusal must be ATOMIC (a partially
-   * adopted cohort would be a state no snapshot ever described) */
-  CEp **eps = malloc(sizeof(CEp *) * (size_t)(n ? n : 1));
-  if (!eps) {
-    for (int k = 0; k < 4 + N_TADOPT; k++) Py_DECREF(arrs[k]);
-    return PyErr_NoMemory();
-  }
-  for (Py_ssize_t i = 0; i < n; i++) {
-    int64_t hid = dat[0][i];
-    CEp *e = NULL;
-    if (hid >= 0 && hid < c->H && c->hs[hid].conns) {
-      PyObject *key = Py_BuildValue("(LLL)", (long long)dat[1][i],
-                                    (long long)dat[2][i],
-                                    (long long)dat[3][i]);
-      if (!key) goto fail;
-      PyObject *v = PyDict_GetItem(c->hs[hid].conns, key);
-      Py_DECREF(key);
-      if (v && Py_TYPE(v) == &CEp_Type) e = (CEp *)v;
-    }
-    if (!e) {
-      PyErr_Format(PyExc_ValueError,
-                   "adopt_transport_columns: row %zd (host %lld port %lld"
-                   ") names no live C endpoint", i, (long long)hid,
-                   (long long)dat[1][i]);
-      goto fail;
-    }
-    eps[i] = e;
-  }
-  for (Py_ssize_t i = 0; i < n; i++) {
-    CEp *e = eps[i];
-    e->cwnd = dat[4][i];
-    e->ssthresh = dat[5][i];
-    e->w_max = dat[6][i];
-    e->epoch_start = dat[7][i];
-    e->rto_backoff = dat[8][i];
-    e->retries = (int)dat[9][i];
-    e->dup_acks = (int)dat[10][i];
-  }
-  free(eps);
-  for (int k = 0; k < 4 + N_TADOPT; k++) Py_DECREF(arrs[k]);
-  Py_RETURN_NONE;
-fail:
-  free(eps);
-  for (int k = 0; k < 4 + N_TADOPT; k++) Py_DECREF(arrs[k]);
-  return NULL;
-}
-
 static PyObject *Core_adopt(CoreObject *c, PyObject *arg) {
   PyObject *seq = PySequence_Fast(
       arg, "adopt expects a sequence of restored C objects");
@@ -7085,11 +6868,12 @@ PyMODINIT_FUNC PyInit__colcore(void) {
    * sacked/rtx_done seq sets) in _export_state and the fingerprint —
    * ABI-2 checkpoints restore the wrong field count and must refuse by
    * name. (ABI 2 was the uid canonical-event-key change.)
-   * ABI 4 (PR 11): the transport column snapshot/adopt surface
-   * (Core.transport_columns / adopt_transport_columns) joined the
-   * state contract, paired with checkpoint VERSION 4 (the Python
+   * ABI 4 (PR 11): a column snapshot/adopt surface joined the state
+   * contract, paired with checkpoint VERSION 4 (the Python
    * StreamSender scoreboards became sorted lists — the canonical form
-   * both column exports and CEp's sorted-tuple export already used). */
+   * CEp's sorted-tuple export already used). That column surface was
+   * removed later; the _export_state/_restore_state format it sat
+   * beside is unchanged, so ABI stays 4 and ABI-4 checkpoints load. */
   PyModule_AddIntConstant(m, "ABI", 4);
   Py_INCREF(&Core_Type);
   PyModule_AddObject(m, "Core", (PyObject *)&Core_Type);

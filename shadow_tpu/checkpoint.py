@@ -96,9 +96,9 @@ FORMAT = "shadow_tpu-checkpoint"
 #: senders lack those attributes and would crash on the first ack after
 #: resume — are refused by the version gate like version-1 before them.
 #: Version 4: the StreamSender SACK/rtx scoreboards became SORTED LISTS
-#: (canonical by construction for the columnar transport export,
-#: network/devtransport.py); a version-3 checkpoint would restore sets
-#: where the bisect-based scoreboard code expects lists. Version 5:
+#: (canonical by construction, like CEp's sorted-tuple export); a
+#: version-3 checkpoint would restore sets where the bisect-based
+#: scoreboard code expects lists. Version 5:
 #: managed (real-binary) configs are checkpointable as re-execution
 #: snapshots (header ``mode: "reexec"`` + a JSON payload of round cursor,
 #: state digest, per-guest journal cursors, and applied live commands —
@@ -128,11 +128,6 @@ VOLATILE_CONFIG_KEYS = (
     ("general", "live_endpoint"),
     ("general", "replay_commands"),
     ("experimental", "native_colcore"),
-    # the columnar transport engine is the same kind of toggle: every
-    # path is bit-identical (tests/test_devtransport.py), engagement is
-    # wall-clock policy, and _reattach_runtime rebuilds — or not — the
-    # engine from the resume invocation's value
-    ("experimental", "device_transport"),
 )
 
 DIGEST_FILE = "state_digests.jsonl"

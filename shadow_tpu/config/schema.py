@@ -160,16 +160,6 @@ class ExperimentalOptions:
     #: C engine for the columnar plane (native/colcore). Bit-identical to
     #: the Python paths; off forces the pure-Python twin (test oracle).
     native_colcore: bool = True
-    #: device-resident columnar transport (network/devtransport.py):
-    #: ack-dominated host rounds defer to the barrier and whole cohorts
-    #: of endpoints advance through ONE batched integer kernel
-    #: (ops/transport_kernels.py) instead of per-ack scalar callbacks.
-    #: Bit-identical on/off (tests/test_devtransport.py); engagement is
-    #: pure wall-clock policy with break-even hysteresis, so the default
-    #: stays off and a losing box measures it as a no-op. No-op with the
-    #: C engine attached (colcore is the scalar fast path) and on the
-    #: thread policies (per-unit plane).
-    device_transport: bool = False
     #: stream loss recovery: "sack" — RFC 2018-shaped block recovery over
     #: the 3-duplicate-ack trigger (receiver reports its buffered ranges
     #: on every out-of-order ack; the sender retransmits ALL holes per
@@ -547,7 +537,9 @@ def parse_config(doc: dict, overrides: Optional[dict] = None) -> ConfigOptions:
     e.tpu_mesh_shards = int(exp.get("tpu_mesh_shards", 0))
     e.tpu_mesh_floor = int(exp.get("tpu_mesh_floor", 2048))
     e.native_colcore = bool(exp.get("native_colcore", True))
-    e.device_transport = bool(exp.get("device_transport", False))
+    _require(not exp.get("device_transport", False),
+             "experimental.device_transport was removed: the C engine "
+             "runs the stream transport")
     e.stream_loss_recovery = str(exp.get("stream_loss_recovery", "sack"))
     _require(e.stream_loss_recovery == "sack",
              "experimental.stream_loss_recovery must be sack (PR 9 "

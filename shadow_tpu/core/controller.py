@@ -59,8 +59,7 @@ VOLATILE_SUMMARY_KEYS = ("wall_seconds", "sim_sec_per_wall_sec",
                          "phase_wall", "phase_detail", "phase_calls",
                          "max_rss_mb", "device",
                          "device_windows_dispatched", "sim_shards",
-                         "shards", "device_transport",
-                         "device_transport_engaged", "supervisor")
+                         "shards", "supervisor")
 
 
 class Controller:
@@ -448,12 +447,6 @@ class Controller:
                 h.equeue.on_first = partial(self._active.add, h.id)
         from shadow_tpu import checkpoint as _ckpt
 
-        attach_dt = getattr(self.engine, "attach_devtransport", None)
-        if attach_dt is not None:
-            # after attach_colcore: the transport engine yields to an
-            # attached C core (experimental.device_transport is volatile
-            # wall-clock policy, like native_colcore)
-            attach_dt(cfg.experimental)
         _ckpt.finish_colcore_adopt(self)
 
     # -- managed re-execution restore (checkpoint format v5) --------------
@@ -701,11 +694,6 @@ class Controller:
                     dig, _ckpt, tel, t0) -> SimTime:
         """The conservative round loop (split from run() so the signal
         try/finally stays readable). Returns the final sim time."""
-        # device transport (network/devtransport.py): deferred host
-        # rounds replay inside end_of_round; their event counts fold
-        # back into `executed` so the skip-ahead decision, the events
-        # total, and the round grid are identical to the scalar twin's
-        devt = getattr(self.engine, "devt", None)
         span = self.spans.span
         sp_events = span("events")
         while now < stop:
@@ -790,8 +778,6 @@ class Controller:
                 # a background device attach failed: the run fails here
                 # rather than finishing on the host twin
                 self.engine.raise_device_error()
-            if devt is not None:
-                executed += devt.take_executed()
             self.rounds += 1
             self.events += executed
             if dig and self.rounds % dig == 0:
@@ -1093,14 +1079,6 @@ class Controller:
                 self.engine, "dev_windows", 0),
             **({"device": self.engine.device_summary()}
                if hasattr(self.engine, "device_summary") else {}),
-            # device transport (PR 11): wall-clock routing telemetry for
-            # the columnar endpoint ticks; engaged = at least one cohort
-            # actually advanced through the batched kernel (a silent
-            # fallback shows, the device_engaged discipline)
-            **(lambda dt: {} if dt is None else {
-                "device_transport_engaged": dt.cohorts > 0,
-                "device_transport": dt.summary(),
-            })(getattr(self.engine, "devt", None)),
             **({"fault_transitions_applied": self.faults.applied}
                if self.faults is not None else {}),
             # flow-latency percentiles + sample counts (telemetry/):

@@ -73,7 +73,6 @@ OVERLAY_ALLOWED = frozenset({
     "general.checkpoint_every",
     "general.checkpoint_dir",
     "experimental.native_colcore",
-    "experimental.device_transport",
 })
 #: volatile keys the fork runner owns per branch — an overlay naming one
 #: is refused with its own wording (not the generic non-volatile error)

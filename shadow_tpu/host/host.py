@@ -60,11 +60,6 @@ class Host:
         self.egress_rows: list[tuple] = []
         self._inbox = None
         self.ingress_deferred_rows: list[tuple] = []
-        #: columnar transport engine (network/devtransport.py) when
-        #: experimental.device_transport is on and the plane is the
-        #: Python columnar one; ack-dominated rounds defer to the
-        #: barrier and advance as one batched kernel (bit-identical)
-        self.devt = None
         # hot-path counters kept as plain ints (Counter.__getitem__ per
         # unit measurably drags at 1M+ units); folded in fold_counters()
         self._n_emitted = 0
@@ -160,14 +155,6 @@ class Host:
             self._n_events += n
             return n
         self._inbox = None
-        devt = self.devt
-        if devt is not None and devt.intercept(self, rows, end):
-            # device transport: the whole round (inbox AND due timers)
-            # defers to the barrier, where cohorts of clean acks across
-            # hosts advance as ONE batched kernel and everything replays
-            # through this method's exact merge discipline — the event
-            # count reports through DeviceTransport.take_executed
-            return 0
         eq = self.equeue
         heap = eq._heap
         dispatch = self.dispatch_row
@@ -462,14 +449,11 @@ class Host:
     def __getstate__(self):
         d = self.__dict__.copy()
         del d["_log_sha"]  # hashlib objects cannot pickle; rebuilt below
-        # runtime-only columnar-transport engine (holds a jax kernel
-        # handle); reattached by Controller._reattach_runtime on restore
-        d["devt"] = None
         return d
 
     def __setstate__(self, d):
+        d.pop("devt", None)  # older checkpoints carry a retired None slot
         self.__dict__.update(d)
-        self.devt = None
         self._log_sha = hashlib.sha256()
         for ln in self._log_lines:
             self._log_sha.update(ln.encode() + b"\n")

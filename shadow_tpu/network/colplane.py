@@ -145,8 +145,7 @@ class ColumnarPlane(DeviceRoutedPlane):
     #: build+dispatch but ~zero readback); draw_flush is what resolving
     #: due batches costs besides them.
     PHASES = ("barrier", "draw_flush", "extract", "ingress_deferred",
-              "window_build", "window_dispatch", "window_readback",
-              "transport_tick")
+              "window_build", "window_dispatch", "window_readback")
 
     def __init__(self, graph: NetworkGraph, params: NetParams, hosts,
                  round_ns: SimTime, backend: str = "numpy",
@@ -202,12 +201,6 @@ class ColumnarPlane(DeviceRoutedPlane):
         #: an error (attach_colcore).
         self._c = None
         self.attach_colcore(tpu_options)
-        #: device-resident columnar transport (network/devtransport.py):
-        #: attached when experimental.device_transport is on and the C
-        #: engine is not (colcore already owns the scalar fast path —
-        #: the column snapshot/adopt ABI remains available either way)
-        self.devt = None
-        self.attach_devtransport(tpu_options)
 
     def attach_colcore(self, tpu_options):
         """(Re)build the C engine over the current structures — the
@@ -238,28 +231,6 @@ class ColumnarPlane(DeviceRoutedPlane):
         if self.shard_n > 1:
             self._bind_shard_core()
         return self._c
-
-    def attach_devtransport(self, tpu_options):
-        """(Re)attach the columnar transport engine — constructor hookup
-        and the checkpoint-restore twin (Controller._reattach_runtime).
-        experimental.device_transport is a volatile wall-clock-policy
-        key: engagement cannot change results (every path is
-        bit-identical, enforced by tests/test_devtransport.py), so a
-        resume may flip it like native_colcore."""
-        for h in self.hosts:
-            h.devt = None
-        self.devt = None
-        if not getattr(tpu_options, "device_transport", False):
-            return None
-        if self._c is not None:
-            return None  # colcore IS the fast scalar twin (module doc)
-        from shadow_tpu.network.devtransport import DeviceTransport
-
-        self.devt = DeviceTransport(self)
-        self.devt.start_device_attach()
-        for h in self.hosts:
-            h.devt = self.devt
-        return self.devt
 
     def _bind_shard_core(self) -> None:
         """Install the shard filter on the C core: the packed send path
@@ -370,12 +341,6 @@ class ColumnarPlane(DeviceRoutedPlane):
         window, install ready speculative tables, pull new speculation
         demand). Windows open and close ONLY at round boundaries, so
         checkpoint.py's round-boundary snapshots stay valid."""
-        if self.devt is not None:
-            # deferred host rounds replay (and their ack cohorts advance
-            # as one batched kernel) BEFORE the barrier collects
-            # emitters, so replayed emissions join this round's barrier
-            # exactly as live-dispatched ones would have
-            self.devt.flush_round(round_end)
         with span("barrier"):
             self._barrier_round(round_start, round_end)
         self._window_tick(round_end)

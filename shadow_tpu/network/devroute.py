@@ -281,9 +281,6 @@ class DeviceRoutedPlane:
         t = getattr(self, "_bg_thread", None)
         if t is not None and t.is_alive():
             t.join()
-        devt = getattr(self, "devt", None)
-        if devt is not None:
-            devt.close()  # join the transport-kernel attach thread too
         d = getattr(self, "device", None)
         if d is not None and hasattr(d, "close_client"):
             d.close_client()
@@ -297,7 +294,7 @@ class DeviceRoutedPlane:
         test_colcore)."""
         d = self.__dict__.copy()
         for k in ("device", "mesh_plane", "_bg_thread", "_c",
-                  "_spec_pending", "devt", "device_error"):
+                  "_spec_pending", "device_error"):
             d.pop(k, None)
         return d
 
@@ -313,7 +310,6 @@ class DeviceRoutedPlane:
         self._spec_on = False
         self._spec_checked = False
         self._spec_clamped = False
-        self.devt = None  # reattached by Controller._reattach_runtime
 
     def reattach_device(self, tpu_options) -> None:
         """Restore-time twin of __init__'s device hookup: re-runs attach,

@@ -266,9 +266,9 @@ class StreamSender:
         #: Both are SORTED lists (PR 11), not sets: membership stays cheap
         #: at scoreboard scale (entries ⊆ rtx seqs, a handful under real
         #: loss), iteration order is canonical by construction — the
-        #: columnar transport export (network/devtransport.py) and the
-        #: determinism fingerprint read them without a sort or a detlint
-        #: unordered-iteration waiver
+        #: determinism fingerprint reads them without a sort or a detlint
+        #: unordered-iteration waiver, and they are the checkpoint
+        #: version-4 pickle layout
         self.sacked: list[int] = []
         self.sack_high = 0
         self.rtx_done: list[int] = []

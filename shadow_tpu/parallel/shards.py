@@ -549,12 +549,6 @@ class _ShardWorker:
                         if not h.equeue._heap:
                             ctl._active.discard(h.id)
             eng.end_of_round(now, round_end)
-            devt = getattr(eng, "devt", None)
-            if devt is not None:
-                # columnar-transport replays ran inside end_of_round:
-                # fold them into this shard's executed count BEFORE the
-                # marker reduction, exactly like Controller._round_loop
-                executed += devt.take_executed()
             ctl.rounds += 1
             ctl.events += executed
 

@@ -499,6 +499,24 @@ def test_removed_loss_models_rejected():
         _parse({"experimental": {"congestion_control": "bbr2"}})
 
 
+def test_removed_device_transport_rejected():
+    """The retired columnar transport toggle: asking for it is a config
+    error by name, not a silently ignored experimental key."""
+    with pytest.raises(ValueError, match="device_transport was removed"):
+        _parse({"experimental": {"device_transport": True}})
+
+
+def test_device_transport_false_still_parses():
+    """Configs that spelled out the old default keep parsing, and the
+    parsed config carries no trace of the key: same fields, same
+    checkpoint config digest as a config that never named it."""
+    from shadow_tpu.checkpoint import config_digest
+
+    cfg = _parse({"experimental": {"device_transport": False}})
+    assert not hasattr(cfg.experimental, "device_transport")
+    assert config_digest(cfg) == config_digest(_parse({}))
+
+
 def test_unknown_host_and_node_fail_at_build():
     d = yaml.safe_load(TWO_NODE)
     d["faults"] = {"events": [

@@ -79,16 +79,6 @@ def test_min_draw_kernel_compiles_at_spec_bucket(one_chip):
     assert compiled.as_text()
 
 
-def test_ack_advance_compiles_int64(one_chip):
-    from shadow_tpu.ops import transport_kernels as TK
-
-    with jax.enable_x64(True):
-        cols = [_spec((65536,), jnp.int64, one_chip) for _ in range(9)]
-        compiled = jax.jit(
-            lambda *c: TK.ack_advance(*c, xp=jnp)).lower(*cols).compile()
-        assert "s64" in compiled.as_text()
-
-
 def test_exchange_rounds_compiles_on_four_chips(topo):
     from shadow_tpu.parallel.mesh import AXIS, _exchange_rounds
 

@@ -10,6 +10,7 @@ isn't a gate.
 
 from __future__ import annotations
 
+import re
 import shutil
 import sys
 from pathlib import Path
@@ -55,6 +56,15 @@ def mutate(tree: Path, relpath: str, old: str, new: str):
 def append(tree: Path, relpath: str, code: str):
     p = tree / relpath
     p.write_text(p.read_text() + "\n" + code + "\n")
+
+
+def mutate_define(tree: Path, cname: str):
+    """Add one to the value of colcore.c's ``#define <cname>``."""
+    p = tree / "native" / "colcore" / "colcore.c"
+    src, n = re.subn(r"^(#define %s )(.+)$" % re.escape(cname),
+                     r"\1(\2 + 1)", p.read_text(), count=1, flags=re.M)
+    assert n == 1, "no #define %s in colcore.c" % cname
+    p.write_text(src)
 
 
 def rules(findings):
@@ -140,39 +150,22 @@ def test_cubic_beta_drift_is_caught(tree):
     assert "cubic-arith:on_loss" in rules(twin_audit.audit(tree))
 
 
-# -- the columnar kernel twin (third surface, PR 11) --------------------------
-
-def test_kernel_const_drift_is_caught(tree):
-    mutate(tree, "shadow_tpu/ops/transport_kernels.py",
-           "MSS = 1460", "MSS = 1500")
-    assert "kernel-const-drift:MSS" in rules(twin_audit.audit(tree))
-
-
-def test_kernel_cc_id_drift_is_caught(tree):
-    mutate(tree, "shadow_tpu/ops/transport_kernels.py",
-           "CC_CUBIC = 1", "CC_CUBIC = 2")
-    assert "kernel-const-drift:CC_CUBIC" in rules(twin_audit.audit(tree))
-
-
-def test_kernel_cc_literal_drift_is_caught(tree):
-    # cubic C constant 0.4 -> 0.5 seeded in the KERNEL side only: the
-    # on_ack literal sets of the scalar twins and the batched kernel
-    # diverge (10_000 -> 8_000 in the delta scaling)
-    mutate(tree, "shadow_tpu/ops/transport_kernels.py",
-           "(a * a * a // 1_000_000) * (4 * MSS) // 10_000",
-           "(a * a * a // 1_000_000) * (4 * MSS) // 8_000")
-    assert "kernel-cc-drift:on_ack" in rules(twin_audit.audit(tree))
-
-
-def test_kernel_cc_literal_drift_scalar_side_is_caught(tree):
-    # the same drift seeded on the SCALAR side fails too — the check is
-    # symmetric, so neither twin can move without the other
+def test_cc_on_ack_literal_drift_python_side_is_caught(tree):
+    # the on_ack literal sets are compared in both directions: drift
+    # seeded on the Python side fails as surely as on the C side
     mutate(tree, "shadow_tpu/network/transport.py",
            "nn = min(newly, 1 << 20)", "nn = min(newly, 1 << 21)")
-    found = rules(twin_audit.audit(tree))
-    assert "kernel-cc-drift:on_ack" in found
-    # ... and the C twin diverges with it (the PR 10 check still fires)
-    assert "cubic-arith:on_ack" in found
+    assert "cubic-arith:on_ack" in rules(twin_audit.audit(tree))
+
+
+@pytest.mark.parametrize(
+    "pyname,cname",
+    [pytest.param(py, c, id=py) for _mod, py, c in twin_audit.CONST_PAIRS
+     if py != "RTO_MAX_NS"])  # RTO_MAX_NS has its own two tests above
+def test_const_pair_drift_is_caught(tree, pyname, cname):
+    # move the C side's value by one: every audited pair fails by name
+    mutate_define(tree, cname)
+    assert "const-drift:%s" % pyname in rules(twin_audit.audit(tree))
 
 
 def test_new_struct_field_without_export_is_caught(tree):

@@ -218,6 +218,18 @@ def test_checkpoint_resume_reproduces_uninterrupted_hashes():
         "resumed digest stream is not a suffix of the uninterrupted one"
 
 
+#: checkpoint.config_digest(CFG), recorded from a build whose config
+#: schema still had the retired columnar-transport key: a checkpoint
+#: written then must still pass load's config-digest check
+CFG_DIGEST = "bbe6ab9ce1507a38c83472b8e273e0854f0fd54b329052c35c8f8a90c9046263"
+
+
+def test_config_digest_matches_older_checkpoints():
+    from shadow_tpu.checkpoint import config_digest
+
+    assert config_digest(parse_config(yaml.safe_load(CFG), {})) == CFG_DIGEST
+
+
 def test_summary_quality_stall_rollup_deterministic():
     """The ABR quality/stall summary (x_mean + abr counters) is
     deterministic run-to-run."""

@@ -449,54 +449,6 @@ print(f"ABR smoke OK: C on/off bit-identical, {seg} segments across "
       f"{groups} host-groups in the report")
 '
 
-echo "== device-transport smoke (web_cdn: devt on/off identity; web_cdn_100k: 100k-host short leg) =="
-devtrun() {
-    rm -rf "/tmp/ci-devt-$1"
-    python -m shadow_tpu examples/web_cdn.yaml --quiet --json-summary \
-        --data-directory "/tmp/ci-devt-$1" \
-        --scheduler-policy tpu_batch \
-        --set experimental.native_colcore=false \
-        --set "experimental.device_transport=$2" \
-        --set general.stop_time=26s \
-        > "/tmp/ci-devt-$1.raw.json"
-    python -c 'import json,sys; from shadow_tpu.core.controller import VOLATILE_SUMMARY_KEYS as V; d=json.load(open(sys.argv[1])); [d.pop(k, None) for k in V]; print(json.dumps(d,sort_keys=True))' \
-        "/tmp/ci-devt-$1.raw.json" > "/tmp/ci-devt-$1.json"
-    (cd "/tmp/ci-devt-$1" && find hosts -type f | sort | xargs -r sha256sum && \
-     sha256sum flows.jsonl metrics.jsonl) > "/tmp/ci-devt-$1.hashes"
-}
-devtrun off false
-devtrun on true
-diff /tmp/ci-devt-off.json /tmp/ci-devt-on.json
-diff /tmp/ci-devt-off.hashes /tmp/ci-devt-on.hashes
-# the committed 100k-endpoint config builds and runs end to end with the
-# columnar transport engaged (short leg: the full 6s config is the bench
-# row's artifact — bench.py web_cdn_100k_row)
-rm -rf /tmp/ci-devt-100k
-python -m shadow_tpu examples/web_cdn_100k.yaml --quiet --json-summary \
-    --data-directory /tmp/ci-devt-100k \
-    --scheduler-policy tpu_batch \
-    --set experimental.native_colcore=false \
-    --set experimental.device_transport=true \
-    --stop-time 500ms > /tmp/ci-devt-100k.json
-python - <<'EOF'
-import json
-# vacuity guards: BOTH devt-on runs must actually have advanced cohorts
-# through the batched kernel — otherwise the identity diffs above
-# compared scalar against scalar and prove nothing
-on = json.load(open("/tmp/ci-devt-on.raw.json"))
-assert on.get("device_transport_engaged"), \
-    "web_cdn on-leg advanced zero cohorts — the identity diff is vacuous"
-big = json.load(open("/tmp/ci-devt-100k.json"))
-assert big.get("device_transport_engaged"), \
-    "100k leg advanced zero cohorts through the batched kernel"
-dt = big.get("device_transport", {})
-print(f"device-transport smoke OK: web_cdn byte-identical on/off "
-      f"({on['device_transport']['cohorts']} cohorts served); "
-      f"100k-host leg ran {big['events']} events, "
-      f"{dt.get('cohorts')} cohorts / {dt.get('acks_batched')} acks "
-      f"batched, {dt.get('misguesses')} misguesses")
-EOF
-
 echo "== telemetry smoke (gossip_churn: cross-policy stream hashes + report parse) =="
 telrun() {
     python -m shadow_tpu examples/gossip_churn.yaml --quiet \

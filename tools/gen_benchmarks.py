@@ -515,26 +515,22 @@ def gen_web_cdn(path="examples/web_cdn.yaml"):
 
 
 def gen_web_cdn_100k(path="examples/web_cdn_100k.yaml"):
-    """The device-transport benchmark config (PR 11; ROADMAP item 2):
-    100k page-loop clients against a 48-edge/4-origin CDN tier — the
-    regime where per-endpoint ticks (ack application + congestion-
-    control arithmetic at the edges, one scalar callback per ack)
-    dominate the round loop, so the columnar transport's one-kernel-
-    per-round cohort advance is measurable on a committed config.
-    Template-streamed like tor_1m: O(templates) YAML, not O(hosts).
-    Short stop_time by design — 100k concurrent fetch loops generate
-    millions of units in a few sim seconds, and the bench row runs
-    device-transport on/off interleaved median-of-3 on it."""
+    """Web CDN at scale: 100k page-loop clients against a
+    48-edge/4-origin CDN tier — the regime where per-endpoint ticks
+    (ack application + congestion-control arithmetic at the edges)
+    dominate the round loop, so it loads the stream transport under
+    web traffic. Template-streamed like tor_1m: O(templates) YAML, not
+    O(hosts). Short stop_time by design — 100k concurrent fetch loops
+    generate millions of units in a few sim seconds."""
     rng = np.random.default_rng(20260815)
     g = 24
     gml = random_gml(rng, g, min_lat_ms=5, max_lat_ms=50, max_loss=0.0,
                      bw_choices=("50 Mbit", "100 Mbit"))
     n_origins, n_edges, n_res, n_clients = 4, 48, 16, 100_000
     out = [
-        "# Device-transport benchmark (PR 11; bench.py web_cdn_100k row,",
-        "# tools/ci.sh devtransport smoke): 100k page-loop clients behind",
-        "# a 48-edge CDN tier — endpoint ticks dominate, the columnar",
-        "# transport's target regime. Template-streamed (quantity +",
+        "# Web CDN at scale: 100k page-loop clients behind a 48-edge CDN",
+        "# tier — stream endpoint ticks dominate, so it loads the stream",
+        "# transport under web traffic. Template-streamed (quantity +",
         "# network_node_ids), so 100k hosts stay a few KB of YAML.",
         "# Generated by tools/gen_benchmarks.py (seeded) - do not hand-edit.",
         "general:",

@@ -111,15 +111,6 @@ def method_def(cls: ast.ClassDef, name: str) -> ast.FunctionDef:
     raise ExtractError("method %s.%s not found" % (cls.name, name))
 
 
-def func_def(tree: ast.Module, name: str) -> ast.FunctionDef:
-    """Module-level function def (ops/transport_kernels.py's kernel
-    functions — the third twin surface)."""
-    for node in tree.body:
-        if isinstance(node, ast.FunctionDef) and node.name == name:
-            return node
-    raise ExtractError("function %r not found" % name)
-
-
 def class_attr(cls: ast.ClassDef, attr: str):
     for node in cls.body:
         if isinstance(node, ast.Assign) and len(node.targets) == 1 \

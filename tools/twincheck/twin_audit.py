@@ -24,11 +24,6 @@ tests/test_twincheck.py's mutation fixtures):
   abi-migration            colcore ABI bumped without a MIGRATION entry
   version-migration        checkpoint VERSION bumped without a MIGRATION entry
   c-intern:<line>          PyUnicode_InternFromString outside module init
-  kernel-const-drift:<N>   a shared transport constant differs between the
-                           scalar twins and ops/transport_kernels.py (the
-                           columnar third surface, PR 11)
-  kernel-cc-drift:<hook>   congestion-control literal drift between the
-                           scalar on_ack twins and the batched kernel
   shim-abi-drift:<NAME>    a shim fast-plane ABI constant (ring header
                            layout, clock-page words, readiness/oplog
                            regions, protocol sentinels) differs between
@@ -343,59 +338,6 @@ def audit(root) -> list:
                       sorted(c_lits - py_lits) or "{}"))
     except (P.ExtractError, C.ExtractError) as e:
         fail("extract:cc-arith", csrc_path, str(e))
-
-    # 8b. the columnar kernel twin (ops/transport_kernels.py, PR 11) ---------
-    # The batched transport kernels duplicate the scalar constants and
-    # the per-CC integer literals DELIBERATELY (a kernel cannot import
-    # from the module it must be audited against — the colcore.c
-    # argument, applied to the third surface). Cross-check both.
-    try:
-        ktree = P.parse(
-            root / "shadow_tpu" / "ops" / "transport_kernels.py")
-        kenv = P.module_constants(ktree)
-        tenv = envs["transport"]
-        for name in ("MSS", "INIT_CWND", "MIN_CWND"):
-            if kenv.get(name) != tenv.get(name):
-                fail("kernel-const-drift:%s" % name,
-                     root / "shadow_tpu" / "ops" / "transport_kernels.py",
-                     "%s=%s (kernel) but %s (transport.py scalar twin)" %
-                     (name, kenv.get(name), tenv.get(name)))
-        if kenv.get("NS_PER_MS") != envs["time"].get("NS_PER_MS"):
-            fail("kernel-const-drift:NS_PER_MS",
-                 root / "shadow_tpu" / "ops" / "transport_kernels.py",
-                 "NS_PER_MS=%s (kernel) but %s (core/time.py)" %
-                 (kenv.get("NS_PER_MS"), envs["time"].get("NS_PER_MS")))
-        # cc_id dispatch values vs the transport registry
-        for name, clsname in P.dict_literal_keys(
-                transport_tree, "CONGESTION_CONTROLS").items():
-            cc_id = P.class_attr(P.class_def(transport_tree, clsname),
-                                 "cc_id")
-            kv = kenv.get("CC_%s" % name.upper())
-            if kv != cc_id:
-                fail("kernel-const-drift:CC_%s" % name.upper(),
-                     root / "shadow_tpu" / "ops" / "transport_kernels.py",
-                     "cc %r: kernel CC_%s=%s vs transport cc_id=%s" %
-                     (name, name.upper(), kv, cc_id))
-        # per-CC on_ack literal sets: the kernel's cc_on_ack merges both
-        # algorithms (like colcore's cc_* functions), so compare against
-        # the union over the registry classes
-        py_lits: set = set()
-        for clsname in P.dict_literal_keys(
-                transport_tree, "CONGESTION_CONTROLS").values():
-            py_lits |= P.int_literal_set(
-                P.method_def(P.class_def(transport_tree, clsname),
-                             "on_ack"), envs["transport"])
-        k_lits = P.int_literal_set(P.func_def(ktree, "cc_on_ack"), kenv)
-        if py_lits != k_lits:
-            fail("kernel-cc-drift:on_ack",
-                 root / "shadow_tpu" / "ops" / "transport_kernels.py",
-                 "congestion-control literals diverged between the "
-                 "scalar twins and the batched kernel: scalar-only %s, "
-                 "kernel-only %s" %
-                 (sorted(py_lits - k_lits) or "{}",
-                  sorted(k_lits - py_lits) or "{}"))
-    except (P.ExtractError, SyntaxError, OSError) as e:
-        fail("extract:kernel", root, str(e))
 
     # 9. ABI / VERSION bumps require a MIGRATION.md entry --------------------
     import re as _re
