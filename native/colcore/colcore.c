@@ -27,7 +27,18 @@
  *                       and the C gossip app; Python callables (timers,
  *                       stream endpoints, plugin callbacks) are invoked
  *                       through the normal C API when a row or event
- *                       isn't C-handled.
+ *                       isn't C-handled. The round's hosts come from a
+ *                       due-time index, a min-heap of (lower bound on
+ *                       the host's heap root, host id), plus the hosts
+ *                       extract gave inbox rows: the same hosts, in the
+ *                       same id order, as a walk of every host with a
+ *                       non-empty heap, without the walk. A push that
+ *                       makes a new heap root lowers the host's key:
+ *                       EventQueue.on_first(time) -> Core.activate(hid,
+ *                       time) for Python pushes, cep_schedule for C ones.
+ *                       The Python planes keep the controller's active
+ *                       set instead; with this core attached nothing
+ *                       maintains it.
  *   - GossipState:      the gossip model's hot half (models/gossip.py
  *                       delegates; peer selection/logging stay Python).
  *
@@ -296,15 +307,12 @@ typedef struct {
   PyObject *heap;      /* owned: equeue._heap list */
   PyObject *live;      /* owned: equeue._live set */
   PyObject *cancelled; /* owned: equeue._cancelled set */
-  /* cached heap root for the per-round due check: an OWNED ref to the
-   * last-seen heap[0] plus its time. Owning the ref makes pointer
-   * identity sound (the object cannot be freed and its address reused
-   * while cached); if heap[0] is a different object, re-read. A root
-   * that was cancelled in place keeps its time — a conservative lower
-   * bound on the live head, which only costs a wasted scan, never a
-   * missed event. */
-  PyObject *head_cache;
-  int64_t head_time;
+  /* the host's key in the due-time index (Core_run_round): the time of
+   * its one valid index entry, DUE_NONE when it has none (empty heap);
+   * due_sel marks a host selected for the running round, re-keyed when
+   * the round's runs are done */
+  int64_t due_t;
+  int due_sel;
   int py_mode;         /* pcap etc.: dispatch through Python run_events */
   PyObject *egress;    /* owned: host.egress_rows (identity-stable) */
   PyObject *conns;     /* owned: host._conns dict (identity-stable) */
@@ -340,13 +348,17 @@ typedef struct {
   int cc_kind;
 } CHost;
 
+/* one entry of the due-time index */
+typedef struct {
+  int64_t t, hid;
+} DueEnt;
+
 typedef struct {
   PyObject_HEAD
   PyObject *plane;   /* borrowed: plane._c owns us (documented cycle-break) */
   PyObject *hosts;   /* owned list */
   PyObject *pending; /* owned deque */
   PyObject *deferred; /* owned set (plane._deferred) */
-  PyObject *active;  /* owned set (controller._active), via bind_active */
   PyObject *storebatch_cls; /* owned: colplane.StoreBatch */
   /* numpy arrays: owned refs + raw pointers */
   PyObject *arrs[11];
@@ -426,20 +438,22 @@ typedef struct {
   int64_t route_inline, route_spec_hit, route_none;
   int32_t *spec_dq; /* demand queue: host ids awaiting a window */
   int spec_dq_n, spec_dq_cap;
-  /* cached sorted snapshot of the active set (run_round's iteration
-   * order). Valid while its length matches the set: discards happen
-   * ONLY inside run_round (which updates both), so between rounds the
-   * set can only GROW — a size match proves the contents are identical
-   * and the per-round snapshot + qsort can be skipped entirely. */
-  int64_t *act_ids;
-  int64_t act_n;
-  int64_t act_cap;
-  /* ids added since the last refresh (extract's touched hosts and the
-   * Python-side activate hook both land here): when the set size equals
-   * act_n + pend_n, the refresh is a tiny sorted-merge instead of a
-   * full iterate + qsort of the whole set */
+  /* the due-time index: a binary min-heap of (key time, host id), where
+   * the key is a lower bound on the time of the host's heap root (see
+   * Core_run_round); due_on once index_heaps has built it */
+  DueEnt *due;
+  int64_t due_n, due_cap;
+  int due_on;
+  /* hosts extract gave inbox rows since the last round: examined by the
+   * next round whatever their key */
   int64_t *act_pend;
   int64_t act_pend_n, act_pend_cap;
+  /* the running round's selection, sorted by host id (scratch, one
+   * slot per host) */
+  int64_t *sel;
+  /* run_round's own time outside the per-host runs (events.select) and
+   * its counts: hosts the selection looked at, hosts run */
+  int64_t sel_ns, sel_calls, n_examined, n_run;
 } CoreObject;
 
 /* time the Python call a pair of these brackets (the outermost one, when
@@ -1099,7 +1113,7 @@ static int cmp_i64(const void *a, const void *b) {
   return (x > y) - (x < y);
 }
 
-/* record a newly-activated host id for the next refresh's merge */
+/* record a host given inbox rows for the next round's selection */
 static int act_pend_add(CoreObject *c, int64_t hid) {
   if (c->act_pend_n == c->act_pend_cap) {
     int64_t ncap = c->act_pend_cap ? c->act_pend_cap * 2 : 64;
@@ -1112,88 +1126,106 @@ static int act_pend_add(CoreObject *c, int64_t hid) {
   return 0;
 }
 
-/* re-snapshot the active set when membership changed outside run_round.
- * When every addition was recorded in act_pend (extract + the activate
- * hook), the refresh is a merge of the small sorted pend batch into the
- * sorted snapshot; a residual size mismatch (additions that bypassed
- * the hook) falls back to the full iterate + qsort. */
-static int act_refresh(CoreObject *c) {
-  Py_ssize_t na = PySet_GET_SIZE(c->active);
-  if ((int64_t)na == c->act_n) {
-    c->act_pend_n = 0; /* pend entries were already merged or stale */
-    return 0;
-  }
-  if (c->act_n >= 0 && (int64_t)na == c->act_n + c->act_pend_n) {
-    int64_t pn = c->act_pend_n, an = c->act_n;
-    if (an + pn > c->act_cap) {
-      int64_t ncap = c->act_cap ? c->act_cap : 256;
-      while (ncap < an + pn) ncap *= 2;
-      int64_t *nb = realloc(c->act_ids, sizeof(int64_t) * (size_t)ncap);
-      if (!nb) { PyErr_NoMemory(); return -1; }
-      c->act_ids = nb;
-      c->act_cap = ncap;
-    }
-    qsort(c->act_pend, (size_t)pn, sizeof(int64_t), cmp_i64);
-    /* backward two-way merge into act_ids */
-    int64_t i = an - 1, j = pn - 1, w = an + pn - 1;
-    while (j >= 0) {
-      if (i >= 0 && c->act_ids[i] > c->act_pend[j])
-        c->act_ids[w--] = c->act_ids[i--];
-      else
-        c->act_ids[w--] = c->act_pend[j--];
-    }
-    c->act_n = an + pn;
-    c->act_pend_n = 0;
-    return 0;
-  }
-  c->act_pend_n = 0;
-  if (na > c->act_cap) {
-    int64_t ncap = c->act_cap ? c->act_cap : 256;
-    while (ncap < na) ncap *= 2;
-    int64_t *nb = realloc(c->act_ids, sizeof(int64_t) * (size_t)ncap);
+/* ---- the due-time index ------------------------------------------------
+ * A binary min-heap of (key time, host id) over the hosts whose event
+ * heap is not empty. The invariant: such a host has an entry whose time
+ * is its due_t, and due_t is at most the time of its heap root (a
+ * cancelled root included). An entry whose time is not its host's due_t
+ * is stale and is dropped when it reaches the top; two entries of one
+ * host with the same time count once. What keeps the invariant:
+ *   - a push that makes a new heap root lowers the key to the root's time
+ *     (cep_schedule here; a Python push through EventQueue.on_first ->
+ *     Core.activate(hid, t));
+ *   - a pop only raises the root, and a cancel leaves it in place;
+ *   - a host selected for a round (due_sel) is re-keyed at its root once
+ *     the round's runs are done, so pushes meanwhile leave it alone;
+ *   - index_heaps keys every host from its heap (attach and restore). */
+#define DUE_NONE INT64_MAX
+
+static int due_push(CoreObject *c, int64_t t, int64_t hid) {
+  if (c->due_n == c->due_cap) {
+    int64_t ncap = c->due_cap ? c->due_cap * 2 : 256;
+    DueEnt *nb = realloc(c->due, sizeof(DueEnt) * (size_t)ncap);
     if (!nb) { PyErr_NoMemory(); return -1; }
-    c->act_ids = nb;
-    c->act_cap = ncap;
+    c->due = nb;
+    c->due_cap = ncap;
   }
-  Py_ssize_t k2 = 0;
-  PyObject *it = PyObject_GetIter(c->active);
-  if (!it) return -1;
-  PyObject *item;
-  while ((item = PyIter_Next(it))) {
-    if (k2 < na) c->act_ids[k2++] = PyLong_AsLongLong(item);
-    Py_DECREF(item);
+  DueEnt *d = c->due;
+  int64_t pos = c->due_n++;
+  while (pos > 0) {
+    int64_t parent = (pos - 1) >> 1;
+    if (d[parent].t <= t) break;
+    d[pos] = d[parent];
+    pos = parent;
   }
-  Py_DECREF(it);
-  if (PyErr_Occurred()) return -1;
-  qsort(c->act_ids, (size_t)k2, sizeof(int64_t), cmp_i64);
-  c->act_n = k2;
+  d[pos].t = t;
+  d[pos].hid = hid;
   return 0;
 }
 
-/* min pending event time over the active hosts — the skip-ahead path's
- * `min(equeue.next_time() for active)` without a Python genexpr. Drops
- * cancelled heads exactly like EventQueue.next_time, so the returned
- * instant (and hence the round grid) is identical to the Python path. */
+static DueEnt due_pop(CoreObject *c) {
+  DueEnt *d = c->due;
+  DueEnt top = d[0];
+  DueEnt last = d[--c->due_n];
+  int64_t n = c->due_n, pos = 0;
+  for (;;) {
+    int64_t ch = 2 * pos + 1;
+    if (ch >= n) break;
+    if (ch + 1 < n && d[ch + 1].t < d[ch].t) ch++;
+    if (last.t <= d[ch].t) break;
+    d[pos] = d[ch];
+    pos = ch;
+  }
+  if (n) d[pos] = last;
+  return top;
+}
+
+/* a push made `t` a root of host `hid`'s heap: key it there at most */
+static int due_lower(CoreObject *c, int64_t hid, int64_t t) {
+  CHost *h = &c->hs[hid];
+  if (h->due_sel || t >= h->due_t) return 0;
+  h->due_t = t;
+  return due_push(c, t, hid);
+}
+
+/* key host `hid` at its heap root (no entry when the heap is empty) */
+static int due_rekey(CoreObject *c, int64_t hid) {
+  CHost *h = &c->hs[hid];
+  h->due_sel = 0;
+  int64_t t = PyList_GET_SIZE(h->heap)
+      ? tup_i64(PyList_GET_ITEM(h->heap, 0), 0) : DUE_NONE;
+  if (t == h->due_t) return 0;
+  h->due_t = t;
+  return t == DUE_NONE ? 0 : due_push(c, t, hid);
+}
+
+/* min pending event time over the hosts — the skip-ahead path's
+ * `min(equeue.next_time() for active)` of the Python planes. The index's top is validated
+ * with heap_head, which drops cancelled heads exactly like
+ * EventQueue.next_time, and re-keyed until its key is its host's live
+ * head: every other key is at most its own live head, so that head is
+ * the minimum and the round grid cannot move. */
 static PyObject *Core_next_time(CoreObject *c, PyObject *noarg) {
   (void)noarg;
-  if (!c->active) {
-    PyErr_SetString(PyExc_RuntimeError, "bind_active() not called");
+  if (!c->due_on) {
+    PyErr_SetString(PyExc_RuntimeError, "index_heaps() not called");
     return NULL;
   }
-  if (act_refresh(c) < 0) return NULL;
-  int64_t best = T_NEVER_C;
-  for (int64_t i = 0; i < c->act_n; i++) {
-    int64_t hid = c->act_ids[i];
-    if (hid < 0 || hid >= c->H) continue;
+  while (c->due_n) {
+    DueEnt top = c->due[0];
+    CHost *h = &c->hs[top.hid];
+    if (top.t != h->due_t) { due_pop(c); continue; } /* stale */
     int err;
-    PyObject *head = heap_head(&c->hs[hid], &err);
+    PyObject *head = heap_head(h, &err);
     if (err) return NULL;
-    if (head) {
-      int64_t t = tup_i64(head, 0);
-      if (t < best) best = t;
-    }
+    int64_t t = head ? tup_i64(head, 0) : DUE_NONE;
+    if (t == top.t)
+      return PyLong_FromLongLong(t < T_NEVER_C ? t : T_NEVER_C);
+    due_pop(c);
+    h->due_t = t;
+    if (t != DUE_NONE && due_push(c, t, top.hid) < 0) return NULL;
   }
-  return PyLong_FromLongLong(best);
+  return PyLong_FromLongLong(T_NEVER_C);
 }
 
 /* ---- store construction (colplane._store_resolved twin) ---------------- */
@@ -2209,7 +2241,7 @@ static PyObject *Core_extract(CoreObject *c, PyObject *args) {
     CHost *h = &c->hs[touched[i]];
     if (multi && h->inbox_n > 1 && h->inbox_multi)
       qsort(h->inbox, (size_t)h->inbox_n, sizeof(IRow), cmp_irow);
-    if (h->py_mode) { /* (see below for the active-set add) */
+    if (h->py_mode) {
       /* pcap hosts: hand a plain Python list of 13-tuples to
        * Host.run_events (materialized here; py_mode hosts are rare) */
       PyObject *lst = PyList_New(h->inbox_n);
@@ -2226,15 +2258,7 @@ static PyObject *Core_extract(CoreObject *c, PyObject *args) {
       Py_DECREF(lst);
       if (r < 0) goto fail;
     }
-    /* activate, recording genuinely-new members for the merge refresh */
-    {
-      int has = PySet_Contains(c->active, h->id_obj);
-      if (has < 0) goto fail;
-      if (!has) {
-        if (PySet_Add(c->active, h->id_obj) < 0) goto fail;
-        if (act_pend_add(c, touched[i]) < 0) goto fail;
-      }
-    }
+    if (act_pend_add(c, touched[i]) < 0) goto fail;
   }
   free(touched);
   Py_RETURN_NONE;
@@ -2348,7 +2372,6 @@ static int Core_traverse(CoreObject *c, visitproc visit, void *arg) {
   Py_VISIT(c->hosts);
   Py_VISIT(c->pending);
   Py_VISIT(c->deferred);
-  Py_VISIT(c->active);
   Py_VISIT(c->storebatch_cls);
   Py_VISIT(c->xout);
   for (int i = 0; i < 11; i++) Py_VISIT(c->arrs[i]);
@@ -2360,7 +2383,6 @@ static int Core_traverse(CoreObject *c, visitproc visit, void *arg) {
       Py_VISIT(h->heap);
       Py_VISIT(h->live);
       Py_VISIT(h->cancelled);
-      Py_VISIT(h->head_cache);
       Py_VISIT(h->egress);
       Py_VISIT(h->conns);
       Py_VISIT(h->listeners);
@@ -2376,7 +2398,6 @@ static int Core_clear_gc(CoreObject *c) {
   Py_CLEAR(c->hosts);
   Py_CLEAR(c->pending);
   Py_CLEAR(c->deferred);
-  Py_CLEAR(c->active);
   Py_CLEAR(c->storebatch_cls);
   Py_CLEAR(c->xout);
   for (int i = 0; i < 11; i++) Py_CLEAR(c->arrs[i]);
@@ -2388,7 +2409,6 @@ static int Core_clear_gc(CoreObject *c) {
       Py_CLEAR(h->heap);
       Py_CLEAR(h->live);
       Py_CLEAR(h->cancelled);
-      Py_CLEAR(h->head_cache);
       Py_CLEAR(h->egress);
       Py_CLEAR(h->conns);
       Py_CLEAR(h->listeners);
@@ -2414,7 +2434,6 @@ static void Core_dealloc(CoreObject *c) {
       Py_XDECREF(h->heap);
       Py_XDECREF(h->live);
       Py_XDECREF(h->cancelled);
-      Py_XDECREF(h->head_cache);
       Py_XDECREF(h->egress);
       Py_XDECREF(h->conns);
       Py_XDECREF(h->listeners);
@@ -2430,8 +2449,9 @@ static void Core_dealloc(CoreObject *c) {
   while (c->pb_n) pbatch_release(c, c->pb_n - 1);
   free(c->pb);
   free(c->brow);
-  free(c->act_ids);
+  free(c->due);
   free(c->act_pend);
+  free(c->sel);
   if (c->spec) {
     for (int64_t i = 0; i < c->H; i++) {
       free(c->spec[i].min_a);
@@ -2456,7 +2476,6 @@ static void Core_dealloc(CoreObject *c) {
   Py_XDECREF(c->hosts);
   Py_XDECREF(c->pending);
   Py_XDECREF(c->deferred);
-  Py_XDECREF(c->active);
   Py_XDECREF(c->storebatch_cls);
   Py_XDECREF(c->xout);
   for (int i = 0; i < 11; i++) Py_XDECREF(c->arrs[i]);
@@ -2549,6 +2568,7 @@ static int Core_init(CoreObject *c, PyObject *args, PyObject *kwds) {
     CHost *h = &c->hs[i];
     PyObject *host = PyList_GET_ITEM(c->hosts, i);
     h->host = host;
+    h->due_t = DUE_NONE;
     h->id_obj = PyObject_GetAttr(host, S_id);
     if (!h->id_obj) return -1;
     if (PyLong_AsLongLong(h->id_obj) != i) {
@@ -2614,34 +2634,43 @@ static int Core_init(CoreObject *c, PyObject *args, PyObject *kwds) {
   return 0;
 }
 
-static PyObject *Core_bind_active(CoreObject *c, PyObject *arg) {
-  if (!PySet_Check(arg)) {
-    PyErr_SetString(PyExc_TypeError, "bind_active expects the active set");
-    return NULL;
+/* (re)build the due-time index from every host's heap: the controller
+ * calls it once the hosts' on_first hooks point at this core (attach and
+ * checkpoint restore) */
+static PyObject *Core_index_heaps(CoreObject *c, PyObject *noarg) {
+  (void)noarg;
+  if (!c->sel) {
+    c->sel = malloc(sizeof(int64_t) * (size_t)(c->H ? c->H : 1));
+    if (!c->sel) return PyErr_NoMemory();
   }
-  Py_INCREF(arg);
-  Py_XSETREF(c->active, arg);
-  c->act_n = -1; /* invalidate the sorted snapshot cache */
-  c->act_pend_n = 0;
+  c->due_n = 0;
+  for (int64_t i = 0; i < c->H; i++) {
+    CHost *h = &c->hs[i];
+    h->due_sel = 0;
+    h->due_t = DUE_NONE;
+    if (due_rekey(c, i) < 0) return NULL;
+  }
+  c->due_on = 1;
   Py_RETURN_NONE;
 }
 
-/* the activation hook (controller wires equeue.on_first and
- * plane.activate here when the C engine is attached): set-add + pend
- * record, so the next refresh merges instead of re-snapshotting */
-static PyObject *Core_activate(CoreObject *c, PyObject *arg) {
-  if (!c->active) {
-    PyErr_SetString(PyExc_RuntimeError, "bind_active() not called");
+/* the on_first hook of every host's event queue (the controller wires
+ * it when the C engine is attached): (hid, t) keys the host at most at t,
+ * which a push just made the first entry of its heap */
+static PyObject *Core_activate(CoreObject *c, PyObject *const *args,
+                               Py_ssize_t nargs) {
+  if (nargs != 2) {
+    PyErr_SetString(PyExc_TypeError, "activate(host_id, time)");
     return NULL;
   }
-  int has = PySet_Contains(c->active, arg);
-  if (has < 0) return NULL;
-  if (!has) {
-    int64_t hid = PyLong_AsLongLong(arg);
-    if (hid == -1 && PyErr_Occurred()) return NULL;
-    if (PySet_Add(c->active, arg) < 0) return NULL;
-    if (act_pend_add(c, hid) < 0) return NULL;
+  int64_t hid = PyLong_AsLongLong(args[0]);
+  int64_t t = PyLong_AsLongLong(args[1]);
+  if (PyErr_Occurred()) return NULL;
+  if (hid < 0 || hid >= c->H) {
+    PyErr_SetString(PyExc_ValueError, "host id out of range");
+    return NULL;
   }
+  if (due_lower(c, hid, t) < 0) return NULL;
   Py_RETURN_NONE;
 }
 
@@ -2738,13 +2767,16 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
     Py_XDECREF(ctrs);
   }
   PyObject *out = Py_BuildValue(
-      "{s{s(dL)s(dL)}s{sLsLsLsL}s{sLsLsL}sLsLsL}",
+      "{s{s(dL)s(dL)s(dL)}s{sLsLsLsLsLsL}s{sLsLsL}sLsLsL}",
       "timers",
       "events.py_callback", c->py_ns / 1e9, (long long)c->py_calls,
       "barrier.handoff", c->handoff_ns / 1e9, (long long)c->handoff_calls,
+      "events.select", c->sel_ns / 1e9, (long long)c->sel_calls,
       "counts",
       "events.emit", (long long)c->n_emit,
       "events.gossip", (long long)c->n_gossip,
+      "events.hosts_examined", (long long)c->n_examined,
+      "events.hosts_run", (long long)c->n_run,
       "tor.ctrl_to_py", (long long)c->n_ctrl_py,
       "tor.circuits", (long long)c->n_circuits,
       "routes",
@@ -2775,6 +2807,7 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
   c->res_units = 0;
   c->res_peak = c->res_held;
   c->n_emit = c->n_gossip = 0;
+  c->sel_ns = c->sel_calls = c->n_examined = c->n_run = 0;
   for (int j = 0; j < 3; j++) c->sect_ns[j] = c->sect_calls[j] = 0;
   c->n_ctrl_py = c->n_circuits = 0;
   c->route_inline = c->route_spec_hit = c->route_none = 0;
@@ -3023,13 +3056,13 @@ static PyMethodDef Core_methods[] = {
     {"refill_ingress", (PyCFunction)Core_refill_ingress, METH_VARARGS,
      "clamped ingress token refill for an elapsed window: (dt_ns)"},
     {"next_time", (PyCFunction)Core_next_time, METH_NOARGS,
-     "min pending event time over the active hosts (skip-ahead)"},
-    {"activate", (PyCFunction)Core_activate, METH_O,
-     "(host_id) -> None  add a host to the active set (merge-tracked)"},
+     "min pending event time over the hosts (skip-ahead)"},
+    {"activate", (PyCFunction)(void (*)(void))Core_activate, METH_FASTCALL,
+     "(host_id, time) -> None  key a host at a new heap root's time"},
     {"flush_acks", (PyCFunction)Core_flush_acks, METH_O,
      "(ack_hosts) -> None  flush each host's coalesced barrier acks"},
     {"run_round", (PyCFunction)Core_run_round, METH_VARARGS,
-     "per-round host loop over the bound active set: (round_end) -> n"},
+     "per-round host loop over the due hosts: (round_end) -> n"},
     {"emit_row", (PyCFunction)Core_emit_row, METH_VARARGS,
      "packed emission (Host.emit_msg delegate): (hid, kind, dst, size, "
      "t_emit, sport, dport, nbytes, seq, frag, nfrags, payload)"},
@@ -3046,8 +3079,8 @@ static PyMethodDef Core_methods[] = {
      "release every handed-off batch not yet stored (teardown)"},
     {"pending_batches", (PyCFunction)Core_pending_batches, METH_NOARGS,
      "number of handed-off device batches the core holds"},
-    {"bind_active", (PyCFunction)Core_bind_active, METH_O,
-     "bind the controller's active-host-id set"},
+    {"index_heaps", (PyCFunction)Core_index_heaps, METH_NOARGS,
+     "(re)build the due-time index from every host's event heap"},
     {"gossip_register", (PyCFunction)Core_gossip_register, METH_VARARGS,
      "(hid, port, peers) -> GossipState; registers the C dgram handler"},
     {"spec_demand", (PyCFunction)Core_spec_demand, METH_VARARGS,
@@ -3340,7 +3373,7 @@ static int64_t cep_now(CEp *e, int *err) {
 }
 
 static PyObject *S_schedule_in, *S_cancel_m, *S_rto_fire, *S_syn_fire,
-    *S_fin_fire, *S_drop_fire, *S_idle_fire, *S_seq_ctr, *S_on_first;
+    *S_fin_fire, *S_drop_fire, *S_idle_fire, *S_seq_ctr;
 
 static int64_t cep_window(CEp *e, int *err) {
   *err = 0;
@@ -3404,24 +3437,18 @@ static int cep_schedule(CEp *e, int64_t delay, PyObject *meth_name,
       : NULL;
   Py_DECREF(task);
   if (!entry) { Py_XDECREF(seq_obj); return -1; }
-  int was_empty = PyList_GET_SIZE(h->heap) == 0;
   if (heap_push(h->heap, entry) < 0 ||
       PySet_Add(h->live, seq_obj) < 0 ||
       attr_set_i64(h->equeue, S_seq_ctr, seq + 1) < 0) {
     Py_DECREF(seq_obj);
     return -1;
   }
-  if (was_empty) {
-    PyObject *of = PyObject_GetAttr(h->equeue, S_on_first);
-    if (!of) { Py_DECREF(seq_obj); return -1; }
-    if (of != Py_None) {
-      PyObject *r = PyObject_CallNoArgs(of);
-      Py_DECREF(of);
-      if (!r) { Py_DECREF(seq_obj); return -1; }
-      Py_DECREF(r);
-    } else {
-      Py_DECREF(of);
-    }
+  /* a new root keys the host in the due-time index (what on_first does
+   * for a Python push) */
+  if (PyList_GET_ITEM(h->heap, 0) == entry &&
+      due_lower(e->core, e->hid, now + delay) < 0) {
+    Py_DECREF(seq_obj);
+    return -1;
   }
   Py_XSETREF(*slot, seq_obj); /* the handle is the seq int, like push() */
   return 0;
@@ -4777,79 +4804,78 @@ static PyObject *Core_run_round(CoreObject *c, PyObject *args) {
   long long end_ll;
   if (!PyArg_ParseTuple(args, "L", &end_ll)) return NULL;
   int64_t end = end_ll;
-  if (!c->active) {
-    PyErr_SetString(PyExc_RuntimeError, "bind_active() not called");
+  if (!c->due_on) {
+    PyErr_SetString(PyExc_RuntimeError, "index_heaps() not called");
     return NULL;
   }
-  /* sorted active host ids (host-id execution order). The snapshot is
-   * CACHED across rounds: membership only shrinks inside this function
-   * (which updates the cache in place) and only grows elsewhere, so a
-   * set-size match proves the cache is exact and the per-round
-   * iterate + qsort — the dominant cost at 10k mostly-parked hosts —
-   * is skipped. */
-  if (act_refresh(c) < 0) return NULL;
+  /* select: every index entry keyed before the round's end, then the
+   * hosts given inbox rows; run in host-id order. Any host with an event
+   * or a row before `end` is among them, so the round runs exactly what
+   * a walk of every host with a non-empty heap would. */
+  int64_t t0 = nsnow();
+  int64_t *sel = c->sel;
+  int64_t n = 0, examined = c->act_pend_n;
+  while (c->due_n && c->due[0].t < end) {
+    DueEnt e = due_pop(c);
+    examined++;
+    CHost *h = &c->hs[e.hid];
+    if (e.t != h->due_t) continue; /* stale, or its twin already taken */
+    h->due_t = DUE_NONE;
+    h->due_sel = 1;
+    sel[n++] = e.hid;
+  }
+  for (int64_t j = 0; j < c->act_pend_n; j++) {
+    CHost *h = &c->hs[c->act_pend[j]];
+    if (h->due_sel) continue;
+    h->due_sel = 1;
+    sel[n++] = c->act_pend[j];
+  }
+  c->act_pend_n = 0;
+  qsort(sel, (size_t)n, sizeof(int64_t), cmp_i64);
+  c->n_examined += examined;
+  int64_t t1 = nsnow();
+  c->sel_ns += t1 - t0;
+  /* run: a host runs if it has inbox rows or its heap root (a cancelled
+   * one included) is before `end` */
   int64_t executed = 0;
-  int64_t *ids = c->act_ids;
-  int64_t k = c->act_n;
-  int64_t w = 0; /* write index: survivors stay, discards compact away */
-  int64_t i = 0;
-  for (; i < k; i++) {
-    int64_t hid = ids[i];
-    if (hid < 0 || hid >= c->H) continue;
+  int ok = 1;
+  for (int64_t i = 0; i < n; i++) {
+    int64_t hid = sel[i];
     CHost *h = &c->hs[hid];
-    int has_inbox = h->py_mode ? 0 : (h->inbox_n > 0);
-    Py_ssize_t hn = PyList_GET_SIZE(h->heap);
-    int heap_due = 0;
-    if (hn) {
-      /* owned-root cache: same object at heap[0] => same (conservative)
-       * head time; most parked hosts cost three pointer reads here */
-      PyObject *head = PyList_GET_ITEM(h->heap, 0);
-      if (head != h->head_cache) {
-        Py_INCREF(head);
-        Py_XSETREF(h->head_cache, head);
-        h->head_time = tup_i64(head, 0);
-      }
-      heap_due = h->head_time < end; /* conservative (cancelled ok) */
-    }
+    int heap_due = PyList_GET_SIZE(h->heap) &&
+                   tup_i64(PyList_GET_ITEM(h->heap, 0), 0) < end;
     if (h->py_mode) {
       /* pcap hosts etc.: the Python run_events consumes _inbox lists */
       PyObject *ib = PyObject_GetAttr(h->host, S_inbox);
-      int has_py_inbox = ib && ib != Py_None;
-      Py_XDECREF(ib);
-      if (!has_py_inbox && !heap_due) {
-        if (!hn) {
-          if (PySet_Discard(c->active, h->id_obj) < 0) goto fail;
-          continue; /* compacted out of the snapshot */
-        }
-        ids[w++] = hid;
-        continue;
-      }
-      int64_t t0 = py_enter(c);
+      if (!ib) { ok = 0; break; }
+      int has_py_inbox = ib != Py_None;
+      Py_DECREF(ib);
+      if (!has_py_inbox && !heap_due) continue;
+      c->n_run++;
+      int64_t tp = py_enter(c);
       PyObject *r = PyObject_CallMethodObjArgs(
           h->host, S_run_events, PyTuple_GET_ITEM(args, 0), NULL);
-      py_leave(c, t0);
-      if (!r) goto fail;
+      py_leave(c, tp);
+      if (!r) { ok = 0; break; }
       executed += PyLong_AsLongLong(r);
       Py_DECREF(r);
-      if (PyErr_Occurred()) goto fail;
-    } else if (has_inbox || heap_due) {
-      int64_t n = run_host_c(c, h, (int)hid, end);
-      if (n < 0) goto fail;
-      executed += n;
-    }
-    if (PyList_GET_SIZE(h->heap) == 0) {
-      if (PySet_Discard(c->active, h->id_obj) < 0) goto fail;
-    } else {
-      ids[w++] = hid;
+      if (PyErr_Occurred()) { ok = 0; break; }
+    } else if (h->inbox_n > 0 || heap_due) {
+      c->n_run++;
+      int64_t r = run_host_c(c, h, (int)hid, end);
+      if (r < 0) { ok = 0; break; }
+      executed += r;
     }
   }
-  c->act_n = w;
+  /* re-key every selected host at its heap root (also after a failed
+   * run, so the index still holds every pending host) */
+  int64_t t2 = nsnow();
+  for (int64_t i = 0; i < n; i++)
+    if (due_rekey(c, sel[i]) < 0) ok = 0;
+  c->sel_ns += nsnow() - t2;
+  c->sel_calls++;
+  if (!ok) return NULL;
   return PyLong_FromLongLong(executed);
-fail:
-  /* keep the untouched tail so the cache still mirrors the set */
-  for (; i < k; i++) ids[w++] = ids[i];
-  c->act_n = w;
-  return NULL;
 }
 
 
@@ -6843,7 +6869,6 @@ PyMODINIT_FUNC PyInit__colcore(void) {
   INTERN(S_drop_fire, "_drop_fire");
   INTERN(S_idle_fire, "_idle_fire");
   INTERN(S_seq_ctr, "_seq");
-  INTERN(S_on_first, "on_first");
 #undef INTERN
   O_zero = PyLong_FromLong(0);
   O_one = PyLong_FromLong(1);

@@ -204,9 +204,11 @@ class Controller:
         )
         policy = cfg.experimental.scheduler_policy
         backend = {"tpu_batch": "tpu", "tpu_mesh": "mesh"}.get(policy, "numpy")
-        # active-host tracking: per-round work is O(hosts with pending
-        # events), not O(all hosts) — the difference at 10k mostly-idle
-        # hosts. A host (re)activates on its queue's empty->nonempty edge.
+        # active-host tracking of the Python planes: per-round work is
+        # O(hosts with pending events), not O(all hosts) — the difference
+        # at 10k mostly-idle hosts. A host (re)activates when a push
+        # makes a new first entry of its queue (empty->nonempty among
+        # them). The C engine keeps a due-time index instead (_wire_hooks).
         self._active: set = set()  # host IDS (ints sort at C speed)
         if backend in ("tpu", "mesh"):
             # the tpu policies run the array-native columnar plane
@@ -220,7 +222,6 @@ class Controller:
                 backend=backend, tpu_options=cfg.experimental,
                 bootstrap_end=cfg.general.bootstrap_end_time,
             )
-            self.engine.activate = self._active.add
         else:
             self.engine = NetworkEngine(
                 self.graph, params, self.hosts, self.round_ns,
@@ -229,21 +230,11 @@ class Controller:
             )
         for h in self.hosts:
             h.engine = self.engine
-            h.equeue.on_first = partial(self._active.add, h.id)
         self.scheduler = make_scheduler(policy, self._sched_hosts(),
                                         cfg.general.parallelism)
-        # C engine (native colcore): owns the per-round host loop and
-        # maintains the active set directly
+        # C engine (native colcore): owns the per-round host loop
         self._c_core = getattr(self.engine, "_c", None)
-        if self._c_core is not None:
-            self._c_core.bind_active(self._active)
-            # route activations through the C core so its sorted
-            # active-set snapshot can merge new members incrementally
-            # instead of re-snapshotting the whole set every round
-            act = self._c_core.activate
-            self.engine.activate = act
-            for h in self.hosts:
-                h.equeue.on_first = partial(act, h.id)
+        self._wire_hooks()
 
         # processes: pyapp: plugins run in-process; any other path is a real
         # executable run under the native preload shim (SURVEY.md §7 phase 4)
@@ -396,6 +387,23 @@ class Controller:
         d["_replay_idx"] = 0
         return d
 
+    def _wire_hooks(self) -> None:
+        """Point the hosts' ``on_first`` hooks (and the Python columnar
+        plane's inbox ``activate``) at what picks each round's hosts: the
+        C core's due-time index, built here from every heap, or the
+        Python planes' active set."""
+        core = self._c_core
+        if core is not None:
+            for h in self.hosts:
+                h.equeue.on_first = partial(core.activate, h.id)
+            core.index_heaps()
+            return
+        if hasattr(self.engine, "emitters"):  # columnar Python paths
+            self.engine.activate = self._active.add
+        for h in self.hosts:
+            h.equeue.on_first = partial(_activate_id, self._active.add,
+                                        h.id)
+
     def _reattach_runtime(self, mirror_log: bool = True) -> None:
         """Rebuild the runtime-only pieces after a checkpoint restore:
         output location, logger mirroring, scheduler threads, the device
@@ -428,23 +436,12 @@ class Controller:
         # C engine: rebuild over the restored structures and REWIRE the
         # activation hooks — the pickled hooks may reference the dead
         # core's placeholder (checkpoint._DeadCoreHandle)
-        self._c_core = None
         attach = getattr(self.engine, "attach_colcore", None)
-        core = attach(cfg.experimental) if attach is not None else None
-        if core is not None:
-            self._c_core = core
-            core.bind_active(self._active)
-            act = core.activate
-            self.engine.activate = act
-            for h in self.hosts:
-                h.equeue.on_first = partial(act, h.id)
-            if self.faults is not None:
-                core.set_faults_active(True)
-        else:
-            if hasattr(self.engine, "emitters"):  # columnar Python paths
-                self.engine.activate = self._active.add
-            for h in self.hosts:
-                h.equeue.on_first = partial(self._active.add, h.id)
+        self._c_core = attach(cfg.experimental) if attach is not None \
+            else None
+        self._wire_hooks()
+        if self._c_core is not None and self.faults is not None:
+            self._c_core.set_faults_active(True)
         from shadow_tpu import checkpoint as _ckpt
 
         _ckpt.finish_colcore_adopt(self)
@@ -764,8 +761,9 @@ class Controller:
             hosts = self.hosts
             with sp_events:
                 if self._c_core is not None:
-                    # the C loop snapshots + sorts the active set, merges
-                    # each host's inbox/heap, and discards drained hosts
+                    # the C loop runs the hosts its due-time index and
+                    # the round's inbox rows select, merging each one's
+                    # inbox and heap
                     executed = self._c_core.run_round(round_end)
                 else:
                     active = [hosts[i] for i in sorted(self._active)]
@@ -1088,6 +1086,12 @@ class Controller:
             **({"telemetry": self.telemetry.summary()}
                if self.telemetry is not None else {}),
         }
+
+
+def _activate_id(add, host_id: int, _time: SimTime) -> None:
+    """A Python plane's ``on_first`` hook: the host joins the active
+    set (the time is for the C engine's index)."""
+    add(host_id)
 
 
 def _default_ip(host_id: int) -> str:

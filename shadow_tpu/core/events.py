@@ -51,10 +51,12 @@ class EventQueue:
         self._seq = 0
         self._live: set[int] = set()  # seqs pushed and not yet popped
         self._cancelled: set[int] = set()
-        #: fired on the empty->nonempty transition; the controller uses it
-        #: to maintain the active-host set (per-round work is then O(active
-        #: hosts), not O(all hosts) — the difference at 10k+ mostly-idle
-        #: hosts)
+        #: ``on_first(time)`` fires when a push makes the pushed entry the
+        #: heap's first (the empty->nonempty transition among them). The
+        #: controller keeps per-round work O(hosts with work) with it, not
+        #: O(all hosts) — the difference at 10k+ mostly-idle hosts: the
+        #: Python planes add the host to the active set, the C engine keys
+        #: the host at ``time`` in its due-time index (colcore.c).
         self.on_first = None
 
     def push(self, time: SimTime, task: Callable[[], None],
@@ -62,11 +64,11 @@ class EventQueue:
         """Schedule ``task`` at ``time``; returns a handle usable with cancel()."""
         seq = self._seq
         self._seq += 1
-        was_empty = not self._heap
-        heapq.heappush(self._heap, (time, band, key if key >= 0 else seq, seq, task))
+        heap = self._heap
+        heapq.heappush(heap, (time, band, key if key >= 0 else seq, seq, task))
         self._live.add(seq)
-        if was_empty and self.on_first is not None:
-            self.on_first()
+        if heap[0][3] == seq and self.on_first is not None:
+            self.on_first(time)
         return seq
 
     def cancel(self, handle: int) -> None:

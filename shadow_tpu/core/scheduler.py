@@ -47,9 +47,9 @@ def _run_hosts(hosts, round_end: SimTime) -> int:
     discards it correctly. Inside run_events the per-host inbox merges
     with the timer heap against a cached head (one identity check per hot
     row; host.py run_events), and the C engine's run_round applies the
-    same two disciplines natively plus a cached sorted active-set
-    snapshot — heap churn at 100k-host tor scale made both first-order
-    costs (PR 5)."""
+    same two disciplines natively (heap churn at 100k-host tor scale
+    made both first-order costs) over the hosts its due-time index picks
+    (native/colcore/colcore.c) instead of a walk."""
     n = 0
     for h in hosts:
         heap = h.equeue._heap

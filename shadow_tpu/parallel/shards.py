@@ -585,7 +585,7 @@ class _ShardWorker:
                 # the next-event minimum is only consumed by the global
                 # skip-ahead reduction, which requires EVERY shard to have
                 # executed zero events — so a shard that executed anything
-                # can ship a placeholder and skip the active-set scan (the
+                # can ship a placeholder and skip the next-time scan (the
                 # single-process loop likewise only scans on quiet rounds;
                 # scanning every round cost ~13 ms/round at 100k hosts)
                 if executed == 0:
