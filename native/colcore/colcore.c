@@ -54,6 +54,7 @@
 #include <stddef.h>
 #include <stdint.h>
 #include <string.h>
+#include <math.h>
 
 /* Python < 3.12 spells the member API via structmember.h (T_INT/READONLY);
  * 3.12 moved the canonical names into Python.h. Compile against both. */
@@ -425,15 +426,24 @@ typedef struct {
   int64_t pb_next;
   int64_t res_units, res_held, res_peak;
   int64_t n_emit, n_gossip;
-  /* self time of the endpoint and tor-relay sections (sect_enter/leave):
-   * events.stream (CEp arrival handling) and events.relay (relay, exit
-   * and client-sink framing and forwarding); a Python call bracketed by
+  /* self time of the endpoint, tor-relay and Bitcoin sections
+   * (sect_enter/leave): events.stream (CEp arrival handling),
+   * events.relay (relay, exit and client-sink framing and forwarding)
+   * and events.btc (a Bitcoin node's message handling, announce queues,
+   * trickle timers and block relay); a Python call bracketed by
    * py_enter inside them (a control cell handed to the model) is charged
    * to neither. Counts of control cells handed to Python and of client
    * circuits (C sinks) made. */
-  int64_t sect_ns[3], sect_calls[3], sect_t;
+  int64_t sect_ns[4], sect_calls[4], sect_t;
   int sect_cur;
   int64_t n_ctrl_py, n_circuits;
+  /* the Bitcoin nodes by host id (borrowed; each node clears its slot
+   * when it goes) and the counts of their trickle timer firings, INV
+   * messages and entries, TX and cmpctblock messages and getblocktxn
+   * requests (models/bitcoin.py) */
+  struct BtcNode **btc;
+  int64_t n_trickles, n_inv_msgs, n_inv_entries, n_tx_msgs, n_cmpct_msgs,
+      n_gbt;
   /* where the C barrier's sent units were drawn (devroute.ROUTES) */
   int64_t route_inline, route_spec_hit, route_none;
   int32_t *spec_dq; /* demand queue: host ids awaiting a window */
@@ -477,7 +487,7 @@ static inline void py_leave(CoreObject *c, int64_t t0) {
 /* section self time: each switch reads the clock once and charges the
  * elapsed time to the section it leaves (sections nest: the relay's
  * forwarding runs inside the endpoint's arrival handling) */
-enum { SECT_NONE, SECT_STREAM, SECT_RELAY };
+enum { SECT_NONE, SECT_STREAM, SECT_RELAY, SECT_BTC };
 static inline int sect_enter(CoreObject *c, int sect) {
   int64_t t = nsnow();
   int prev = c->sect_cur;
@@ -995,6 +1005,12 @@ static int dispatch_c(CoreObject *c, CHost *h, int hid, IRow *ir,
                          ir->payload ? ir->payload : Py_None, ir->peer);
 }
 
+/* the Bitcoin nodes' trickle timers (defined with the nodes below): the
+ * run loop fires them itself, as C */
+struct BtcTimer;
+static PyTypeObject BtcTimer_Type;
+static int btc_timer_fire(struct BtcTimer *t, int64_t now);
+
 /* ---- Host.run_events twin over the C inbox ---------------------------- */
 static int64_t run_host_c(CoreObject *c, CHost *h, int hid, int64_t end) {
   /* no entry clock read: inbox rows satisfy t >= host._now (rows are
@@ -1075,13 +1091,20 @@ static int64_t run_host_c(CoreObject *c, CHost *h, int hid, int64_t end) {
         now = tup_i64(ev, 0);
         now_dirty = 0;
         if (attr_set_i64(h->host, S_now, now) < 0) { Py_DECREF(ev); goto mdone; }
-        int64_t t0 = py_enter(c);
-        PyObject *res = PyObject_CallNoArgs(PyTuple_GET_ITEM(ev, 4));
-        py_leave(c, t0);
-        Py_DECREF(ev);
-        if (!res) goto mdone;
-        Py_DECREF(res);
-        if (attr_i64(h->host, S_now, &now) < 0) goto mdone;
+        PyObject *task = PyTuple_GET_ITEM(ev, 4);
+        if (Py_TYPE(task) == &BtcTimer_Type) {
+          int rf = btc_timer_fire((struct BtcTimer *)task, now);
+          Py_DECREF(ev);
+          if (rf < 0) goto mdone;
+        } else {
+          int64_t t0 = py_enter(c);
+          PyObject *res = PyObject_CallNoArgs(task);
+          py_leave(c, t0);
+          Py_DECREF(ev);
+          if (!res) goto mdone;
+          Py_DECREF(res);
+          if (attr_i64(h->host, S_now, &now) < 0) goto mdone;
+        }
         n++;
         continue;
       }
@@ -2462,6 +2485,7 @@ static void Core_dealloc(CoreObject *c) {
     free(c->spec);
   }
   free(c->spec_dq);
+  free(c->btc);
   if (c->xrecs) {
     for (int j = 0; j < c->shard_n; j++) {
       for (int i = 0; i < c->xn[j]; i++) Py_XDECREF(c->xpay[j][i]);
@@ -2767,7 +2791,7 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
     Py_XDECREF(ctrs);
   }
   PyObject *out = Py_BuildValue(
-      "{s{s(dL)s(dL)s(dL)}s{sLsLsLsLsLsL}s{sLsLsL}sLsLsL}",
+      "{s{s(dL)s(dL)s(dL)}s{sLsLsLsLsLsLsLsLsLsLsLsL}s{sLsLsL}sLsLsL}",
       "timers",
       "events.py_callback", c->py_ns / 1e9, (long long)c->py_calls,
       "barrier.handoff", c->handoff_ns / 1e9, (long long)c->handoff_calls,
@@ -2779,6 +2803,12 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
       "events.hosts_run", (long long)c->n_run,
       "tor.ctrl_to_py", (long long)c->n_ctrl_py,
       "tor.circuits", (long long)c->n_circuits,
+      "btc.trickles", (long long)c->n_trickles,
+      "btc.inv_msgs", (long long)c->n_inv_msgs,
+      "btc.inv_entries", (long long)c->n_inv_entries,
+      "btc.tx_msgs", (long long)c->n_tx_msgs,
+      "btc.cmpct_msgs", (long long)c->n_cmpct_msgs,
+      "btc.getblocktxn", (long long)c->n_gbt,
       "routes",
       "inline", (long long)c->route_inline,
       "spec_hit", (long long)c->route_spec_hit,
@@ -2787,11 +2817,12 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
       "resident_units", (long long)c->res_units,
       "resident_peak", (long long)c->res_peak);
   if (!out) return NULL;
-  /* the endpoint and relay sections only where they ran (a gossip run
-   * has neither) */
-  static const char *sect_names[3] = {NULL, "events.stream", "events.relay"};
+  /* the endpoint, relay and Bitcoin sections only where they ran (a
+   * gossip run has none) */
+  static const char *sect_names[4] = {NULL, "events.stream", "events.relay",
+                                      "events.btc"};
   PyObject *timers = PyDict_GetItemString(out, "timers");
-  for (int j = SECT_STREAM; j <= SECT_RELAY; j++) {
+  for (int j = SECT_STREAM; j <= SECT_BTC; j++) {
     if (!c->sect_calls[j]) continue;
     PyObject *v = Py_BuildValue("(dL)", c->sect_ns[j] / 1e9,
                                 (long long)c->sect_calls[j]);
@@ -2808,8 +2839,10 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
   c->res_peak = c->res_held;
   c->n_emit = c->n_gossip = 0;
   c->sel_ns = c->sel_calls = c->n_examined = c->n_run = 0;
-  for (int j = 0; j < 3; j++) c->sect_ns[j] = c->sect_calls[j] = 0;
+  for (int j = 0; j < 4; j++) c->sect_ns[j] = c->sect_calls[j] = 0;
   c->n_ctrl_py = c->n_circuits = 0;
+  c->n_trickles = c->n_inv_msgs = c->n_inv_entries = 0;
+  c->n_tx_msgs = c->n_cmpct_msgs = c->n_gbt = 0;
   c->route_inline = c->route_spec_hit = c->route_none = 0;
   return out;
 }
@@ -2819,6 +2852,7 @@ static PyObject *Core_flush_acks(CoreObject *c, PyObject *arg);
 static PyObject *Core_run_round(CoreObject *c, PyObject *args);
 static PyObject *Core_relay_new(CoreObject *c, PyObject *args);
 static PyObject *Core_tor_client_sink(CoreObject *c, PyObject *args);
+static PyObject *Core_btc_node(CoreObject *c, PyObject *args);
 static PyObject *Core_adopt(CoreObject *c, PyObject *arg);
 
 /* -- fault lifecycle (shadow_tpu/faults.py) ------------------------------ */
@@ -3101,6 +3135,9 @@ static PyMethodDef Core_methods[] = {
      "(hid, on_ctrl) -> Relay (C tor-relay data path)"},
     {"tor_client_sink", (PyCFunction)Core_tor_client_sink, METH_VARARGS,
      "(endpoint, on_cell) -> TorSink (C tor-client data path)"},
+    {"btc_node", (PyCFunction)Core_btc_node, METH_VARARGS,
+     "(hid, port, outbound peers, full-relay count, rng seed, inbound cap, "
+     "outbound and inbound trickle means in ns) -> BtcNode"},
     {"bind_shard", (PyCFunction)Core_bind_shard, METH_VARARGS,
      "install the multi-process shard filter: (shard_id, n_shards, xout "
      "per-shard row lists — or None for the packed send path); "
@@ -3326,6 +3363,9 @@ typedef struct CEp {
    * terminal frame parsing + DATA-body byte counting in C, one Python
    * callback per CONTROL cell (models/tor.py TorClient twin) */
   struct CTorSink *tsink;
+  /* a Bitcoin node's peer (borrowed back-pointer; the node owns the ep):
+   * delivery, drain and connect go to models/bitcoin.py's C half */
+  struct BtcPeer *btc;
   /* C tor-exit stream (OWNED; the stream borrows the ep back): counted
    * server bytes re-framed as circuit DATA cells in C (TorExit twin) */
   PyObject *xsink;
@@ -3362,6 +3402,10 @@ static int tsink_feed(struct CTorSink *s, int64_t nbytes,
 static int tsink_pump(struct CTorSink *s, int64_t now);
 struct CExitStream;
 static int exit_feed(struct CExitStream *s, int64_t now, int64_t nbytes);
+struct BtcPeer;
+static int btc_feed(struct BtcPeer *p, int64_t now, int64_t nbytes);
+static int btc_pump(struct BtcPeer *p, int64_t now);
+static int btc_connected(struct BtcPeer *p, int64_t now);
 
 /* current sim clock of the owning host: used by timer-driven entry
  * points; row-driven entry points pass `now` explicitly */
@@ -3729,6 +3773,8 @@ static int cs_on_ack(CEp *e, int64_t now, int64_t cum_ack, int64_t wnd,
       int r = e->sink ? relay_drain(e->sink, now) : tsink_pump(e->tsink, now);
       sect_leave(c, prev);
       if (r < 0) return -1;
+    } else if (e->btc && e->buffered < e->send_buffer) {
+      if (btc_pump(e->btc, now) < 0) return -1;
     } else if (e->tgen_mode == 1 && e->buffered < e->send_buffer) {
       /* TGenServer on_drain twin (push is a no-op with no backlog,
        * exactly like the Python closure called with room) */
@@ -3899,6 +3945,13 @@ static int cr_deliver(CEp *e, int64_t now, int64_t nbytes,
                       PyObject *payload) {
   e->rcv_nxt += nbytes;
   e->bytes_received += nbytes;
+  if (e->btc) {
+    CoreObject *c = e->core;
+    int prev = sect_enter(c, SECT_BTC);
+    int r = btc_feed(e->btc, now, nbytes);
+    sect_leave(c, prev);
+    return r;
+  }
   if (e->tsink || e->xsink || (e->sink && e->tgen_mode == 0)) {
     CoreObject *c = e->core;
     int prev = sect_enter(c, SECT_RELAY);
@@ -4116,7 +4169,9 @@ static int ce_handle_fields(CEp *e, int64_t now, int k, int64_t nbytes,
       e->state = ST_ESTABLISHED;
       e->adv_wnd = seq;
       if (ce_cancel_ctl(e) < 0) return -1;
-      if (e->on_connected && e->on_connected != Py_None) {
+      if (e->btc) {
+        if (btc_connected(e->btc, now) < 0) return -1;
+      } else if (e->on_connected && e->on_connected != Py_None) {
         PyObject *tn = PyLong_FromLongLong(now);
         if (!tn) return -1;
         PyObject *r = PyObject_CallOneArg(e->on_connected, tn);
@@ -5980,6 +6035,729 @@ static PyObject *Core_tor_client_sink(CoreObject *c, PyObject *args) {
 }
 
 /* ======================================================================
+ * Bitcoin Core relay (models/bitcoin.py's hot half).
+ *
+ * One BtcNode per node: its peers (a C stream endpoint each, outbound
+ * ones first in draw order, inbound ones in the order they were
+ * accepted), its transactions as bit masks over the experiment's own
+ * transactions (txid i is bit i), the block, and the trickle timers.
+ * Messages travel as counted stream bytes at their wire size; what a
+ * message says rides a per-link queue that the sending peer appends to
+ * when it queues the message and the receiving peer pops when the
+ * message's last byte is delivered in order (the stream is reliable and
+ * ordered, so this is the byte stream's content, read without building
+ * it). Messages a handler queues to one peer go out as one write when
+ * the handler ends (Core's MSG_MORE sends coalesce the same way); writes
+ * the send buffer refuses wait in `pend` and go out on drain.
+ *
+ * Timers are BtcTimer objects on the host's event heap: one per
+ * outbound peer (mean 2 s) and one shared by every inbound peer (mean
+ * 5 s), armed when a queue turns non-empty, with exponential delays from
+ * a splitmix64 stream seeded from the host RNG. The run loop calls them
+ * directly, not as Python.
+ *
+ * Self time goes to the section events.btc; the stream sends a flush
+ * makes go to events.stream.
+ * ====================================================================== */
+
+enum { BM_VERSION, BM_VERACK, BM_WTXIDRELAY, BM_SENDHEADERS, BM_SENDCMPCT,
+       BM_INV, BM_GETDATA, BM_TX, BM_HEADERS, BM_CMPCTBLOCK,
+       BM_GETBLOCKTXN, BM_BLOCKTXN, BM_N };
+
+/* payload bytes of each message; the 24-byte message header comes on
+ * top (the sizes models/bitcoin.py's docstring lists) */
+#define BTC_HDR 24
+#define BTC_VERSION_LEN 102
+#define BTC_SENDCMPCT_LEN 9
+#define BTC_INV_ENTRY 36
+#define BTC_TX_LEN 400
+#define BTC_HEADERS_LEN 82
+#define BTC_GETDATA_BLOCK_LEN 37
+#define BTC_BLOCK_TXS 3000
+#define BTC_CMPCT_LEN (88 + 6 * BTC_BLOCK_TXS + 250)
+#define BTC_GBT_INDEX 3
+#define BTC_HB_PEERS 3
+/* the delay of a trickle timer (a planted test fault replaces it) */
+#define BTC_TRICKLE_DELAY(n, mean) btc_exp((n), (mean))
+
+typedef struct {
+  uint8_t type, flag;
+  int32_t size; /* wire bytes */
+  uint64_t mask; /* inv/getdata/tx/cmpctblock/getblocktxn/blocktxn txs */
+} BtcMsg;
+
+typedef struct BtcPeer {
+  CEp *ep;                /* owned; ep->btc is the borrowed back-pointer */
+  struct BtcNode *node;   /* borrowed: the node owns its peers */
+  struct BtcPeer *remote; /* the other end: its queue arrives here */
+  int idx, outbound, full, ready, hb_to, has_block, armed, dirty;
+  uint64_t known; /* txs the peer is known to have */
+  uint64_t q;     /* txs queued for the peer's next INV */
+  int64_t cork;   /* bytes queued by the running handler */
+  int64_t pend;   /* bytes the send buffer has not taken yet */
+  int64_t rx_got; /* bytes of the head message delivered so far */
+  BtcMsg *mq;     /* messages written to the peer, oldest first */
+  int mq_head, mq_n, mq_cap;
+  int64_t n_inv, n_tx, n_blk, fires;
+} BtcPeer;
+
+typedef struct BtcNode {
+  PyObject_HEAD
+  CoreObject *core; /* owned */
+  int hid, port, nout, nfull, max_inbound, ninbound;
+  int32_t *outs;
+  BtcPeer **peers;
+  int npeers, peers_cap;
+  int *dirty;
+  int ndirty;
+  uint64_t rng;
+  int64_t trickle_out, trickle_in;
+  uint64_t known, mempool, requested, block_mask, gbt_mask;
+  int have_block, block_state, n_hb, inb_armed;
+  int64_t block_t, gbt_sent, inb_fires;
+  int64_t sent[BM_N];
+} BtcNode;
+
+typedef struct BtcTimer {
+  PyObject_HEAD
+  BtcNode *node; /* owned */
+  int idx;       /* the outbound peer, or -1: the inbound peers' timer */
+} BtcTimer;
+
+static PyTypeObject BtcNode_Type;
+static PyObject *S_next_ephemeral;
+
+static int64_t btc_varint(int64_t n) {
+  return n < 253 ? 1 : n <= 0xFFFF ? 3 : n <= 0xFFFFFFFFLL ? 5 : 9;
+}
+
+/* splitmix64 */
+static uint64_t btc_next(BtcNode *n) {
+  uint64_t z = (n->rng += 0x9E3779B97F4A7C15ULL);
+  z = (z ^ (z >> 30)) * 0xBF58476D1CE4E5B9ULL;
+  z = (z ^ (z >> 27)) * 0x94D049BB133111EBULL;
+  return z ^ (z >> 31);
+}
+
+/* an exponential delay of mean `mean` ns: -log of a uniform in (0, 1]
+ * from the top 53 bits, times the mean, truncated */
+static int64_t btc_exp(BtcNode *n, int64_t mean) {
+  double u = (double)((btc_next(n) >> 11) + 1) * 0x1p-53;
+  return (int64_t)(-log(u) * (double)mean);
+}
+
+static int btc_schedule(BtcNode *n, int64_t now, int64_t delay, int idx) {
+  CoreObject *c = n->core;
+  CHost *h = &c->hs[n->hid];
+  int64_t seq;
+  if (attr_i64(h->equeue, S_seq_ctr, &seq) < 0) return -1;
+  BtcTimer *t = PyObject_GC_New(BtcTimer, &BtcTimer_Type);
+  if (!t) return -1;
+  Py_INCREF(n);
+  t->node = n;
+  t->idx = idx;
+  PyObject_GC_Track((PyObject *)t);
+  PyObject *seq_obj = PyLong_FromLongLong(seq);
+  /* (time, band=BAND_APP, key=seq, seq, task), as cep_schedule */
+  PyObject *entry = seq_obj ? Py_BuildValue("(LiOON)", (long long)(now + delay),
+                                            1, seq_obj, seq_obj, t)
+                            : NULL;
+  if (!entry) {
+    if (seq_obj) Py_DECREF(seq_obj);
+    else Py_DECREF(t);
+    return -1;
+  }
+  if (heap_push(h->heap, entry) < 0 || PySet_Add(h->live, seq_obj) < 0 ||
+      attr_set_i64(h->equeue, S_seq_ctr, seq + 1) < 0) {
+    Py_DECREF(seq_obj);
+    return -1;
+  }
+  int r = 0;
+  if (PyList_GET_ITEM(h->heap, 0) == entry)
+    r = due_lower(c, n->hid, now + delay);
+  Py_DECREF(seq_obj);
+  return r;
+}
+
+/* queue one message to `p`: it joins the link's queue and the handler's
+ * write to `p` */
+static int btc_queue(BtcNode *n, BtcPeer *p, int type, int flag,
+                     uint64_t mask, int64_t len) {
+  if (p->mq_n == p->mq_cap) {
+    int ncap = p->mq_cap ? p->mq_cap * 2 : 8;
+    BtcMsg *nq = malloc((size_t)ncap * sizeof(BtcMsg));
+    if (!nq) { PyErr_NoMemory(); return -1; }
+    for (int i = 0; i < p->mq_n; i++)
+      nq[i] = p->mq[(p->mq_head + i) % p->mq_cap];
+    free(p->mq);
+    p->mq = nq;
+    p->mq_head = 0;
+    p->mq_cap = ncap;
+  }
+  BtcMsg *m = &p->mq[(p->mq_head + p->mq_n++) % p->mq_cap];
+  m->type = (uint8_t)type;
+  m->flag = (uint8_t)flag;
+  m->size = (int32_t)(BTC_HDR + len);
+  m->mask = mask;
+  p->cork += BTC_HDR + len;
+  if (!p->dirty) {
+    p->dirty = 1;
+    n->dirty[n->ndirty++] = p->idx;
+  }
+  n->sent[type]++;
+  return 0;
+}
+
+/* offer the bytes waiting for the send buffer (the drain path runs this
+ * inside the endpoint's section) */
+static int btc_pump(BtcPeer *p, int64_t now) {
+  if (p->pend <= 0) return 0;
+  int64_t acc = cs_send(p->ep, now, p->pend, NULL, 0);
+  if (acc < 0) return -1;
+  p->pend -= acc;
+  return 0;
+}
+
+/* the end of a handler: one write per peer it queued to, in the order
+ * it first queued to them */
+static int btc_flush(BtcNode *n, int64_t now) {
+  if (!n->ndirty) return 0;
+  CoreObject *c = n->core;
+  int prev = sect_enter(c, SECT_STREAM);
+  int r = 0;
+  for (int i = 0; i < n->ndirty && r == 0; i++) {
+    BtcPeer *p = n->peers[n->dirty[i]];
+    p->dirty = 0;
+    p->pend += p->cork;
+    p->cork = 0;
+    r = btc_pump(p, now);
+  }
+  n->ndirty = 0;
+  sect_leave(c, prev);
+  return r;
+}
+
+/* a transaction's announcement: queued for every ready full-relay peer
+ * not known to have it; a queue turning non-empty arms its timer */
+static int btc_relay_tx(BtcNode *n, int64_t now, uint64_t bit) {
+  for (int i = 0; i < n->npeers; i++) {
+    BtcPeer *p = n->peers[i];
+    if (!p->ready || !p->full || (p->known & bit)) continue;
+    int was = p->q != 0;
+    p->q |= bit;
+    if (was) continue;
+    if (p->outbound) {
+      if (!p->armed) {
+        p->armed = 1;
+        if (btc_schedule(n, now, BTC_TRICKLE_DELAY(n, n->trickle_out), i) < 0)
+          return -1;
+      }
+    } else if (!n->inb_armed) {
+      n->inb_armed = 1;
+      if (btc_schedule(n, now, BTC_TRICKLE_DELAY(n, n->trickle_in), -1) < 0)
+        return -1;
+    }
+  }
+  return 0;
+}
+
+static int btc_send_inv(BtcNode *n, BtcPeer *p) {
+  uint64_t e = p->q & n->mempool & ~p->known;
+  p->q = 0;
+  p->armed = 0;
+  if (!e) return 0;
+  p->known |= e;
+  int64_t k = __builtin_popcountll(e);
+  p->n_inv++;
+  n->core->n_inv_msgs++;
+  n->core->n_inv_entries += k;
+  return btc_queue(n, p, BM_INV, 0, e, btc_varint(k) + BTC_INV_ENTRY * k);
+}
+
+/* the block is whole here: it leaves the mempool, and goes to every
+ * ready peer not known to have it (pushed whole to the peers that asked
+ * for high-bandwidth relay, by header to the rest) */
+static int btc_accept_block(BtcNode *n, int64_t now) {
+  n->have_block = 1;
+  n->block_t = now;
+  n->mempool &= ~n->block_mask;
+  n->known |= n->block_mask;
+  for (int i = 0; i < n->npeers; i++) {
+    BtcPeer *p = n->peers[i];
+    if (!p->ready || p->has_block) continue;
+    p->has_block = 1;
+    p->n_blk++;
+    int r;
+    if (p->hb_to) {
+      n->core->n_cmpct_msgs++;
+      r = btc_queue(n, p, BM_CMPCTBLOCK, 0, n->block_mask, BTC_CMPCT_LEN);
+    } else {
+      r = btc_queue(n, p, BM_HEADERS, 0, 0, BTC_HEADERS_LEN);
+    }
+    if (r < 0) return -1;
+  }
+  return 0;
+}
+
+static int btc_handle(BtcNode *n, BtcPeer *p, int64_t now, const BtcMsg *m) {
+  uint64_t mask = m->mask;
+  switch (m->type) {
+    case BM_VERSION:
+      if (!p->outbound) {
+        p->full = m->flag;
+        if (btc_queue(n, p, BM_VERSION, 1, 0, BTC_VERSION_LEN) < 0)
+          return -1;
+      }
+      if (btc_queue(n, p, BM_WTXIDRELAY, 0, 0, 0) < 0) return -1;
+      return btc_queue(n, p, BM_VERACK, 0, 0, 0);
+    case BM_VERACK: {
+      p->ready = 1;
+      int hb = p->outbound && n->n_hb < BTC_HB_PEERS;
+      n->n_hb += hb;
+      if (btc_queue(n, p, BM_SENDHEADERS, 0, 0, 0) < 0) return -1;
+      return btc_queue(n, p, BM_SENDCMPCT, hb, 0, BTC_SENDCMPCT_LEN);
+    }
+    case BM_SENDCMPCT:
+      if (m->flag) p->hb_to = 1;
+      return 0;
+    case BM_INV: {
+      p->known |= mask;
+      uint64_t fresh = mask & ~n->known & ~n->requested;
+      if (!fresh) return 0;
+      n->requested |= fresh;
+      int64_t k = __builtin_popcountll(fresh);
+      return btc_queue(n, p, BM_GETDATA, 0, fresh,
+                       btc_varint(k) + BTC_INV_ENTRY * k);
+    }
+    case BM_GETDATA:
+      if (m->flag) { /* getdata(CMPCT_BLOCK) */
+        p->has_block = 1;
+        if (!n->have_block) return 0;
+        p->n_blk++;
+        n->core->n_cmpct_msgs++;
+        return btc_queue(n, p, BM_CMPCTBLOCK, 0, n->block_mask,
+                         BTC_CMPCT_LEN);
+      }
+      p->known |= mask;
+      while (mask) {
+        uint64_t bit = mask & (~mask + 1);
+        mask &= mask - 1;
+        p->n_tx++;
+        n->core->n_tx_msgs++;
+        if (btc_queue(n, p, BM_TX, 0, bit, BTC_TX_LEN) < 0) return -1;
+      }
+      return 0;
+    case BM_TX:
+      p->known |= mask;
+      n->requested &= ~mask;
+      if (n->known & mask) return 0;
+      n->known |= mask;
+      n->mempool |= mask;
+      return btc_relay_tx(n, now, mask);
+    case BM_HEADERS:
+      p->has_block = 1;
+      if (n->have_block || n->block_state) return 0;
+      n->block_state = 1;
+      return btc_queue(n, p, BM_GETDATA, 1, 0, BTC_GETDATA_BLOCK_LEN);
+    case BM_CMPCTBLOCK: {
+      p->has_block = 1;
+      if (n->have_block || n->block_state == 2) return 0;
+      n->block_mask = mask;
+      uint64_t missing = mask & ~n->known;
+      if (!missing) return btc_accept_block(n, now);
+      n->block_state = 2;
+      n->gbt_sent++;
+      n->gbt_mask = missing;
+      n->core->n_gbt++;
+      int64_t k = __builtin_popcountll(missing);
+      return btc_queue(n, p, BM_GETBLOCKTXN, 0, missing,
+                       32 + btc_varint(k) + BTC_GBT_INDEX * k);
+    }
+    case BM_GETBLOCKTXN: {
+      int64_t k = __builtin_popcountll(mask);
+      return btc_queue(n, p, BM_BLOCKTXN, 0, mask,
+                       32 + btc_varint(k) + BTC_TX_LEN * k);
+    }
+    case BM_BLOCKTXN:
+      p->has_block = 1;
+      if (n->have_block) return 0;
+      n->known |= mask;
+      return btc_accept_block(n, now);
+    default: /* wtxidrelay, sendheaders: every peer sends them */
+      return 0;
+  }
+}
+
+/* bytes delivered in order on `p`'s endpoint (cr_deliver) */
+static int btc_feed(BtcPeer *p, int64_t now, int64_t nbytes) {
+  BtcNode *n = p->node;
+  BtcPeer *r = p->remote;
+  while (nbytes > 0) {
+    if (!r || r->mq_n == 0) {
+      PyErr_SetString(PyExc_RuntimeError,
+                      "bitcoin: stream bytes with no message");
+      return -1;
+    }
+    BtcMsg *m = &r->mq[r->mq_head];
+    int64_t need = m->size - p->rx_got;
+    if (nbytes < need) {
+      p->rx_got += nbytes;
+      break;
+    }
+    nbytes -= need;
+    p->rx_got = 0;
+    BtcMsg msg = *m;
+    r->mq_head = (r->mq_head + 1) % r->mq_cap;
+    r->mq_n--;
+    if (btc_handle(n, p, now, &msg) < 0) return -1;
+  }
+  return btc_flush(n, now);
+}
+
+/* an outbound connection is up: VERSION (relay flag off on a
+ * block-relay-only link) */
+static int btc_connected(BtcPeer *p, int64_t now) {
+  BtcNode *n = p->node;
+  CoreObject *c = n->core;
+  int prev = sect_enter(c, SECT_BTC);
+  int r = btc_queue(n, p, BM_VERSION, p->full, 0, BTC_VERSION_LEN);
+  if (r == 0) r = btc_flush(n, now);
+  sect_leave(c, prev);
+  return r;
+}
+
+static int btc_timer_fire(BtcTimer *t, int64_t now) {
+  BtcNode *n = t->node;
+  if (!n || !n->core) return 0;
+  CoreObject *c = n->core;
+  int prev = sect_enter(c, SECT_BTC);
+  c->n_trickles++;
+  int r = 0;
+  if (t->idx >= 0) {
+    BtcPeer *p = n->peers[t->idx];
+    p->fires++;
+    r = btc_send_inv(n, p);
+  } else {
+    n->inb_armed = 0;
+    n->inb_fires++;
+    for (int i = 0; i < n->npeers && r == 0; i++) {
+      BtcPeer *p = n->peers[i];
+      if (!p->outbound && p->q) r = btc_send_inv(n, p);
+    }
+  }
+  if (r == 0) r = btc_flush(n, now);
+  sect_leave(c, prev);
+  return r;
+}
+
+static BtcPeer *btc_add_peer(BtcNode *n, CEp *e, int outbound, int full) {
+  if (n->npeers == n->peers_cap) {
+    int ncap = n->peers_cap ? n->peers_cap * 2 : 16;
+    BtcPeer **np = realloc(n->peers, (size_t)ncap * sizeof(BtcPeer *));
+    if (!np) { PyErr_NoMemory(); return NULL; }
+    n->peers = np;
+    int *nd = realloc(n->dirty, (size_t)ncap * sizeof(int));
+    if (!nd) { PyErr_NoMemory(); return NULL; }
+    n->dirty = nd;
+    n->peers_cap = ncap;
+  }
+  BtcPeer *p = calloc(1, sizeof(BtcPeer));
+  if (!p) { PyErr_NoMemory(); return NULL; }
+  Py_INCREF(e);
+  p->ep = e;
+  p->node = n;
+  p->idx = n->npeers;
+  p->outbound = outbound;
+  p->full = full;
+  e->btc = p;
+  n->peers[n->npeers++] = p;
+  return p;
+}
+
+static void btc_release(BtcNode *n) {
+  CoreObject *c = n->core;
+  if (c && c->btc && c->btc[n->hid] == n) c->btc[n->hid] = NULL;
+  for (int i = 0; i < n->npeers; i++) {
+    BtcPeer *p = n->peers[i];
+    if (p->remote) p->remote->remote = NULL;
+    if (p->ep) {
+      if (p->ep->btc == p) p->ep->btc = NULL;
+      Py_CLEAR(p->ep);
+    }
+    free(p->mq);
+    free(p);
+  }
+  n->npeers = 0;
+}
+
+static int BtcNode_traverse(BtcNode *n, visitproc visit, void *arg) {
+  Py_VISIT(n->core);
+  for (int i = 0; i < n->npeers; i++) Py_VISIT(n->peers[i]->ep);
+  return 0;
+}
+
+static int BtcNode_clear_gc(BtcNode *n) {
+  btc_release(n);
+  Py_CLEAR(n->core);
+  return 0;
+}
+
+static void BtcNode_dealloc(BtcNode *n) {
+  PyObject_GC_UnTrack(n);
+  btc_release(n);
+  free(n->peers);
+  free(n->dirty);
+  free(n->outs);
+  Py_XDECREF(n->core);
+  Py_TYPE(n)->tp_free((PyObject *)n);
+}
+
+/* the listener: an inbound connection becomes a peer, up to the cap */
+static PyObject *BtcNode_accept(BtcNode *n, PyObject *const *args,
+                                Py_ssize_t nargs) {
+  if (nargs < 1 || Py_TYPE(args[0]) != &CEp_Type) {
+    PyErr_SetString(PyExc_TypeError, "accept expects a C endpoint");
+    return NULL;
+  }
+  CEp *e = (CEp *)args[0];
+  CoreObject *c = n->core;
+  if (n->ninbound >= n->max_inbound) {
+    PyObject *r = CEp_close(e, NULL);
+    return r;
+  }
+  int prev = sect_enter(c, SECT_BTC);
+  BtcPeer *p = btc_add_peer(n, e, 0, 0);
+  if (p) {
+    n->ninbound++;
+    BtcNode *a = c->btc ? c->btc[e->remote_host] : NULL;
+    for (int i = 0; a && i < a->npeers; i++) {
+      BtcPeer *q = a->peers[i];
+      if (q->outbound && q->ep && q->ep->local_port == e->remote_port &&
+          q->ep->remote_host == n->hid && !q->remote) {
+        q->remote = p;
+        p->remote = q;
+        break;
+      }
+    }
+  }
+  sect_leave(c, prev);
+  if (!p) return NULL;
+  Py_RETURN_NONE;
+}
+
+/* the node comes up: a connection to each outbound peer, in draw order
+ * (Host.connect + connect() for each) */
+static PyObject *BtcNode_boot(BtcNode *n, PyObject *noarg) {
+  (void)noarg;
+  CoreObject *c = n->core;
+  CHost *h = &c->hs[n->hid];
+  int64_t now, eph;
+  if (attr_i64(h->host, S_now, &now) < 0 ||
+      attr_i64(h->host, S_next_ephemeral, &eph) < 0)
+    return NULL;
+  for (int i = 0; i < n->nout; i++) {
+    int lport = (int)eph++;
+    if (attr_set_i64(h->host, S_next_ephemeral, eph) < 0) return NULL;
+    CEp *e = cep_new(c, n->hid, lport, n->outs[i], n->port, 1, c->sock_sbuf,
+                     c->sock_rbuf, h->cc_kind);
+    if (!e) return NULL;
+    PyObject *key = Py_BuildValue("(iii)", lport, n->outs[i], n->port);
+    int rs = key ? PyDict_SetItem(h->conns, key, (PyObject *)e) : -1;
+    Py_XDECREF(key);
+    BtcPeer *p = rs < 0 ? NULL : btc_add_peer(n, e, 1, i < n->nfull);
+    Py_DECREF(e);
+    if (!p) return NULL;
+    e->state = ST_SYN_SENT;
+    if (ce_send_syn(e, now) < 0) return NULL;
+  }
+  Py_RETURN_NONE;
+}
+
+static PyObject *BtcNode_originate(BtcNode *n, PyObject *arg) {
+  long idx = PyLong_AsLong(arg);
+  if (idx == -1 && PyErr_Occurred()) return NULL;
+  if (idx < 0 || idx > 63) {
+    PyErr_SetString(PyExc_ValueError, "txid out of range (0..63)");
+    return NULL;
+  }
+  int64_t now;
+  if (attr_i64(n->core->hs[n->hid].host, S_now, &now) < 0) return NULL;
+  uint64_t bit = 1ULL << idx;
+  if (!(n->known & bit)) {
+    n->known |= bit;
+    n->mempool |= bit;
+    if (btc_relay_tx(n, now, bit) < 0 || btc_flush(n, now) < 0) return NULL;
+  }
+  Py_RETURN_NONE;
+}
+
+/* this node finds the block, holding the transactions `mask` */
+static PyObject *BtcNode_mine(BtcNode *n, PyObject *arg) {
+  uint64_t mask = PyLong_AsUnsignedLongLong(arg);
+  if (mask == (uint64_t)-1 && PyErr_Occurred()) return NULL;
+  int64_t now;
+  if (attr_i64(n->core->hs[n->hid].host, S_now, &now) < 0) return NULL;
+  if (!n->have_block) {
+    n->block_mask = mask;
+    if (btc_accept_block(n, now) < 0 || btc_flush(n, now) < 0) return NULL;
+  }
+  Py_RETURN_NONE;
+}
+
+static PyObject *BtcNode_stats(BtcNode *n, PyObject *noarg) {
+  (void)noarg;
+  PyObject *sent = PyTuple_New(BM_N);
+  if (!sent) return NULL;
+  for (int i = 0; i < BM_N; i++) {
+    PyObject *v = PyLong_FromLongLong(n->sent[i]);
+    if (!v) { Py_DECREF(sent); return NULL; }
+    PyTuple_SET_ITEM(sent, i, v);
+  }
+  return Py_BuildValue("(KLLKLN)", (unsigned long long)n->known,
+                       (long long)(n->have_block ? n->block_t : -1),
+                       (long long)n->gbt_sent,
+                       (unsigned long long)n->gbt_mask,
+                       (long long)n->inb_fires, sent);
+}
+
+static PyObject *BtcNode_peer_info(BtcNode *n, PyObject *noarg) {
+  (void)noarg;
+  PyObject *out = PyList_New(n->npeers);
+  if (!out) return NULL;
+  for (int i = 0; i < n->npeers; i++) {
+    BtcPeer *p = n->peers[i];
+    PyObject *t = Py_BuildValue(
+        "(iiiiLLLL)", p->ep ? p->ep->remote_host : -1, p->outbound, p->full,
+        p->hb_to, (long long)p->n_inv, (long long)p->n_tx,
+        (long long)p->n_blk, (long long)p->fires);
+    if (!t) { Py_DECREF(out); return NULL; }
+    PyList_SET_ITEM(out, i, t);
+  }
+  return out;
+}
+
+static PyMethodDef BtcNode_methods[] = {
+    {"accept", (PyCFunction)(void (*)(void))BtcNode_accept, METH_FASTCALL,
+     "(endpoint, now): the listener; an inbound connection becomes a peer"},
+    {"boot", (PyCFunction)BtcNode_boot, METH_NOARGS,
+     "connect to every outbound peer, in draw order"},
+    {"originate", (PyCFunction)BtcNode_originate, METH_O,
+     "(txid) -> a transaction made here enters the mempool and is queued"},
+    {"mine", (PyCFunction)BtcNode_mine, METH_O,
+     "(txs mask) -> this node finds the block, holding those txs"},
+    {"stats", (PyCFunction)BtcNode_stats, METH_NOARGS,
+     "-> (known, block_ns or -1, getblocktxn sent, its txs, inbound timer "
+     "firings, sent count per message type)"},
+    {"peer_info", (PyCFunction)BtcNode_peer_info, METH_NOARGS,
+     "-> [(remote host, outbound, full relay, high-bandwidth to, INVs, "
+     "TXs, block messages, own timer firings)] per peer"},
+    {NULL, NULL, 0, NULL}};
+
+static PyTypeObject BtcNode_Type = {
+    PyVarObject_HEAD_INIT(NULL, 0).tp_name = "_colcore.BtcNode",
+    .tp_basicsize = sizeof(BtcNode),
+    .tp_dealloc = (destructor)BtcNode_dealloc,
+    .tp_flags = Py_TPFLAGS_DEFAULT | Py_TPFLAGS_HAVE_GC,
+    .tp_traverse = (traverseproc)BtcNode_traverse,
+    .tp_clear = (inquiry)BtcNode_clear_gc,
+    .tp_methods = BtcNode_methods,
+    .tp_free = PyObject_GC_Del,
+    .tp_doc = "a Bitcoin node's relay (models/bitcoin.py delegates)",
+};
+
+static int BtcTimer_traverse(BtcTimer *t, visitproc visit, void *arg) {
+  Py_VISIT(t->node);
+  return 0;
+}
+
+static int BtcTimer_clear_gc(BtcTimer *t) {
+  Py_CLEAR(t->node);
+  return 0;
+}
+
+static void BtcTimer_dealloc(BtcTimer *t) {
+  PyObject_GC_UnTrack(t);
+  Py_XDECREF(t->node);
+  Py_TYPE(t)->tp_free((PyObject *)t);
+}
+
+/* a call from Python (a host not run by Core_run_round) fires it too */
+static PyObject *BtcTimer_call(BtcTimer *t, PyObject *args, PyObject *kw) {
+  (void)args;
+  (void)kw;
+  if (!t->node || !t->node->core) Py_RETURN_NONE;
+  int64_t now;
+  if (attr_i64(t->node->core->hs[t->node->hid].host, S_now, &now) < 0)
+    return NULL;
+  if (btc_timer_fire(t, now) < 0) return NULL;
+  Py_RETURN_NONE;
+}
+
+static PyTypeObject BtcTimer_Type = {
+    PyVarObject_HEAD_INIT(NULL, 0).tp_name = "_colcore.BtcTimer",
+    .tp_basicsize = sizeof(BtcTimer),
+    .tp_dealloc = (destructor)BtcTimer_dealloc,
+    .tp_flags = Py_TPFLAGS_DEFAULT | Py_TPFLAGS_HAVE_GC,
+    .tp_traverse = (traverseproc)BtcTimer_traverse,
+    .tp_clear = (inquiry)BtcTimer_clear_gc,
+    .tp_call = (ternaryfunc)BtcTimer_call,
+    .tp_free = PyObject_GC_Del,
+    .tp_doc = "a trickle timer of a BtcNode (an event-heap task)",
+};
+
+static PyObject *Core_btc_node(CoreObject *c, PyObject *args) {
+  long long hid, seed_ll, tout, tin;
+  int port, nfull, max_inbound;
+  PyObject *peers;
+  if (!PyArg_ParseTuple(args, "LiOiKiLL", &hid, &port, &peers, &nfull,
+                        &seed_ll, &max_inbound, &tout, &tin))
+    return NULL;
+  if (hid < 0 || hid >= c->H) {
+    PyErr_SetString(PyExc_ValueError, "host id out of range");
+    return NULL;
+  }
+  PyObject *seq = PySequence_Fast(peers, "peers must be a sequence");
+  if (!seq) return NULL;
+  Py_ssize_t np = PySequence_Fast_GET_SIZE(seq);
+  if (!c->btc) {
+    c->btc = calloc((size_t)c->H, sizeof(BtcNode *));
+    if (!c->btc) { Py_DECREF(seq); return PyErr_NoMemory(); }
+  }
+  BtcNode *n = PyObject_GC_New(BtcNode, &BtcNode_Type);
+  if (!n) { Py_DECREF(seq); return NULL; }
+  memset(((char *)n) + sizeof(PyObject), 0,
+         sizeof(BtcNode) - sizeof(PyObject));
+  Py_INCREF(c);
+  n->core = c;
+  n->hid = (int)hid;
+  n->port = port;
+  n->nfull = nfull;
+  n->max_inbound = max_inbound;
+  n->rng = (uint64_t)seed_ll;
+  n->trickle_out = tout;
+  n->trickle_in = tin;
+  n->block_t = -1;
+  n->outs = malloc(sizeof(int32_t) * (size_t)(np ? np : 1));
+  if (!n->outs) { Py_DECREF(seq); Py_DECREF(n); return PyErr_NoMemory(); }
+  for (Py_ssize_t i = 0; i < np; i++) {
+    long v = PyLong_AsLong(PySequence_Fast_GET_ITEM(seq, i));
+    if ((v == -1 && PyErr_Occurred()) || v < 0 || v >= c->H) {
+      if (!PyErr_Occurred())
+        PyErr_SetString(PyExc_ValueError, "peer host id out of range");
+      Py_DECREF(seq);
+      Py_DECREF(n);
+      return NULL;
+    }
+    n->outs[i] = (int32_t)v;
+  }
+  n->nout = (int)np;
+  Py_DECREF(seq);
+  c->btc[hid] = n;
+  PyObject_GC_Track((PyObject *)n);
+  return (PyObject *)n;
+}
+
+/* ======================================================================
  * Checkpoint export/restore (shadow_tpu/checkpoint.py).
  *
  * Every C object that can be live at a round boundary — stream
@@ -6869,6 +7647,7 @@ PyMODINIT_FUNC PyInit__colcore(void) {
   INTERN(S_drop_fire, "_drop_fire");
   INTERN(S_idle_fire, "_idle_fire");
   INTERN(S_seq_ctr, "_seq");
+  INTERN(S_next_ephemeral, "_next_ephemeral");
 #undef INTERN
   O_zero = PyLong_FromLong(0);
   O_one = PyLong_FromLong(1);
@@ -6880,7 +7659,8 @@ PyMODINIT_FUNC PyInit__colcore(void) {
       || PyType_Ready(&CEp_Type) < 0 || PyType_Ready(&CRelay_Type) < 0
       || PyType_Ready(&CBatch_Type) < 0
       || PyType_Ready(&CTorSink_Type) < 0
-      || PyType_Ready(&CExitStream_Type) < 0)
+      || PyType_Ready(&CExitStream_Type) < 0
+      || PyType_Ready(&BtcNode_Type) < 0 || PyType_Ready(&BtcTimer_Type) < 0)
     return NULL;
   PyObject *m = PyModule_Create(&colcore_module);
   if (!m) return NULL;

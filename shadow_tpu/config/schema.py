@@ -42,7 +42,7 @@ CONGESTION_CONTROL_NAMES = ("newreno", "cubic")
 #: not at process spawn time mid-build); pyapp paths OUTSIDE the
 #: namespace still load dynamically (user workloads stay free).
 MODEL_REGISTRY = ("tgen", "gossip", "tor", "echo", "httpd", "web",
-                  "dns", "abr")
+                  "dns", "abr", "bitcoin")
 
 
 @dataclass

@@ -136,7 +136,10 @@ SHIM_RING_ATTR_PAIRS = [
 #:   core   Core.adopt() sets it when the endpoint joins a core
 #:   sink   the owning CRelay's _restore_state re-links its conns
 #:   tsink  the owning CTorSink's _restore_state re-links its client ep
-STRUCT_EXPORT_EXEMPT = {"core", "sink", "tsink"}
+#:   btc    a BtcNode's peer (models/bitcoin.py); Bitcoin nodes do not
+#:          checkpoint: a BtcNode cannot be pickled, so a checkpoint of
+#:          one refuses by its type's name
+STRUCT_EXPORT_EXEMPT = {"core", "sink", "tsink", "btc"}
 
 def _codes_align(export, restore) -> bool:
     """Positional compatibility of an export Py_BuildValue format with
