@@ -4,13 +4,17 @@
  * floor).  Design rule: this module accelerates FUNCTIONS, never forks
  * data STRUCTURES.  Egress rows stay the same 12-field Python tuples
  * Host.emit_msg appends; store rows stay the same 13-field tuples in the
- * same StoreBatch/pending deque; the event heap stays EventQueue._heap.
- * Every Python path (mesh plane, fault filters, pcap hosts, managed
- * bridges, round_robin qdisc) therefore interoperates with the C path
- * per-phase with no conversion layer, and the bit-identity obligations
- * (tests/test_colplane.py, test_colcore.py) reduce to "same arithmetic,
- * same order" — which this file replicates operation-for-operation from
- * network/colplane.py, network/fluid.py and host/host.py.
+ * same StoreBatch/pending deque; Python pushes stay in EventQueue._heap.
+ * The one exception is the timers C code arms (see "C timers"): they live
+ * in a per-host C heap whose entries are the ones a push would have made,
+ * merged with EventQueue._heap in the same (time, band, key, seq) order
+ * and folded into every Python view of the queue. Every Python path (mesh
+ * plane, fault filters, pcap hosts, managed bridges, round_robin qdisc)
+ * therefore interoperates with the C path per-phase with no conversion
+ * layer, and the bit-identity obligations (tests/test_colplane.py,
+ * test_colcore.py) reduce to "same arithmetic, same order" — which this
+ * file replicates operation-for-operation from network/colplane.py,
+ * network/fluid.py and host/host.py.
  *
  * What runs in C:
  *   - Core.barrier():   egress collection, uid minting, blackhole filter,
@@ -22,20 +26,22 @@
  *                       back to Python (the existing dispatch machinery).
  *   - Core.extract():   due-prefix extraction from the pending store into
  *                       per-host C inboxes (per-host (t,key) order).
- *   - Core.run_round(): the per-round host loop: inbox/heap merge, C heap
- *                       pops, ingress-bucket charging, datagram dispatch,
- *                       and the C gossip app; Python callables (timers,
+ *   - Core.run_round(): the per-round host loop: the merge of the inbox
+ *                       with the two heaps, heap pops, C timer fires,
+ *                       ingress-bucket charging, datagram dispatch, and
+ *                       the C gossip app; Python callables (timers,
  *                       stream endpoints, plugin callbacks) are invoked
  *                       through the normal C API when a row or event
  *                       isn't C-handled. The round's hosts come from a
  *                       due-time index, a min-heap of (lower bound on
- *                       the host's heap root, host id), plus the hosts
- *                       extract gave inbox rows: the same hosts, in the
- *                       same id order, as a walk of every host with a
- *                       non-empty heap, without the walk. A push that
- *                       makes a new heap root lowers the host's key:
- *                       EventQueue.on_first(time) -> Core.activate(hid,
- *                       time) for Python pushes, cep_schedule for C ones.
+ *                       the host's first entry over both heaps, host id),
+ *                       plus the hosts extract gave inbox rows: the same
+ *                       hosts, in the same id order, as a walk of every
+ *                       host with a non-empty queue, without the walk. A
+ *                       push that makes a new root of either heap lowers
+ *                       the host's key: EventQueue.on_first(time) ->
+ *                       Core.activate(hid, time) for Python pushes,
+ *                       ct_arm for C timers.
  *                       The Python planes keep the controller's active
  *                       set instead; with this core attached nothing
  *                       maintains it.
@@ -61,6 +67,7 @@
 #ifndef Py_T_INT
 #include <structmember.h>
 #define Py_T_INT T_INT
+#define Py_T_OBJECT_EX T_OBJECT_EX
 #define Py_READONLY READONLY
 #endif
 
@@ -301,13 +308,43 @@ typedef struct {
   int32_t kind, dst, sport, dport, frag, nfrags;
 } ERow;
 
+/* one entry of a host's C timer heap (see "C timers" below): the order
+ * key (time, seq) and the record that holds the rest */
+typedef struct {
+  int64_t t, seq;
+  int32_t rec;
+} CTEnt;
+
+/* a C timer's record: the kind says which handler fires the target */
+typedef struct {
+  int64_t seq;       /* the timer's seq; -1 while the record is free */
+  PyObject *target;  /* owned: the endpoint or the Bitcoin node */
+  int32_t kind, arg; /* arg: the Bitcoin outbound peer, or -1 */
+  int32_t dead;      /* cancelled, still in the heap */
+  int32_t next;      /* the free list's next record */
+} CTRec;
+
+/* what an endpoint keeps of a timer it may cancel (on = held) */
+typedef struct {
+  int64_t seq;
+  int32_t rec, on;
+} CTHandle;
+
 typedef struct {
   PyObject *host;      /* borrowed: Core->hosts list holds the ref */
   PyObject *id_obj;    /* owned: the host's stable `id` int object */
-  PyObject *equeue;    /* owned: host.equeue (C timer push/cancel) */
+  PyObject *equeue;    /* owned: host.equeue */
   PyObject *heap;      /* owned: equeue._heap list */
   PyObject *live;      /* owned: equeue._live set */
   PyObject *cancelled; /* owned: equeue._cancelled set */
+  /* the slot of equeue._seq (EventQueue has __slots__): the one seq
+   * counter that Python pushes and C arms draw from */
+  PyObject **seqp;
+  /* the C timer heap: ct_n entries, ct_dead of them cancelled; records
+   * in ctr, the free ones chained from ctr_free (-1: none) */
+  CTEnt *ct;
+  CTRec *ctr;
+  int ct_n, ct_cap, ct_dead, ctr_cap, ctr_free;
   /* the host's key in the due-time index (Core_run_round): the time of
    * its one valid index entry, DUE_NONE when it has none (empty heap);
    * due_sel marks a host selected for the running round, re-keyed when
@@ -464,6 +501,8 @@ typedef struct {
   /* run_round's own time outside the per-host runs (events.select) and
    * its counts: hosts the selection looked at, hosts run */
   int64_t sel_ns, sel_calls, n_examined, n_run;
+  /* C timers armed, cancelled while pending, and fired */
+  int64_t n_ct_armed, n_ct_cancelled, n_ct_fired;
 } CoreObject;
 
 /* time the Python call a pair of these brackets (the outermost one, when
@@ -739,27 +778,443 @@ static int heap_push(PyObject *heap, PyObject *entry) {
   return 0;
 }
 
-/* EventQueue._drop_cancelled_head twin. Returns borrowed head or NULL
- * (empty); -1 via *err on failure. */
-static PyObject *heap_head(CHost *h, int *err) {
-  *err = 0;
-  while (PyList_GET_SIZE(h->heap)) {
-    PyObject *head = PyList_GET_ITEM(h->heap, 0);
-    PyObject *seq = PyTuple_GET_ITEM(head, 3);
-    int c = PySet_Contains(h->cancelled, seq);
-    if (c < 0) { *err = 1; return NULL; }
-    if (!c) return head;
-    PyObject *popped = heap_pop(h->heap);
-    if (!popped) { *err = 1; return NULL; }
-    seq = PyTuple_GET_ITEM(popped, 3);
-    if (PySet_Discard(h->cancelled, seq) < 0 ||
-        PySet_Discard(h->live, seq) < 0) {
-      Py_DECREF(popped); *err = 1; return NULL;
-    }
-    Py_DECREF(popped);
-  }
-  return NULL;
+/* ---- C timers ------------------------------------------------------------
+ * A timer that C code arms (a stream endpoint's RTO, SYN, FIN, idle or
+ * drop timer, a Bitcoin node's trickle timer) lives in its host's C timer
+ * heap, not in EventQueue._heap: a plain (time, seq, record) entry, with
+ * its kind and target in a record that the endpoint's handle names, so
+ * arming builds no Python object and a cancel is O(1) and hashes nothing.
+ * Python pushes stay in EventQueue._heap.
+ *
+ * One order over both heaps. Every C timer is (time, BAND_APP, key=seq,
+ * seq), its seq drawn from the host's one counter (equeue._seq): the
+ * entry EventQueue.push would have made. So the union of the two heaps
+ * pops in the same (time, band, key, seq) order as one Python heap would,
+ * and union_head is the one place that picks between them. A cancel marks
+ * the record dead and leaves the entry in place, as EventQueue.cancel
+ * leaves its tuple: a host's first entry (a dead one included), which the
+ * due-time index and run_round's selection read, is the same entry either
+ * way, and union_head drops dead entries only at the head of the union,
+ * as EventQueue._drop_cancelled_head did.
+ *
+ * The Python views (EventQueue's len, next_time, head, pop_until,
+ * live_times, live_count, clear_band) fold the C timers in through the
+ * host's HostTimers view; a checkpoint folds them into the Python heap as
+ * the (time, band, key, seq, task) tuples a push would have made, so the
+ * on-disk format is the Python heap's, and index_heaps adopts such tuples
+ * back (fold_timers / adopt_timers). The views that build a task (head,
+ * pop_until, the fold) refuse a Bitcoin trickle timer, which has none. */
+enum { CT_RTO, CT_SYN, CT_FIN, CT_IDLE, CT_DROP, CT_BTC_OUT, CT_BTC_IN };
+
+static int due_lower(CoreObject *c, int64_t hid, int64_t t);
+struct CEp;
+struct BtcNode;
+static int cep_timer_fire(struct CEp *e, int kind, int64_t now);
+static int btc_fire(struct BtcNode *n, int idx, int64_t now);
+static PyObject *ct_task(const CTRec *r);
+static int ct_adopt_all(CoreObject *c);
+static PyObject *S_rto_fire, *S_syn_fire, *S_fin_fire, *S_drop_fire,
+    *S_idle_fire, *S_seq_ctr;
+
+static inline int ct_lt(const CTEnt *a, const CTEnt *b) {
+  return a->t < b->t || (a->t == b->t && a->seq < b->seq);
 }
+
+/* C entry `e` comes before Python heap tuple `p` (seqs never tie: both
+ * heaps draw from one counter) */
+static inline int ct_before(const CTEnt *e, PyObject *p) {
+  int64_t t = tup_i64(p, 0);
+  if (e->t != t) return e->t < t;
+  int64_t band = tup_i64(p, 1);
+  if (band != 1) return band > 1;
+  int64_t key = tup_i64(p, 2);
+  if (e->seq != key) return e->seq < key;
+  return e->seq < tup_i64(p, 3);
+}
+
+/* the time of the host's first entry over both heaps, a dead one
+ * included (DUE_NONE-like INT64_MAX when both are empty) */
+static inline int64_t root_t(CHost *h) {
+  int64_t t = PyList_GET_SIZE(h->heap)
+      ? tup_i64(PyList_GET_ITEM(h->heap, 0), 0) : INT64_MAX;
+  return h->ct_n && h->ct[0].t < t ? h->ct[0].t : t;
+}
+
+/* the slot of equeue._seq, found through EventQueue's member
+ * descriptor */
+static PyObject **eq_seq_slot(PyObject *eq) {
+  PyObject *d = PyObject_GetAttr((PyObject *)Py_TYPE(eq), S_seq_ctr);
+  if (!d) return NULL;
+  PyObject **p = NULL;
+  if (Py_IS_TYPE(d, &PyMemberDescr_Type)) {
+    PyMemberDef *m = ((PyMemberDescrObject *)d)->d_member;
+    if (m->type == Py_T_OBJECT_EX) p = (PyObject **)((char *)eq + m->offset);
+  }
+  Py_DECREF(d);
+  if (!p) PyErr_SetString(PyExc_TypeError, "equeue._seq is not a slot");
+  return p;
+}
+
+/* take the next seq from equeue._seq, as EventQueue.push does */
+static int eq_take_seq(CHost *h, int64_t *seq) {
+  PyObject *v = *h->seqp;
+  int64_t s = v ? PyLong_AsLongLong(v) : -1;
+  if (s == -1 && (!v || PyErr_Occurred())) {
+    if (!PyErr_Occurred())
+      PyErr_SetString(PyExc_RuntimeError, "equeue._seq is unset");
+    return -1;
+  }
+  PyObject *nv = PyLong_FromLongLong(s + 1);
+  if (!nv) return -1;
+  *h->seqp = nv;
+  Py_DECREF(v);
+  *seq = s;
+  return 0;
+}
+
+static int ct_rec_new(CHost *h) {
+  if (h->ctr_free < 0) {
+    int ncap = h->ctr_cap ? h->ctr_cap * 2 : 8;
+    CTRec *nr = realloc(h->ctr, sizeof(CTRec) * (size_t)ncap);
+    if (!nr) { PyErr_NoMemory(); return -1; }
+    for (int i = h->ctr_cap; i < ncap; i++) {
+      nr[i].seq = -1;
+      nr[i].target = NULL;
+      nr[i].next = i + 1 < ncap ? i + 1 : -1;
+    }
+    h->ctr_free = h->ctr_cap;
+    h->ctr = nr;
+    h->ctr_cap = ncap;
+  }
+  int r = h->ctr_free;
+  h->ctr_free = h->ctr[r].next;
+  return r;
+}
+
+/* free a record; its target reference passes to the caller */
+static inline void ct_rec_free(CHost *h, int r) {
+  h->ctr[r].seq = -1;
+  h->ctr[r].target = NULL;
+  h->ctr[r].next = h->ctr_free;
+  h->ctr_free = r;
+}
+
+/* push a timer (an owned reference to `target` is taken); returns its
+ * position in the heap (0: the new C root), -1 on error */
+static int ct_push(CHost *h, int64_t t, int64_t seq, int kind,
+                   PyObject *target, int arg, int dead, CTHandle *hd) {
+  if (h->ct_n == h->ct_cap) {
+    int ncap = h->ct_cap ? h->ct_cap * 2 : 8;
+    CTEnt *ne = realloc(h->ct, sizeof(CTEnt) * (size_t)ncap);
+    if (!ne) { PyErr_NoMemory(); return -1; }
+    h->ct = ne;
+    h->ct_cap = ncap;
+  }
+  int r = ct_rec_new(h);
+  if (r < 0) return -1;
+  CTRec *rec = &h->ctr[r];
+  rec->seq = seq;
+  Py_INCREF(target);
+  rec->target = target;
+  rec->kind = kind;
+  rec->arg = arg;
+  rec->dead = dead;
+  if (dead) h->ct_dead++;
+  if (hd) {
+    hd->seq = seq;
+    hd->rec = r;
+    hd->on = 1;
+  }
+  CTEnt ent = {t, seq, r};
+  int pos = h->ct_n++;
+  while (pos > 0) {
+    int parent = (pos - 1) >> 1;
+    if (!ct_lt(&ent, &h->ct[parent])) break;
+    h->ct[pos] = h->ct[parent];
+    pos = parent;
+  }
+  h->ct[pos] = ent;
+  return pos;
+}
+
+/* pop the C root */
+static CTEnt ct_pop(CHost *h) {
+  CTEnt *a = h->ct;
+  CTEnt top = a[0];
+  CTEnt last = a[--h->ct_n];
+  int n = h->ct_n, pos = 0;
+  for (;;) {
+    int ch = 2 * pos + 1;
+    if (ch >= n) break;
+    if (ch + 1 < n && ct_lt(&a[ch + 1], &a[ch])) ch++;
+    if (!ct_lt(&a[ch], &last)) break;
+    a[pos] = a[ch];
+    pos = ch;
+  }
+  if (n) a[pos] = last;
+  return top;
+}
+
+/* arm a timer at `t` on host `hid`, with the next seq of the host's
+ * counter; a new first entry keys the host in the due-time index */
+static int ct_arm(CoreObject *c, int64_t hid, int64_t t, int kind,
+                  PyObject *target, int arg, CTHandle *hd) {
+  CHost *h = &c->hs[hid];
+  int64_t seq;
+  if (eq_take_seq(h, &seq) < 0) return -1;
+  int pos = ct_push(h, t, seq, kind, target, arg, 0, hd);
+  if (pos < 0) return -1;
+  c->n_ct_armed++;
+  return pos == 0 ? due_lower(c, hid, t) : 0;
+}
+
+/* EventQueue.cancel twin: a timer still pending dies; the handle is
+ * released either way */
+static void ct_cancel(CoreObject *c, CHost *h, CTHandle *hd) {
+  if (!hd->on) return;
+  hd->on = 0;
+  if (hd->rec < 0 || hd->rec >= h->ctr_cap) return;
+  CTRec *r = &h->ctr[hd->rec];
+  if (r->seq == hd->seq && !r->dead) {
+    r->dead = 1;
+    h->ct_dead++;
+    c->n_ct_cancelled++;
+  }
+}
+
+/* pop the C root, a dead entry */
+static void ct_drop_head(CHost *h) {
+  CTEnt e = ct_pop(h);
+  PyObject *tg = h->ctr[e.rec].target;
+  ct_rec_free(h, e.rec);
+  h->ct_dead--;
+  Py_DECREF(tg);
+}
+
+/* the head of the union of a host's two heaps, dead entries dropped on
+ * the way: 0 when both are empty, 1 for the Python heap's root (*p0,
+ * borrowed), 2 for the C heap's root (h->ct[0]), -1 on error */
+static int union_head(CHost *h, PyObject **p0) {
+  for (;;) {
+    PyObject *p = PyList_GET_SIZE(h->heap) ? PyList_GET_ITEM(h->heap, 0)
+                                           : NULL;
+    if (h->ct_n && (!p || ct_before(&h->ct[0], p))) {
+      if (!h->ctr[h->ct[0].rec].dead) return 2;
+      ct_drop_head(h);
+      continue;
+    }
+    if (!p) return 0;
+    PyObject *seq = PyTuple_GET_ITEM(p, 3);
+    int dead = PySet_Contains(h->cancelled, seq);
+    if (dead < 0) return -1;
+    if (!dead) {
+      *p0 = p;
+      return 1;
+    }
+    PyObject *popped = heap_pop(h->heap);
+    if (!popped) return -1;
+    seq = PyTuple_GET_ITEM(popped, 3);
+    int r = PySet_Discard(h->cancelled, seq) < 0 ||
+            PySet_Discard(h->live, seq) < 0;
+    Py_DECREF(popped);
+    if (r) return -1;
+  }
+}
+
+/* fire the C root, popped by the caller as `e`, in its handler's
+ * section */
+static int ct_fire(CoreObject *c, CHost *h, CTEnt e, int64_t now) {
+  CTRec r = h->ctr[e.rec];
+  ct_rec_free(h, e.rec);
+  c->n_ct_fired++;
+  int rc;
+  if (r.kind >= CT_BTC_OUT) {
+    rc = btc_fire((struct BtcNode *)r.target, r.arg, now);
+  } else {
+    int prev = sect_enter(c, SECT_STREAM);
+    rc = cep_timer_fire((struct CEp *)r.target, r.kind, now);
+    sect_leave(c, prev);
+  }
+  Py_DECREF(r.target);
+  return rc;
+}
+
+/* ---- HostTimers: a host's event queue as EventQueue sees it with the C
+ * engine attached (EventQueue._ct) ------------------------------------- */
+typedef struct {
+  PyObject_HEAD
+  CoreObject *core; /* owned */
+  int hid;
+} HostTimers;
+
+static PyTypeObject HostTimers_Type;
+
+static CHost *ht_host(HostTimers *v) { return &v->core->hs[v->hid]; }
+
+static int HostTimers_traverse(HostTimers *v, visitproc visit, void *arg) {
+  Py_VISIT(v->core);
+  return 0;
+}
+
+static int HostTimers_clear_gc(HostTimers *v) {
+  Py_CLEAR(v->core);
+  return 0;
+}
+
+static void HostTimers_dealloc(HostTimers *v) {
+  PyObject_GC_UnTrack(v);
+  Py_XDECREF(v->core);
+  Py_TYPE(v)->tp_free((PyObject *)v);
+}
+
+static int ht_check(HostTimers *v) {
+  if (!v->core || !v->core->hs || !v->core->hs[v->hid].heap) {
+    PyErr_SetString(PyExc_RuntimeError, "HostTimers: no core");
+    return -1;
+  }
+  return 0;
+}
+
+/* the union head as EventQueue.head gives it: (time, band, key, seq,
+ * task), or None */
+static PyObject *HostTimers_head(HostTimers *v, PyObject *noarg) {
+  (void)noarg;
+  if (ht_check(v) < 0) return NULL;
+  CHost *h = ht_host(v);
+  PyObject *p0 = NULL;
+  int which = union_head(h, &p0);
+  if (which < 0) return NULL;
+  if (which == 1) return Py_NewRef(p0);
+  if (which == 0) Py_RETURN_NONE;
+  CTEnt *e = &h->ct[0];
+  PyObject *task = ct_task(&h->ctr[e->rec]);
+  if (!task) return NULL;
+  return Py_BuildValue("(LiLLN)", (long long)e->t, 1, (long long)e->seq,
+                       (long long)e->seq, task);
+}
+
+static PyObject *HostTimers_next_time(HostTimers *v, PyObject *noarg) {
+  (void)noarg;
+  if (ht_check(v) < 0) return NULL;
+  CHost *h = ht_host(v);
+  PyObject *p0 = NULL;
+  int which = union_head(h, &p0);
+  if (which < 0) return NULL;
+  return PyLong_FromLongLong(which == 1   ? tup_i64(p0, 0)
+                             : which == 2 ? h->ct[0].t
+                                          : T_NEVER_C);
+}
+
+/* EventQueue.pop_until over both heaps: (time, task) of the union head
+ * when it is before `end`, else None */
+static PyObject *HostTimers_pop_until(HostTimers *v, PyObject *arg) {
+  int64_t end = PyLong_AsLongLong(arg);
+  if (end == -1 && PyErr_Occurred()) return NULL;
+  if (ht_check(v) < 0) return NULL;
+  CHost *h = ht_host(v);
+  PyObject *p0 = NULL;
+  int which = union_head(h, &p0);
+  if (which < 0) return NULL;
+  if (which == 1 && tup_i64(p0, 0) < end) {
+    PyObject *ev = heap_pop(h->heap);
+    if (!ev) return NULL;
+    if (PySet_Discard(h->live, PyTuple_GET_ITEM(ev, 3)) < 0) {
+      Py_DECREF(ev);
+      return NULL;
+    }
+    PyObject *out = PyTuple_Pack(2, PyTuple_GET_ITEM(ev, 0),
+                                 PyTuple_GET_ITEM(ev, 4));
+    Py_DECREF(ev);
+    return out;
+  }
+  if (which == 2 && h->ct[0].t < end) {
+    PyObject *task = ct_task(&h->ctr[h->ct[0].rec]);
+    if (!task) return NULL;
+    CTEnt e = ct_pop(h);
+    PyObject *tg = h->ctr[e.rec].target;
+    ct_rec_free(h, e.rec);
+    v->core->n_ct_fired++;
+    Py_DECREF(tg);
+    return Py_BuildValue("(LN)", (long long)e.t, task);
+  }
+  Py_RETURN_NONE;
+}
+
+/* (time, BAND_APP) of every pending C timer not cancelled */
+static PyObject *HostTimers_live_times(HostTimers *v, PyObject *noarg) {
+  (void)noarg;
+  if (ht_check(v) < 0) return NULL;
+  CHost *h = ht_host(v);
+  PyObject *out = PyList_New(0);
+  if (!out) return NULL;
+  for (int i = 0; i < h->ct_n; i++) {
+    if (h->ctr[h->ct[i].rec].dead) continue;
+    PyObject *t = Py_BuildValue("(Li)", (long long)h->ct[i].t, 1);
+    if (!t || PyList_Append(out, t) < 0) {
+      Py_XDECREF(t);
+      Py_DECREF(out);
+      return NULL;
+    }
+    Py_DECREF(t);
+  }
+  return out;
+}
+
+/* EventQueue.clear_band(BAND_APP) over the C timers: cancel every
+ * pending one; returns how many */
+static PyObject *HostTimers_clear(HostTimers *v, PyObject *noarg) {
+  (void)noarg;
+  if (ht_check(v) < 0) return NULL;
+  CHost *h = ht_host(v);
+  int64_t n = 0;
+  for (int i = 0; i < h->ct_n; i++) {
+    CTRec *r = &h->ctr[h->ct[i].rec];
+    if (r->dead) continue;
+    r->dead = 1;
+    n++;
+  }
+  h->ct_dead += (int)n;
+  v->core->n_ct_cancelled += n;
+  return PyLong_FromLongLong(n);
+}
+
+/* pending C timers not cancelled */
+static Py_ssize_t HostTimers_len(HostTimers *v) {
+  if (ht_check(v) < 0) return -1;
+  CHost *h = ht_host(v);
+  return h->ct_n - h->ct_dead;
+}
+
+static PyMethodDef HostTimers_methods[] = {
+    {"head", (PyCFunction)HostTimers_head, METH_NOARGS,
+     "EventQueue.head over both heaps"},
+    {"next_time", (PyCFunction)HostTimers_next_time, METH_NOARGS,
+     "EventQueue.next_time over both heaps"},
+    {"pop_until", (PyCFunction)HostTimers_pop_until, METH_O,
+     "EventQueue.pop_until over both heaps: (end) -> (time, task) | None"},
+    {"live_times", (PyCFunction)HostTimers_live_times, METH_NOARGS,
+     "[(time, BAND_APP)] of the pending C timers not cancelled"},
+    {"clear", (PyCFunction)HostTimers_clear, METH_NOARGS,
+     "cancel every pending C timer (clear_band(BAND_APP)) -> count"},
+    {NULL, NULL, 0, NULL}};
+
+static PySequenceMethods HostTimers_as_seq = {
+    .sq_length = (lenfunc)HostTimers_len,
+};
+
+static PyTypeObject HostTimers_Type = {
+    PyVarObject_HEAD_INIT(NULL, 0).tp_name = "_colcore.HostTimers",
+    .tp_basicsize = sizeof(HostTimers),
+    .tp_dealloc = (destructor)HostTimers_dealloc,
+    .tp_flags = Py_TPFLAGS_DEFAULT | Py_TPFLAGS_HAVE_GC,
+    .tp_traverse = (traverseproc)HostTimers_traverse,
+    .tp_clear = (inquiry)HostTimers_clear_gc,
+    .tp_as_sequence = &HostTimers_as_seq,
+    .tp_methods = HostTimers_methods,
+    .tp_free = PyObject_GC_Del,
+    .tp_doc = "a host's C timers as its EventQueue sees them (len: the "
+              "pending ones not cancelled)",
+};
 
 /* ---- emission (C gossip sendto -> egress row tuple) ------------------- */
 /* packed emission core: append one ERow to the host's C egress buffer.
@@ -1005,12 +1460,6 @@ static int dispatch_c(CoreObject *c, CHost *h, int hid, IRow *ir,
                          ir->payload ? ir->payload : Py_None, ir->peer);
 }
 
-/* the Bitcoin nodes' trickle timers (defined with the nodes below): the
- * run loop fires them itself, as C */
-struct BtcTimer;
-static PyTypeObject BtcTimer_Type;
-static int btc_timer_fire(struct BtcTimer *t, int64_t now);
-
 /* ---- Host.run_events twin over the C inbox ---------------------------- */
 static int64_t run_host_c(CoreObject *c, CHost *h, int hid, int64_t end) {
   /* no entry clock read: inbox rows satisfy t >= host._now (rows are
@@ -1025,26 +1474,31 @@ static int64_t run_host_c(CoreObject *c, CHost *h, int hid, int64_t end) {
   int pos = 0, ln = h->inbox_n;
   int err = -1;
   /* fast path: no heap events at all */
-  while (pos < ln && PyList_GET_SIZE(h->heap) == 0) {
+  while (pos < ln && PyList_GET_SIZE(h->heap) == 0 && h->ct_n == 0) {
     if (dispatch_c(c, h, hid, &rows[pos], &now, &now_dirty) < 0)
       goto done;
     pos++; n++;
   }
-  if (PyList_GET_SIZE(h->heap)) {
-    /* the inbox<->heap merge with a CACHED root: an owned ref to the
-     * last-validated heap[0] plus its (t, band, key). While the root
-     * object is unchanged, its triple is a lower bound on the live head
-     * (a later cancel only moves the live head LATER), so a row that
-     * beats the cached triple may dispatch without touching the
-     * cancelled set; anything else re-validates through heap_head.
-     * This turns the per-row cost of the hot merge from a set lookup +
-     * four tuple reads into one pointer compare + int compares. */
-    PyObject *h0own = NULL; /* owned: validated head at cache time */
+  if (PyList_GET_SIZE(h->heap) || h->ct_n) {
+    /* the inbox<->heaps merge with a CACHED head: the (t, band, key) of
+     * the last-validated union head, with the two roots it was validated
+     * against (an owned ref to the Python root, the C root's seq). While
+     * neither root has changed, the triple is a lower bound on the live
+     * head (an earlier push would change a root; a cancel only moves the
+     * live head LATER), so a row that beats it may dispatch without
+     * validating the head again. This turns the per-row cost of the hot
+     * merge from a set lookup + four tuple reads into two identity
+     * compares + int compares. */
+    PyObject *p0own = NULL; /* owned: the Python root at cache time */
+    int64_t c0seq = -1;     /* the C root's seq at cache time */
+    int cached = 0;
     int64_t h0t = 0, h0band = 0, h0key = 0;
     int rcod2 = -1;
     for (;;) {
-      if (h0own && pos < ln && PyList_GET_SIZE(h->heap) &&
-          PyList_GET_ITEM(h->heap, 0) == h0own) {
+      if (cached && pos < ln &&
+          (PyList_GET_SIZE(h->heap) ? PyList_GET_ITEM(h->heap, 0) : NULL) ==
+              p0own &&
+          (h->ct_n ? h->ct[0].seq : -1) == c0seq) {
         int64_t ti = rows[pos].t;
         if (ti < h0t ||
             (ti == h0t &&
@@ -1055,21 +1509,25 @@ static int64_t run_host_c(CoreObject *c, CHost *h, int hid, int64_t end) {
           continue;
         }
       }
-      int herr;
-      PyObject *h0 = heap_head(h, &herr);
-      if (herr) goto mdone;
-      int hv = 0;
-      h0t = 0; h0band = 0; h0key = 0;
-      if (h0) {
-        Py_INCREF(h0);
-        Py_XSETREF(h0own, h0);
-        h0t = tup_i64(h0, 0);
-        h0band = tup_i64(h0, 1);
-        h0key = tup_i64(h0, 2);
-        hv = h0t < end;
-      } else {
-        Py_CLEAR(h0own);
+      PyObject *p0 = NULL;
+      int which = union_head(h, &p0);
+      if (which < 0) goto mdone;
+      cached = which != 0;
+      if (which == 1) {
+        h0t = tup_i64(p0, 0);
+        h0band = tup_i64(p0, 1);
+        h0key = tup_i64(p0, 2);
+      } else if (which == 2) {
+        h0t = h->ct[0].t;
+        h0band = 1;
+        h0key = h->ct[0].seq;
       }
+      PyObject *pr = PyList_GET_SIZE(h->heap) ? PyList_GET_ITEM(h->heap, 0)
+                                              : NULL;
+      Py_XINCREF(pr);
+      Py_XSETREF(p0own, pr);
+      c0seq = h->ct_n ? h->ct[0].seq : -1;
+      int hv = cached && h0t < end;
       if (pos < ln) {
         int64_t ti = rows[pos].t;
         /* inbox rows are BAND_NET (0): they win same-time ties unless a
@@ -1084,19 +1542,18 @@ static int64_t run_host_c(CoreObject *c, CHost *h, int hid, int64_t end) {
         }
       }
       if (hv) {
-        PyObject *ev = heap_pop(h->heap);
-        if (!ev) goto mdone;
-        PyObject *seq = PyTuple_GET_ITEM(ev, 3);
-        if (PySet_Discard(h->live, seq) < 0) { Py_DECREF(ev); goto mdone; }
-        now = tup_i64(ev, 0);
+        now = h0t;
         now_dirty = 0;
-        if (attr_set_i64(h->host, S_now, now) < 0) { Py_DECREF(ev); goto mdone; }
-        PyObject *task = PyTuple_GET_ITEM(ev, 4);
-        if (Py_TYPE(task) == &BtcTimer_Type) {
-          int rf = btc_timer_fire((struct BtcTimer *)task, now);
-          Py_DECREF(ev);
-          if (rf < 0) goto mdone;
+        if (attr_set_i64(h->host, S_now, now) < 0) goto mdone;
+        if (which == 2) {
+          /* a C timer: its handler runs here, with no Python call */
+          if (ct_fire(c, h, ct_pop(h), now) < 0) goto mdone;
         } else {
+          PyObject *ev = heap_pop(h->heap);
+          if (!ev) goto mdone;
+          PyObject *seq = PyTuple_GET_ITEM(ev, 3);
+          if (PySet_Discard(h->live, seq) < 0) { Py_DECREF(ev); goto mdone; }
+          PyObject *task = PyTuple_GET_ITEM(ev, 4);
           int64_t t0 = py_enter(c);
           PyObject *res = PyObject_CallNoArgs(task);
           py_leave(c, t0);
@@ -1112,7 +1569,7 @@ static int64_t run_host_c(CoreObject *c, CHost *h, int hid, int64_t end) {
     }
     rcod2 = 0;
   mdone:
-    Py_XDECREF(h0own);
+    Py_XDECREF(p0own);
     if (rcod2 < 0) goto done;
   }
   err = 0;
@@ -1151,18 +1608,20 @@ static int act_pend_add(CoreObject *c, int64_t hid) {
 
 /* ---- the due-time index ------------------------------------------------
  * A binary min-heap of (key time, host id) over the hosts whose event
- * heap is not empty. The invariant: such a host has an entry whose time
- * is its due_t, and due_t is at most the time of its heap root (a
- * cancelled root included). An entry whose time is not its host's due_t
- * is stale and is dropped when it reaches the top; two entries of one
- * host with the same time count once. What keeps the invariant:
- *   - a push that makes a new heap root lowers the key to the root's time
- *     (cep_schedule here; a Python push through EventQueue.on_first ->
- *     Core.activate(hid, t));
+ * heaps (the Python heap and the C timer heap) are not both empty. The
+ * invariant: such a host has an entry whose time is its due_t, and due_t
+ * is at most the time of its first entry over both heaps (a cancelled
+ * one included), the lesser of the two roots. An entry whose time is not
+ * its host's due_t is stale and is dropped when it reaches the top; two
+ * entries of one host with the same time count once. What keeps the
+ * invariant:
+ *   - a push that makes a new root of either heap lowers the key to the
+ *     root's time (ct_arm for a C timer; a Python push through
+ *     EventQueue.on_first -> Core.activate(hid, t));
  *   - a pop only raises the root, and a cancel leaves it in place;
  *   - a host selected for a round (due_sel) is re-keyed at its root once
  *     the round's runs are done, so pushes meanwhile leave it alone;
- *   - index_heaps keys every host from its heap (attach and restore). */
+ *   - index_heaps keys every host from its heaps (attach and restore). */
 #define DUE_NONE INT64_MAX
 
 static int due_push(CoreObject *c, int64_t t, int64_t hid) {
@@ -1203,7 +1662,8 @@ static DueEnt due_pop(CoreObject *c) {
   return top;
 }
 
-/* a push made `t` a root of host `hid`'s heap: key it there at most */
+/* a push made `t` a root of one of host `hid`'s heaps: key it there at
+ * most */
 static int due_lower(CoreObject *c, int64_t hid, int64_t t) {
   CHost *h = &c->hs[hid];
   if (h->due_sel || t >= h->due_t) return 0;
@@ -1211,23 +1671,23 @@ static int due_lower(CoreObject *c, int64_t hid, int64_t t) {
   return due_push(c, t, hid);
 }
 
-/* key host `hid` at its heap root (no entry when the heap is empty) */
+/* key host `hid` at its first entry (no entry when both heaps are
+ * empty) */
 static int due_rekey(CoreObject *c, int64_t hid) {
   CHost *h = &c->hs[hid];
   h->due_sel = 0;
-  int64_t t = PyList_GET_SIZE(h->heap)
-      ? tup_i64(PyList_GET_ITEM(h->heap, 0), 0) : DUE_NONE;
+  int64_t t = root_t(h);
   if (t == h->due_t) return 0;
   h->due_t = t;
   return t == DUE_NONE ? 0 : due_push(c, t, hid);
 }
 
 /* min pending event time over the hosts — the skip-ahead path's
- * `min(equeue.next_time() for active)` of the Python planes. The index's top is validated
- * with heap_head, which drops cancelled heads exactly like
- * EventQueue.next_time, and re-keyed until its key is its host's live
- * head: every other key is at most its own live head, so that head is
- * the minimum and the round grid cannot move. */
+ * `min(equeue.next_time() for active)` of the Python planes. The index's
+ * top is validated with union_head, which drops cancelled heads exactly
+ * like EventQueue.next_time, and re-keyed until its key is its host's
+ * live head: every other key is at most its own live head, so that head
+ * is the minimum and the round grid cannot move. */
 static PyObject *Core_next_time(CoreObject *c, PyObject *noarg) {
   (void)noarg;
   if (!c->due_on) {
@@ -1238,10 +1698,11 @@ static PyObject *Core_next_time(CoreObject *c, PyObject *noarg) {
     DueEnt top = c->due[0];
     CHost *h = &c->hs[top.hid];
     if (top.t != h->due_t) { due_pop(c); continue; } /* stale */
-    int err;
-    PyObject *head = heap_head(h, &err);
-    if (err) return NULL;
-    int64_t t = head ? tup_i64(head, 0) : DUE_NONE;
+    PyObject *p0 = NULL;
+    int which = union_head(h, &p0);
+    if (which < 0) return NULL;
+    int64_t t = which == 1 ? tup_i64(p0, 0)
+                : which == 2 ? h->ct[0].t : DUE_NONE;
     if (t == top.t)
       return PyLong_FromLongLong(t < T_NEVER_C ? t : T_NEVER_C);
     due_pop(c);
@@ -2391,6 +2852,18 @@ static PyObject *grab_array(PyObject *o, const char *name, int typenum,
   return v;
 }
 
+/* empty a host's C timer heap, releasing its targets */
+static void ct_release(CHost *h) {
+  int n = h->ct_n;
+  h->ct_n = h->ct_dead = 0;
+  for (int j = 0; j < n; j++) {
+    int r = h->ct[j].rec;
+    PyObject *tg = h->ctr[r].target;
+    ct_rec_free(h, r);
+    Py_XDECREF(tg);
+  }
+}
+
 static int Core_traverse(CoreObject *c, visitproc visit, void *arg) {
   Py_VISIT(c->hosts);
   Py_VISIT(c->pending);
@@ -2411,6 +2884,7 @@ static int Core_traverse(CoreObject *c, visitproc visit, void *arg) {
       Py_VISIT(h->listeners);
       Py_VISIT(h->ack_eps);
       for (int j = 0; j < h->nports; j++) Py_VISIT(h->gs[j]);
+      for (int j = 0; j < h->ct_n; j++) Py_VISIT(h->ctr[h->ct[j].rec].target);
       /* inbox payloads / egress payloads are bytes|None (no cycles) */
     }
   }
@@ -2442,6 +2916,7 @@ static int Core_clear_gc(CoreObject *c) {
       h->inbox_n = 0;
       for (int j = 0; j < h->erow_n; j++) Py_CLEAR(h->erow[j].payload);
       h->erow_n = 0;
+      ct_release(h);
     }
   }
   return 0;
@@ -2466,6 +2941,9 @@ static void Core_dealloc(CoreObject *c) {
       for (int j = 0; j < h->erow_n; j++) Py_XDECREF(h->erow[j].payload);
       free(h->erow);
       for (int j = 0; j < h->nports; j++) Py_XDECREF(h->gs[j]);
+      ct_release(h);
+      free(h->ct);
+      free(h->ctr);
     }
     free(c->hs);
   }
@@ -2606,6 +3084,9 @@ static int Core_init(CoreObject *c, PyObject *args, PyObject *kwds) {
     h->live = PyObject_GetAttrString(eq, "_live");
     h->cancelled = PyObject_GetAttrString(eq, "_cancelled");
     if (!h->heap || !h->live || !h->cancelled) return -1;
+    h->seqp = eq_seq_slot(eq);
+    if (!h->seqp) return -1;
+    h->ctr_free = -1;
     PyObject *pcap = PyObject_GetAttr(host, S_pcap);
     if (!pcap) return -1;
     h->py_mode = pcap != Py_None;
@@ -2658,11 +3139,13 @@ static int Core_init(CoreObject *c, PyObject *args, PyObject *kwds) {
   return 0;
 }
 
-/* (re)build the due-time index from every host's heap: the controller
+/* (re)build the due-time index from every host's heaps: the controller
  * calls it once the hosts' on_first hooks point at this core (attach and
- * checkpoint restore) */
+ * checkpoint restore). C timers a checkpoint folded into the Python heaps
+ * return to the C heaps first. */
 static PyObject *Core_index_heaps(CoreObject *c, PyObject *noarg) {
   (void)noarg;
+  if (ct_adopt_all(c) < 0) return NULL;
   if (!c->sel) {
     c->sel = malloc(sizeof(int64_t) * (size_t)(c->H ? c->H : 1));
     if (!c->sel) return PyErr_NoMemory();
@@ -2791,7 +3274,7 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
     Py_XDECREF(ctrs);
   }
   PyObject *out = Py_BuildValue(
-      "{s{s(dL)s(dL)s(dL)}s{sLsLsLsLsLsLsLsLsLsLsLsL}s{sLsLsL}sLsLsL}",
+      "{s{s(dL)s(dL)s(dL)}s{sLsLsLsLsLsLsLsLsLsLsLsLsLsLsL}s{sLsLsL}sLsLsL}",
       "timers",
       "events.py_callback", c->py_ns / 1e9, (long long)c->py_calls,
       "barrier.handoff", c->handoff_ns / 1e9, (long long)c->handoff_calls,
@@ -2801,6 +3284,9 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
       "events.gossip", (long long)c->n_gossip,
       "events.hosts_examined", (long long)c->n_examined,
       "events.hosts_run", (long long)c->n_run,
+      "timers.armed", (long long)c->n_ct_armed,
+      "timers.cancelled", (long long)c->n_ct_cancelled,
+      "timers.fired", (long long)c->n_ct_fired,
       "tor.ctrl_to_py", (long long)c->n_ctrl_py,
       "tor.circuits", (long long)c->n_circuits,
       "btc.trickles", (long long)c->n_trickles,
@@ -2839,6 +3325,7 @@ static PyObject *Core_fold_counters(CoreObject *c, PyObject *noarg) {
   c->res_peak = c->res_held;
   c->n_emit = c->n_gossip = 0;
   c->sel_ns = c->sel_calls = c->n_examined = c->n_run = 0;
+  c->n_ct_armed = c->n_ct_cancelled = c->n_ct_fired = 0;
   for (int j = 0; j < 4; j++) c->sect_ns[j] = c->sect_calls[j] = 0;
   c->n_ctrl_py = c->n_circuits = 0;
   c->n_trickles = c->n_inv_msgs = c->n_inv_entries = 0;
@@ -2854,6 +3341,9 @@ static PyObject *Core_relay_new(CoreObject *c, PyObject *args);
 static PyObject *Core_tor_client_sink(CoreObject *c, PyObject *args);
 static PyObject *Core_btc_node(CoreObject *c, PyObject *args);
 static PyObject *Core_adopt(CoreObject *c, PyObject *arg);
+static PyObject *Core_fold_timers(CoreObject *c, PyObject *noarg);
+static PyObject *Core_adopt_timers(CoreObject *c, PyObject *noarg);
+static PyObject *Core_host_timers(CoreObject *c, PyObject *arg);
 
 /* -- fault lifecycle (shadow_tpu/faults.py) ------------------------------ */
 static PyObject *Core_bind_shard(CoreObject *c, PyObject *args) {
@@ -3114,7 +3604,15 @@ static PyMethodDef Core_methods[] = {
     {"pending_batches", (PyCFunction)Core_pending_batches, METH_NOARGS,
      "number of handed-off device batches the core holds"},
     {"index_heaps", (PyCFunction)Core_index_heaps, METH_NOARGS,
-     "(re)build the due-time index from every host's event heap"},
+     "(re)build the due-time index from every host's event heaps, first "
+     "adopting C timers a checkpoint folded into the Python heaps"},
+    {"host_timers", (PyCFunction)Core_host_timers, METH_O,
+     "(hid) -> HostTimers: the host's C timers for its EventQueue"},
+    {"fold_timers", (PyCFunction)Core_fold_timers, METH_NOARGS,
+     "move every C timer into its host's Python heap as the (time, band, "
+     "key, seq, task) entry it stands for (checkpoint write)"},
+    {"adopt_timers", (PyCFunction)Core_adopt_timers, METH_NOARGS,
+     "move C timers found in the Python heaps back into the C heaps"},
     {"gossip_register", (PyCFunction)Core_gossip_register, METH_VARARGS,
      "(hid, port, peers) -> GossipState; registers the C dgram handler"},
     {"spec_demand", (PyCFunction)Core_spec_demand, METH_VARARGS,
@@ -3180,8 +3678,9 @@ static PyTypeObject Core_Type = {
  * StreamEndpoint/StreamSender/StreamReceiver, one object per connection
  * half. App callbacks (on_data, on_connected, ...) stay Python; all
  * protocol bookkeeping (windows, cumulative acks, OOO buffering,
- * retransmission, close handshakes) runs here. Timers go through the
- * host's Python event queue (bound-method tasks), so event identity and
+ * retransmission, close handshakes) runs here. Timers live in the host's
+ * C timer heap, each the entry the Python twin's schedule_in would have
+ * pushed, with its seq from the same counter, so event identity and
  * ordering match the Python twin exactly.
  * ====================================================================== */
 
@@ -3326,18 +3825,18 @@ typedef struct CEp {
   int local_port, remote_host, remote_port;
   int initiator, state, syn_tries, fin_tries, peer_fin;
   int64_t rto_ns;
-  PyObject *ctl_timer; /* owned PyLong handle, or NULL */
+  CTHandle ctl_timer; /* the SYN or FIN timer */
   /* opt-in idle timeout (StreamEndpoint.set_idle_timeout twin): rearmed
    * on every arrival, expiry surfaces ETIMEDOUT — the pure-receiver
    * dead-peer detector fault configs rely on (faults.py) */
   int64_t idle_timeout_ns; /* 0 = off */
-  PyObject *idle_timer; /* owned PyLong handle, or NULL */
+  CTHandle idle_timer;
   /* sender */
   int64_t chunk, cwnd, ssthresh, send_buffer, snd_nxt, snd_una, adv_wnd;
   int64_t buffered, bytes_acked;
   int64_t rto_backoff;
   int retries, loss_events;
-  PyObject *rto_timer; /* owned PyLong handle, or NULL */
+  CTHandle rto_timer;
   Ring sendbuf; /* SQEnt */
   Ring rtx;     /* RtxEnt */
   /* receiver */
@@ -3416,9 +3915,6 @@ static int64_t cep_now(CEp *e, int *err) {
   return v;
 }
 
-static PyObject *S_schedule_in, *S_cancel_m, *S_rto_fire, *S_syn_fire,
-    *S_fin_fire, *S_drop_fire, *S_idle_fire, *S_seq_ctr;
-
 static int64_t cep_window(CEp *e, int *err) {
   *err = 0;
   int64_t unread = 0;
@@ -3457,59 +3953,17 @@ static int cep_mark_ack(CEp *e) {
   return PyDict_SetItem(aeps, (PyObject *)e, Py_None);
 }
 
-/* timers ride the host's Python event queue so seq/order match the twin
- * — but the push itself runs here (EventQueue.push twin over the cached
- * heap/_live/_seq structures): at tor_100k scale the per-unit RTO
- * arm/cancel churn through two Python method calls was a first-order
- * cost of the host loop. The shared _seq counter keeps C and Python
- * pushes on one deterministic sequence. */
-static int cep_schedule(CEp *e, int64_t delay, PyObject *meth_name,
-                        PyObject **slot) {
-  CHost *h = cep_h(e);
+/* arm one of the endpoint's timers `delay` after the host's clock, in
+ * the host's C timer heap (see "C timers"); `hd` (NULL: not tracked, as
+ * the Python twin's final drop) keeps the handle for a cancel */
+static int cep_arm(CEp *e, int64_t delay, int kind, CTHandle *hd) {
   int64_t now;
-  if (attr_i64(h->host, S_now, &now) < 0) return -1;
-  int64_t seq;
-  if (attr_i64(h->equeue, S_seq_ctr, &seq) < 0) return -1;
-  PyObject *task = PyObject_GetAttr((PyObject *)e, meth_name);
-  if (!task) return -1;
-  PyObject *seq_obj = PyLong_FromLongLong(seq);
-  /* (time, band=BAND_APP, key=seq, seq, task) — schedule_in's default
-   * band/key exactly (key < 0 resolves to seq) */
-  PyObject *entry = seq_obj
-      ? Py_BuildValue("(LiOOO)", (long long)(now + delay), 1, seq_obj,
-                      seq_obj, task)
-      : NULL;
-  Py_DECREF(task);
-  if (!entry) { Py_XDECREF(seq_obj); return -1; }
-  if (heap_push(h->heap, entry) < 0 ||
-      PySet_Add(h->live, seq_obj) < 0 ||
-      attr_set_i64(h->equeue, S_seq_ctr, seq + 1) < 0) {
-    Py_DECREF(seq_obj);
-    return -1;
-  }
-  /* a new root keys the host in the due-time index (what on_first does
-   * for a Python push) */
-  if (PyList_GET_ITEM(h->heap, 0) == entry &&
-      due_lower(e->core, e->hid, now + delay) < 0) {
-    Py_DECREF(seq_obj);
-    return -1;
-  }
-  Py_XSETREF(*slot, seq_obj); /* the handle is the seq int, like push() */
-  return 0;
+  if (attr_i64(cep_h(e)->host, S_now, &now) < 0) return -1;
+  return ct_arm(e->core, e->hid, now + delay, kind, (PyObject *)e, -1, hd);
 }
 
-static int cep_cancel_timer(CEp *e, PyObject **slot) {
-  if (!*slot) return 0;
-  CHost *h = cep_h(e);
-  /* EventQueue.cancel twin: lazy-cancel iff still live */
-  int live = PySet_Contains(h->live, *slot);
-  if (live < 0) { Py_CLEAR(*slot); return -1; }
-  if (live && PySet_Add(h->cancelled, *slot) < 0) {
-    Py_CLEAR(*slot);
-    return -1;
-  }
-  Py_CLEAR(*slot);
-  return 0;
+static void cep_cancel_timer(CEp *e, CTHandle *hd) {
+  ct_cancel(e->core, cep_h(e), hd);
 }
 
 static int cs_pump(CEp *e, int64_t now);
@@ -3522,12 +3976,9 @@ static int ce_reset(CEp *e, const char *reason);
 static int ce_enter_time_wait(CEp *e, int64_t now);
 
 static int cs_arm_rto(CEp *e, int reset) {
-  if (reset && e->rto_timer) {
-    if (cep_cancel_timer(e, &e->rto_timer) < 0) return -1;
-  }
-  if (!e->rto_timer)
-    return cep_schedule(e, e->rto_ns * e->rto_backoff, S_rto_fire,
-                        &e->rto_timer);
+  if (reset) cep_cancel_timer(e, &e->rto_timer);
+  if (!e->rto_timer.on)
+    return cep_arm(e, e->rto_ns * e->rto_backoff, CT_RTO, &e->rto_timer);
   return 0;
 }
 
@@ -3707,7 +4158,7 @@ static int cs_enter_recovery(CEp *e, int64_t now) {
 }
 
 static int cs_on_rto(CEp *e, int64_t now) {
-  Py_CLEAR(e->rto_timer);
+  e->rto_timer.on = 0;
   if (e->snd_nxt - e->snd_una == 0 || e->state == ST_CLOSED ||
       e->state == ST_TIME_WAIT)
     return 0;
@@ -3749,7 +4200,7 @@ static int cs_on_ack(CEp *e, int64_t now, int64_t cum_ack, int64_t wnd,
     if (e->rtx_done.count) i64set_prune_below(&e->rtx_done, cum_ack);
     e->rto_backoff = 1;
     e->retries = 0;
-    if (cep_cancel_timer(e, &e->rto_timer) < 0) return -1;
+    cep_cancel_timer(e, &e->rto_timer);
     if (e->snd_nxt - e->snd_una > 0) {
       if (cs_arm_rto(e, 0) < 0) return -1;
     }
@@ -4039,12 +4490,10 @@ static int cr_on_data(CEp *e, int64_t now, int64_t seq, int64_t n,
 }
 
 /* ---- endpoint (StreamEndpoint twin) ------------------------------------ */
-static int ce_cancel_ctl(CEp *e) { return cep_cancel_timer(e, &e->ctl_timer); }
-
 static int ce_drop(CEp *e) {
-  if (ce_cancel_ctl(e) < 0) return -1;
-  if (cep_cancel_timer(e, &e->rto_timer) < 0) return -1;
-  if (cep_cancel_timer(e, &e->idle_timer) < 0) return -1;
+  cep_cancel_timer(e, &e->ctl_timer);
+  cep_cancel_timer(e, &e->rto_timer);
+  cep_cancel_timer(e, &e->idle_timer);
   e->state = ST_CLOSED;
   e->tsink = NULL; /* borrowed back-pointer; the sink still owns us */
   Py_CLEAR(e->xsink); /* the exit stream dies with its server conn */
@@ -4088,14 +4537,12 @@ static int ce_enter_time_wait(CEp *e, int64_t now) {
   int was_open = e->state == ST_ESTABLISHED || e->state == ST_CLOSING ||
                  e->state == ST_FIN_SENT;
   e->state = ST_TIME_WAIT;
-  if (ce_cancel_ctl(e) < 0) return -1;
-  if (cep_cancel_timer(e, &e->rto_timer) < 0) return -1;
-  if (cep_cancel_timer(e, &e->idle_timer) < 0) return -1;
+  cep_cancel_timer(e, &e->ctl_timer);
+  cep_cancel_timer(e, &e->rto_timer);
+  cep_cancel_timer(e, &e->idle_timer);
   /* schedule the final drop WITHOUT tracking a handle (Python twin
    * schedules self._drop unconditionally) */
-  PyObject *tmp = NULL;
-  if (cep_schedule(e, 2 * e->rto_ns, S_drop_fire, &tmp) < 0) return -1;
-  Py_XDECREF(tmp);
+  if (cep_arm(e, 2 * e->rto_ns, CT_DROP, NULL) < 0) return -1;
   if (was_open && e->sink) return relay_conn_closed(e->sink);
   if (was_open && e->on_close && e->on_close != Py_None) {
     PyObject *tn = PyLong_FromLongLong(now);
@@ -4114,7 +4561,7 @@ static int ce_send_fin(CEp *e, int64_t now) {
   if (cep_emit(e, now, TK_FIN, 0, NULL, 0, 0, 0) < 0) return -1;
   int64_t mult = 1LL << (e->fin_tries - 1);
   if (mult > 64) mult = 64;
-  return cep_schedule(e, e->rto_ns * mult, S_fin_fire, &e->ctl_timer);
+  return cep_arm(e, e->rto_ns * mult, CT_FIN, &e->ctl_timer);
 }
 
 static int ce_sender_drained(CEp *e, int64_t now) {
@@ -4140,19 +4587,18 @@ static int ce_send_syn(CEp *e, int64_t now) {
   if (cep_emit(e, now, TK_SYN, 0, NULL, 0, 0, w) < 0) return -1;
   int64_t mult = 1LL << (e->syn_tries - 1);
   if (mult > 64) mult = 64;
-  return cep_schedule(e, e->rto_ns * mult, S_syn_fire, &e->ctl_timer);
+  return cep_arm(e, e->rto_ns * mult, CT_SYN, &e->ctl_timer);
 }
 
 /* the unit-arrival dispatch (StreamEndpoint.handle_fields twin) */
 static int ce_handle_fields(CEp *e, int64_t now, int k, int64_t nbytes,
                             PyObject *payload, int64_t seq) {
   int err;
-  if (e->idle_timer) {
+  if (e->idle_timer.on) {
     /* any arrival proves the peer is alive (StreamEndpoint twin: the
      * rearm consumes one seq, exactly like _rearm_idle's schedule_in) */
-    if (cep_cancel_timer(e, &e->idle_timer) < 0) return -1;
-    if (cep_schedule(e, e->idle_timeout_ns, S_idle_fire,
-                     &e->idle_timer) < 0)
+    cep_cancel_timer(e, &e->idle_timer);
+    if (cep_arm(e, e->idle_timeout_ns, CT_IDLE, &e->idle_timer) < 0)
       return -1;
   }
   if (k == TK_SYN) {
@@ -4168,7 +4614,7 @@ static int ce_handle_fields(CEp *e, int64_t now, int k, int64_t nbytes,
     if (e->state == ST_SYN_SENT) {
       e->state = ST_ESTABLISHED;
       e->adv_wnd = seq;
-      if (ce_cancel_ctl(e) < 0) return -1;
+      cep_cancel_timer(e, &e->ctl_timer);
       if (e->btc) {
         if (btc_connected(e->btc, now) < 0) return -1;
       } else if (e->on_connected && e->on_connected != Py_None) {
@@ -4208,7 +4654,7 @@ static int ce_handle_fields(CEp *e, int64_t now, int k, int64_t nbytes,
   }
   if (k == TK_FINACK) {
     if (e->state == ST_FIN_SENT) {
-      if (ce_cancel_ctl(e) < 0) return -1;
+      cep_cancel_timer(e, &e->ctl_timer);
       if (e->sink) {
         if (ce_drop(e) < 0) return -1;
         return relay_conn_closed(e->sink);
@@ -4263,9 +4709,6 @@ static int CEp_clear_gc(CEp *e) {
 static void CEp_dealloc(CEp *e) {
   PyObject_GC_UnTrack(e);
   Py_XDECREF(e->core);
-  Py_XDECREF(e->ctl_timer);
-  Py_XDECREF(e->rto_timer);
-  Py_XDECREF(e->idle_timer);
   for (int i = 0; i < e->sendbuf.count; i++)
     Py_XDECREF(((SQEnt *)ring_at(&e->sendbuf, i))->payload);
   for (int i = 0; i < e->rtx.count; i++)
@@ -4416,54 +4859,60 @@ static PyObject *CEp_emit(CEp *e, PyObject *args, PyObject *kw) {
   Py_RETURN_NONE;
 }
 
-/* timer entry points (scheduled on the host's Python event queue) */
-static PyObject *CEp_rto_fire(CEp *e, PyObject *noarg) {
-  (void)noarg;
+/* fire one of the endpoint's timers (the run loop calls it for a C
+ * timer; the _*_fire methods below for a Python caller) */
+static int cep_timer_fire(CEp *e, int kind, int64_t now) {
+  switch (kind) {
+  case CT_RTO:
+    return cs_on_rto(e, now);
+  case CT_SYN:
+    return e->state == ST_SYN_SENT ? ce_send_syn(e, now) : 0;
+  case CT_FIN:
+    return e->state == ST_FIN_SENT ? ce_send_fin(e, now) : 0;
+  case CT_DROP:
+    return ce_drop(e);
+  default: /* CT_IDLE: StreamEndpoint._idle_expired twin */
+    e->idle_timer.on = 0;
+    if (e->state == ST_CLOSED || e->state == ST_TIME_WAIT) return 0;
+    if (e->core->faults_active) cep_h(e)->d_timeouts++;
+    return ce_reset(e, "connection timed out (ETIMEDOUT): idle timeout — no "
+                       "traffic from peer");
+  }
+}
+
+static PyObject *cep_fire_py(CEp *e, int kind) {
   int err;
   int64_t now = cep_now(e, &err);
   if (err) return NULL;
-  if (cs_on_rto(e, now) < 0) return NULL;
+  if (cep_timer_fire(e, kind, now) < 0) return NULL;
   Py_RETURN_NONE;
+}
+
+/* the timers as Python tasks: what a Python caller of a C timer runs
+ * (EventQueue.pop_until on a host the Python loop runs) */
+static PyObject *CEp_rto_fire(CEp *e, PyObject *noarg) {
+  (void)noarg;
+  return cep_fire_py(e, CT_RTO);
 }
 
 static PyObject *CEp_syn_fire(CEp *e, PyObject *noarg) {
   (void)noarg;
-  if (e->state == ST_SYN_SENT) {
-    int err;
-    int64_t now = cep_now(e, &err);
-    if (err) return NULL;
-    if (ce_send_syn(e, now) < 0) return NULL;
-  }
-  Py_RETURN_NONE;
+  return cep_fire_py(e, CT_SYN);
 }
 
 static PyObject *CEp_fin_fire(CEp *e, PyObject *noarg) {
   (void)noarg;
-  if (e->state == ST_FIN_SENT) {
-    int err;
-    int64_t now = cep_now(e, &err);
-    if (err) return NULL;
-    if (ce_send_fin(e, now) < 0) return NULL;
-  }
-  Py_RETURN_NONE;
+  return cep_fire_py(e, CT_FIN);
 }
 
 static PyObject *CEp_drop_fire(CEp *e, PyObject *noarg) {
   (void)noarg;
-  if (ce_drop(e) < 0) return NULL;
-  Py_RETURN_NONE;
+  return cep_fire_py(e, CT_DROP);
 }
 
 static PyObject *CEp_idle_fire(CEp *e, PyObject *noarg) {
-  /* StreamEndpoint._idle_expired twin */
   (void)noarg;
-  Py_CLEAR(e->idle_timer);
-  if (e->state == ST_CLOSED || e->state == ST_TIME_WAIT) Py_RETURN_NONE;
-  if (e->core->faults_active) cep_h(e)->d_timeouts++;
-  if (ce_reset(e, "connection timed out (ETIMEDOUT): idle timeout — no "
-                  "traffic from peer") < 0)
-    return NULL;
-  Py_RETURN_NONE;
+  return cep_fire_py(e, CT_IDLE);
 }
 
 static PyObject *CEp_set_idle_timeout(CEp *e, PyObject *arg) {
@@ -4473,10 +4922,10 @@ static PyObject *CEp_set_idle_timeout(CEp *e, PyObject *arg) {
     t = PyLong_AsLongLong(arg);
     if (t == -1 && PyErr_Occurred()) return NULL;
   }
-  if (cep_cancel_timer(e, &e->idle_timer) < 0) return NULL;
+  cep_cancel_timer(e, &e->idle_timer);
   e->idle_timeout_ns = t > 0 ? t : 0;
   if (e->idle_timeout_ns &&
-      cep_schedule(e, e->idle_timeout_ns, S_idle_fire, &e->idle_timer) < 0)
+      cep_arm(e, e->idle_timeout_ns, CT_IDLE, &e->idle_timer) < 0)
     return NULL;
   Py_RETURN_NONE;
 }
@@ -4486,13 +4935,13 @@ static PyObject *CEp_set_idle_timeout(CEp *e, PyObject *arg) {
  * semantics to the Python endpoint's private methods */
 static PyObject *CEp_cancel_ctl_m(CEp *e, PyObject *noarg) {
   (void)noarg;
-  if (ce_cancel_ctl(e) < 0) return NULL;
+  cep_cancel_timer(e, &e->ctl_timer);
   Py_RETURN_NONE;
 }
 
 static PyObject *CEp_cancel_rto_m(CEp *e, PyObject *noarg) {
   (void)noarg;
-  if (cep_cancel_timer(e, &e->rto_timer) < 0) return NULL;
+  cep_cancel_timer(e, &e->rto_timer);
   Py_RETURN_NONE;
 }
 
@@ -4890,15 +5339,14 @@ static PyObject *Core_run_round(CoreObject *c, PyObject *args) {
   c->n_examined += examined;
   int64_t t1 = nsnow();
   c->sel_ns += t1 - t0;
-  /* run: a host runs if it has inbox rows or its heap root (a cancelled
-   * one included) is before `end` */
+  /* run: a host runs if it has inbox rows or its first entry over both
+   * heaps (a cancelled one included) is before `end` */
   int64_t executed = 0;
   int ok = 1;
   for (int64_t i = 0; i < n; i++) {
     int64_t hid = sel[i];
     CHost *h = &c->hs[hid];
-    int heap_due = PyList_GET_SIZE(h->heap) &&
-                   tup_i64(PyList_GET_ITEM(h->heap, 0), 0) < end;
+    int heap_due = root_t(h) < end;
     if (h->py_mode) {
       /* pcap hosts etc.: the Python run_events consumes _inbox lists */
       PyObject *ib = PyObject_GetAttr(h->host, S_inbox);
@@ -6050,11 +6498,13 @@ static PyObject *Core_tor_client_sink(CoreObject *c, PyObject *args) {
  * the handler ends (Core's MSG_MORE sends coalesce the same way); writes
  * the send buffer refuses wait in `pend` and go out on drain.
  *
- * Timers are BtcTimer objects on the host's event heap: one per
- * outbound peer (mean 2 s) and one shared by every inbound peer (mean
- * 5 s), armed when a queue turns non-empty, with exponential delays from
- * a splitmix64 stream seeded from the host RNG. The run loop calls them
- * directly, not as Python.
+ * Trickle timers live in the host's C timer heap (kinds CT_BTC_OUT and
+ * CT_BTC_IN): one per outbound peer (mean 2 s) and one shared by every
+ * inbound peer (mean 5 s), armed when a queue turns non-empty, with
+ * exponential delays from a splitmix64 stream seeded from the host RNG.
+ * The run loop fires them with btc_fire; they have no Python task, so
+ * the views that need one (a checkpoint's fold, a pcap host's pop)
+ * refuse them, as models/bitcoin.py refuses pcap hosts.
  *
  * Self time goes to the section events.btc; the stream sends a flush
  * makes go to events.stream.
@@ -6118,12 +6568,6 @@ typedef struct BtcNode {
   int64_t sent[BM_N];
 } BtcNode;
 
-typedef struct BtcTimer {
-  PyObject_HEAD
-  BtcNode *node; /* owned */
-  int idx;       /* the outbound peer, or -1: the inbound peers' timer */
-} BtcTimer;
-
 static PyTypeObject BtcNode_Type;
 static PyObject *S_next_ephemeral;
 
@@ -6146,37 +6590,11 @@ static int64_t btc_exp(BtcNode *n, int64_t mean) {
   return (int64_t)(-log(u) * (double)mean);
 }
 
+/* arm a trickle timer of outbound peer `idx` (-1: the inbound peers'
+ * one) in the host's C timer heap */
 static int btc_schedule(BtcNode *n, int64_t now, int64_t delay, int idx) {
-  CoreObject *c = n->core;
-  CHost *h = &c->hs[n->hid];
-  int64_t seq;
-  if (attr_i64(h->equeue, S_seq_ctr, &seq) < 0) return -1;
-  BtcTimer *t = PyObject_GC_New(BtcTimer, &BtcTimer_Type);
-  if (!t) return -1;
-  Py_INCREF(n);
-  t->node = n;
-  t->idx = idx;
-  PyObject_GC_Track((PyObject *)t);
-  PyObject *seq_obj = PyLong_FromLongLong(seq);
-  /* (time, band=BAND_APP, key=seq, seq, task), as cep_schedule */
-  PyObject *entry = seq_obj ? Py_BuildValue("(LiOON)", (long long)(now + delay),
-                                            1, seq_obj, seq_obj, t)
-                            : NULL;
-  if (!entry) {
-    if (seq_obj) Py_DECREF(seq_obj);
-    else Py_DECREF(t);
-    return -1;
-  }
-  if (heap_push(h->heap, entry) < 0 || PySet_Add(h->live, seq_obj) < 0 ||
-      attr_set_i64(h->equeue, S_seq_ctr, seq + 1) < 0) {
-    Py_DECREF(seq_obj);
-    return -1;
-  }
-  int r = 0;
-  if (PyList_GET_ITEM(h->heap, 0) == entry)
-    r = due_lower(c, n->hid, now + delay);
-  Py_DECREF(seq_obj);
-  return r;
+  return ct_arm(n->core, n->hid, now + delay,
+                idx >= 0 ? CT_BTC_OUT : CT_BTC_IN, (PyObject *)n, idx, NULL);
 }
 
 /* queue one message to `p`: it joins the link's queue and the handler's
@@ -6426,15 +6844,16 @@ static int btc_connected(BtcPeer *p, int64_t now) {
   return r;
 }
 
-static int btc_timer_fire(BtcTimer *t, int64_t now) {
-  BtcNode *n = t->node;
+/* fire the trickle timer of outbound peer `idx` (-1: the inbound
+ * peers') */
+static int btc_fire(BtcNode *n, int idx, int64_t now) {
   if (!n || !n->core) return 0;
   CoreObject *c = n->core;
   int prev = sect_enter(c, SECT_BTC);
   c->n_trickles++;
   int r = 0;
-  if (t->idx >= 0) {
-    BtcPeer *p = n->peers[t->idx];
+  if (idx >= 0) {
+    BtcPeer *p = n->peers[idx];
     p->fires++;
     r = btc_send_inv(n, p);
   } else {
@@ -6665,45 +7084,154 @@ static PyTypeObject BtcNode_Type = {
     .tp_doc = "a Bitcoin node's relay (models/bitcoin.py delegates)",
 };
 
-static int BtcTimer_traverse(BtcTimer *t, visitproc visit, void *arg) {
-  Py_VISIT(t->node);
+/* ---- C timers: Python tasks, checkpoint fold and adopt ------------------ */
+/* the Python task of a C timer, the task of the Python heap entry it
+ * stands for (a checkpoint's fold, a pcap host's pop_until): the
+ * endpoint's bound _*_fire method. A Bitcoin trickle timer has none, and
+ * refuses by name: Bitcoin runs on no pcap host and writes no
+ * checkpoint. */
+static int ct_task_check(const CTRec *r) {
+  if (r->kind < CT_BTC_OUT) return 1;
+  PyErr_SetString(PyExc_TypeError,
+                  "a Bitcoin trickle timer has no Python task (Bitcoin "
+                  "runs on no pcap host and writes no checkpoint)");
   return 0;
 }
 
-static int BtcTimer_clear_gc(BtcTimer *t) {
-  Py_CLEAR(t->node);
+static PyObject *ct_task(const CTRec *r) {
+  if (!ct_task_check(r)) return NULL;
+  PyObject *const names[] = {S_rto_fire, S_syn_fire, S_fin_fire,
+                             S_idle_fire, S_drop_fire};
+  return PyObject_GetAttr(r->target, names[r->kind]);
+}
+
+/* the kind of C timer a Python task fires (its endpoint target), or -1
+ * for any other task */
+static int ct_kind_of(PyObject *task, PyObject **target) {
+  if (!PyCFunction_Check(task)) return -1;
+  PyObject *self = PyCFunction_GET_SELF(task);
+  if (!self || !Py_IS_TYPE(self, &CEp_Type)) return -1;
+  const PyCFunction fires[] = {
+      (PyCFunction)CEp_rto_fire, (PyCFunction)CEp_syn_fire,
+      (PyCFunction)CEp_fin_fire, (PyCFunction)CEp_idle_fire,
+      (PyCFunction)CEp_drop_fire};
+  PyCFunction f = PyCFunction_GET_FUNCTION(task);
+  for (int k = CT_RTO; k <= CT_DROP; k++) {
+    if (f == fires[k]) {
+      *target = self;
+      return k;
+    }
+  }
+  return -1;
+}
+
+/* move the C timers among a host's Python heap entries (a restored
+ * checkpoint's, or fold_timers') into its C heap, dead if cancelled, and
+ * link each to the endpoint handle that names it */
+static int ct_adopt(CHost *h) {
+  Py_ssize_t n = PyList_GET_SIZE(h->heap), i;
+  PyObject *target;
+  for (i = 0; i < n; i++)
+    if (ct_kind_of(PyTuple_GET_ITEM(PyList_GET_ITEM(h->heap, i), 4),
+                   &target) >= 0)
+      break;
+  if (i == n) return 0;
+  PyObject *old = PyList_GetSlice(h->heap, 0, n);
+  if (!old || PyList_SetSlice(h->heap, 0, n, NULL) < 0) {
+    Py_XDECREF(old);
+    return -1;
+  }
+  int rc = 0;
+  for (i = 0; i < n && rc == 0; i++) {
+    PyObject *ent = PyList_GET_ITEM(old, i);
+    int kind = ct_kind_of(PyTuple_GET_ITEM(ent, 4), &target);
+    if (kind < 0) {
+      rc = heap_push(h->heap, Py_NewRef(ent));
+      continue;
+    }
+    PyObject *seqo = PyTuple_GET_ITEM(ent, 3);
+    int64_t t = tup_i64(ent, 0), seq = PyLong_AsLongLong(seqo);
+    int dead = PyErr_Occurred() ? -1 : PySet_Contains(h->cancelled, seqo);
+    if (dead < 0 || PySet_Discard(h->live, seqo) < 0 ||
+        PySet_Discard(h->cancelled, seqo) < 0) {
+      rc = -1;
+      break;
+    }
+    int pos = ct_push(h, t, seq, kind, target, -1, dead, NULL);
+    if (pos < 0) {
+      rc = -1;
+      break;
+    }
+    CEp *e = (CEp *)target;
+    CTHandle *hd = kind == CT_RTO    ? &e->rto_timer
+                   : kind == CT_IDLE ? &e->idle_timer
+                   : kind <= CT_FIN  ? &e->ctl_timer : NULL;
+    if (hd && hd->on && hd->seq == seq) hd->rec = h->ct[pos].rec;
+  }
+  Py_DECREF(old);
+  return rc;
+}
+
+static int ct_adopt_all(CoreObject *c) {
+  for (int64_t i = 0; i < c->H; i++)
+    if (ct_adopt(&c->hs[i]) < 0) return -1;
   return 0;
 }
 
-static void BtcTimer_dealloc(BtcTimer *t) {
-  PyObject_GC_UnTrack(t);
-  Py_XDECREF(t->node);
-  Py_TYPE(t)->tp_free((PyObject *)t);
-}
-
-/* a call from Python (a host not run by Core_run_round) fires it too */
-static PyObject *BtcTimer_call(BtcTimer *t, PyObject *args, PyObject *kw) {
-  (void)args;
-  (void)kw;
-  if (!t->node || !t->node->core) Py_RETURN_NONE;
-  int64_t now;
-  if (attr_i64(t->node->core->hs[t->node->hid].host, S_now, &now) < 0)
-    return NULL;
-  if (btc_timer_fire(t, now) < 0) return NULL;
+/* a checkpoint writes the C timers as the Python heap entries they would
+ * have been: (time, BAND_APP, seq, seq, task), in _live, and in
+ * _cancelled when dead. adopt_timers (and index_heaps, on restore) takes
+ * them back; the order, and so the run, is the same. A Bitcoin trickle
+ * timer refuses the fold before anything moves. */
+static PyObject *Core_fold_timers(CoreObject *c, PyObject *noarg) {
+  (void)noarg;
+  for (int64_t i = 0; i < c->H; i++) {
+    CHost *h = &c->hs[i];
+    for (int j = 0; j < h->ct_n; j++)
+      if (!ct_task_check(&h->ctr[h->ct[j].rec])) return NULL;
+  }
+  for (int64_t i = 0; i < c->H; i++) {
+    CHost *h = &c->hs[i];
+    for (int j = 0; j < h->ct_n; j++) {
+      CTEnt *e = &h->ct[j];
+      CTRec *r = &h->ctr[e->rec];
+      PyObject *task = ct_task(r);
+      PyObject *seq = task ? PyLong_FromLongLong(e->seq) : NULL;
+      PyObject *ent = seq ? Py_BuildValue("(LiOON)", (long long)e->t, 1,
+                                          seq, seq, task)
+                          : NULL;
+      if (!seq) Py_XDECREF(task);
+      int bad = !ent || heap_push(h->heap, ent) < 0 ||
+                PySet_Add(h->live, seq) < 0 ||
+                (r->dead && PySet_Add(h->cancelled, seq) < 0);
+      Py_XDECREF(seq);
+      if (bad) return NULL;
+    }
+    ct_release(h);
+  }
   Py_RETURN_NONE;
 }
 
-static PyTypeObject BtcTimer_Type = {
-    PyVarObject_HEAD_INIT(NULL, 0).tp_name = "_colcore.BtcTimer",
-    .tp_basicsize = sizeof(BtcTimer),
-    .tp_dealloc = (destructor)BtcTimer_dealloc,
-    .tp_flags = Py_TPFLAGS_DEFAULT | Py_TPFLAGS_HAVE_GC,
-    .tp_traverse = (traverseproc)BtcTimer_traverse,
-    .tp_clear = (inquiry)BtcTimer_clear_gc,
-    .tp_call = (ternaryfunc)BtcTimer_call,
-    .tp_free = PyObject_GC_Del,
-    .tp_doc = "a trickle timer of a BtcNode (an event-heap task)",
-};
+static PyObject *Core_adopt_timers(CoreObject *c, PyObject *noarg) {
+  (void)noarg;
+  if (ct_adopt_all(c) < 0) return NULL;
+  Py_RETURN_NONE;
+}
+
+static PyObject *Core_host_timers(CoreObject *c, PyObject *arg) {
+  int64_t hid = PyLong_AsLongLong(arg);
+  if (hid == -1 && PyErr_Occurred()) return NULL;
+  if (hid < 0 || hid >= c->H) {
+    PyErr_SetString(PyExc_ValueError, "host id out of range");
+    return NULL;
+  }
+  HostTimers *v = PyObject_GC_New(HostTimers, &HostTimers_Type);
+  if (!v) return NULL;
+  v->core = (CoreObject *)Py_NewRef(c);
+  v->hid = (int)hid;
+  PyObject_GC_Track((PyObject *)v);
+  return (PyObject *)v;
+}
 
 static PyObject *Core_btc_node(CoreObject *c, PyObject *args) {
   long long hid, seed_ll, tout, tin;
@@ -6928,6 +7456,22 @@ static GossipState *gossip_shell(void) {
   return g;
 }
 
+/* a timer handle as the Python twin keeps it: its seq, or None */
+static PyObject *ct_handle_obj(const CTHandle *hd) {
+  return hd->on ? PyLong_FromLongLong(hd->seq) : Py_NewRef(Py_None);
+}
+
+/* restore a handle; adopt_timers links it to its record */
+static int ct_handle_set(CTHandle *hd, PyObject *v) {
+  hd->on = 0;
+  hd->rec = -1;
+  if (v == Py_None) return 0;
+  hd->seq = PyLong_AsLongLong(v);
+  if (hd->seq == -1 && PyErr_Occurred()) return -1;
+  hd->on = 1;
+  return 0;
+}
+
 /* -- CEp export/restore (55 positional fields; ABI-guarded) -------------- */
 static PyObject *CEp_export_state(CEp *e, PyObject *noarg) {
   (void)noarg;
@@ -6936,25 +7480,31 @@ static PyObject *CEp_export_state(CEp *e, PyObject *noarg) {
   PyObject *oo = rt ? export_rtx(&e->ooo) : NULL;
   PyObject *sk = oo ? i64set_sorted_tuple(&e->sacked) : NULL;
   PyObject *rd = sk ? i64set_sorted_tuple(&e->rtx_done) : NULL;
-  if (!rd) {
+  PyObject *ctl = rd ? ct_handle_obj(&e->ctl_timer) : NULL;
+  PyObject *rto = ctl ? ct_handle_obj(&e->rto_timer) : NULL;
+  PyObject *idle = rto ? ct_handle_obj(&e->idle_timer) : NULL;
+  if (!idle) {
     Py_XDECREF(sb);
     Py_XDECREF(rt);
     Py_XDECREF(oo);
     Py_XDECREF(sk);
+    Py_XDECREF(rd);
+    Py_XDECREF(ctl);
+    Py_XDECREF(rto);
     return NULL;
   }
-  return Py_BuildValue(
+  PyObject *out = Py_BuildValue(
       "(iiiiOiiiOLOLLLLLLLLLLiiONNLLLLLiNOOOOOOiLLOLOLOiLLiLLNN)",
       e->hid, e->local_port, e->remote_host, e->remote_port,
       e->initiator ? Py_True : Py_False, e->state, e->syn_tries,
       e->fin_tries, e->peer_fin ? Py_True : Py_False,
-      (long long)e->rto_ns, ornone(e->ctl_timer), (long long)e->chunk,
+      (long long)e->rto_ns, ctl, (long long)e->chunk,
       (long long)e->cwnd, (long long)e->ssthresh,
       (long long)e->send_buffer, (long long)e->snd_nxt,
       (long long)e->snd_una, (long long)e->adv_wnd,
       (long long)e->buffered, (long long)e->bytes_acked,
       (long long)e->rto_backoff, e->retries, e->loss_events,
-      ornone(e->rto_timer), sb, rt, (long long)e->recv_buffer,
+      rto, sb, rt, (long long)e->recv_buffer,
       (long long)e->rcv_nxt, (long long)e->ooo_bytes,
       (long long)e->bytes_received, (long long)e->last_wnd, e->dup_acks,
       oo, ornone(e->app_unread), ornone(e->on_connected),
@@ -6962,10 +7512,14 @@ static PyObject *CEp_export_state(CEp *e, PyObject *noarg) {
       ornone(e->on_error), e->tgen_mode, (long long)e->tgen_pending,
       (long long)e->tgen_want, ornone(e->tgen_cb),
       (long long)e->tgen_t_first, ornone(e->xsink),
-      (long long)e->idle_timeout_ns, ornone(e->idle_timer),
+      (long long)e->idle_timeout_ns, idle,
       e->cc_kind, (long long)e->w_max, (long long)e->epoch_start,
       e->in_recovery ? 1 : 0, (long long)e->recover,
       (long long)e->sack_high, sk, rd);
+  Py_DECREF(ctl);
+  Py_DECREF(rto);
+  Py_DECREF(idle);
+  return out;
 }
 
 static PyObject *CEp_restore_state(CEp *e, PyObject *state) {
@@ -7039,9 +7593,10 @@ static PyObject *CEp_restore_state(CEp *e, PyObject *state) {
     Py_XINCREF(nv);                                     \
     Py_XSETREF(slot, nv);                               \
   } while (0)
-  EP_SLOT(e->ctl_timer, ctl_t);
-  EP_SLOT(e->rto_timer, rto_t);
-  EP_SLOT(e->idle_timer, idle_t);
+  if (ct_handle_set(&e->ctl_timer, ctl_t) < 0 ||
+      ct_handle_set(&e->rto_timer, rto_t) < 0 ||
+      ct_handle_set(&e->idle_timer, idle_t) < 0)
+    return NULL;
   EP_SLOT(e->app_unread, app_unread);
   EP_SLOT(e->on_connected, on_connected);
   EP_SLOT(e->on_data, on_data);
@@ -7639,8 +8194,6 @@ PyMODINIT_FUNC PyInit__colcore(void) {
   INTERN(S_socket_send_buffer, "socket_send_buffer");
   INTERN(S_socket_recv_buffer, "socket_recv_buffer");
   INTERN(S_dispatch, "dispatch");
-  INTERN(S_schedule_in, "schedule_in");
-  INTERN(S_cancel_m, "cancel");
   INTERN(S_rto_fire, "_rto_fire");
   INTERN(S_syn_fire, "_syn_fire");
   INTERN(S_fin_fire, "_fin_fire");
@@ -7660,7 +8213,8 @@ PyMODINIT_FUNC PyInit__colcore(void) {
       || PyType_Ready(&CBatch_Type) < 0
       || PyType_Ready(&CTorSink_Type) < 0
       || PyType_Ready(&CExitStream_Type) < 0
-      || PyType_Ready(&BtcNode_Type) < 0 || PyType_Ready(&BtcTimer_Type) < 0)
+      || PyType_Ready(&BtcNode_Type) < 0
+      || PyType_Ready(&HostTimers_Type) < 0)
     return NULL;
   PyObject *m = PyModule_Create(&colcore_module);
   if (!m) return NULL;

@@ -227,6 +227,10 @@ def _dead_core():
     return _DeadCoreHandle()
 
 
+def _no_view():
+    return None
+
+
 def finish_colcore_adopt(controller) -> None:
     """Bind every checkpoint-restored C object to the rebuilt core
     (called by Controller._reattach_runtime after attach_colcore)."""
@@ -267,6 +271,11 @@ class _SimPickler(pickle.Pickler):
                 # only reachable through activation-hook closures; the
                 # restore path rebuilds a fresh core and rewires them
                 return (_dead_core, ())
+            if name == "HostTimers":
+                # an event queue's view of its C timers, which the write
+                # folded into the queue itself; the restore's
+                # Controller._wire_hooks gives each queue a new view
+                return (_no_view, ())
             if name in ("Endpoint", "Relay", "TorSink", "GossipState",
                         "ExitStream"):
                 # shell first, state second: cycles (endpoint <-> relay
@@ -402,7 +411,14 @@ def save_checkpoint(controller, now: int) -> Path:
         **({"shard": controller.shard_id} if n_shards > 1 else {}),
     }
     tmp = path.with_suffix(".tmp")
+    core = getattr(eng, "_c", None)
     try:
+        if core is not None:
+            # C timers are written as the Python heap entries they stand
+            # for (the format stays the same); the restore's index_heaps
+            # adopts them back, as adopt_timers does here once the file
+            # is written
+            core.fold_timers()
         # stream the pickle straight into the temp file: a checkpoint at
         # north-star scale is GBs, and a BytesIO staging copy would hold
         # the whole thing in RAM twice on top of the live state
@@ -413,6 +429,9 @@ def save_checkpoint(controller, now: int) -> Path:
     except BaseException:
         tmp.unlink(missing_ok=True)
         raise
+    finally:
+        if core is not None:
+            core.adopt_timers()
     os.replace(tmp, path)  # a torn write can never look like a checkpoint
     return path
 

@@ -396,6 +396,7 @@ class Controller:
         if core is not None:
             for h in self.hosts:
                 h.equeue.on_first = partial(core.activate, h.id)
+                h.equeue._ct = core.host_timers(h.id)
             core.index_heaps()
             return
         if hasattr(self.engine, "emitters"):  # columnar Python paths
@@ -403,6 +404,7 @@ class Controller:
         for h in self.hosts:
             h.equeue.on_first = partial(_activate_id, self._active.add,
                                         h.id)
+            h.equeue._ct = None
 
     def _reattach_runtime(self, mirror_log: bool = True) -> None:
         """Rebuild the runtime-only pieces after a checkpoint restore:

@@ -42,9 +42,16 @@ class EventQueue:
     heap ordering total without comparing task callables. ``band``/``key``
     impose a canonical order on same-time ties that is stable across
     scheduler policies and data-plane backends (see BAND_NET above).
+
+    With the C engine attached, timers that C code arms (stream endpoints,
+    Bitcoin nodes) live in the host's C timer heap instead, each the
+    (time, BAND_APP, seq, seq) entry a push would have made, with its seq
+    from this queue's counter (native/colcore/colcore.c, "C timers").
+    ``_ct`` is then the host's view of them, and every reading below
+    folds them in: the queue behaves as the one heap it was.
     """
 
-    __slots__ = ("_heap", "_seq", "_live", "_cancelled", "on_first")
+    __slots__ = ("_heap", "_seq", "_live", "_cancelled", "on_first", "_ct")
 
     def __init__(self) -> None:
         self._heap: list[tuple[SimTime, int, int, int, Callable[[], None]]] = []
@@ -58,6 +65,9 @@ class EventQueue:
         #: Python planes add the host to the active set, the C engine keys
         #: the host at ``time`` in its due-time index (colcore.c).
         self.on_first = None
+        #: the host's C timers (colcore HostTimers) when the C engine is
+        #: attached (Controller._wire_hooks), else None
+        self._ct = None
 
     def push(self, time: SimTime, task: Callable[[], None],
              band: int = BAND_APP, key: int = -1) -> int:
@@ -84,7 +94,8 @@ class EventQueue:
         are discarded at delivery — keeping event counts identical to the
         columnar plane, whose resolved arrivals live outside the heap).
         Returns the number of events cancelled."""
-        n = 0
+        n = self._ct.clear() if (self._ct is not None
+                                 and band == BAND_APP) else 0
         for entry in self._heap:
             seq = entry[3]
             if (entry[1] == band and seq in self._live
@@ -95,17 +106,23 @@ class EventQueue:
 
     def next_time(self) -> SimTime:
         """Time of the earliest pending event, or T_NEVER if empty."""
+        if self._ct is not None:
+            return self._ct.next_time()
         self._drop_cancelled_head()
         return self._heap[0][0] if self._heap else T_NEVER
 
     def head(self):
         """The earliest pending entry (time, band, key, seq, task), or
         None — the columnar plane's inbox-merge peek."""
+        if self._ct is not None:
+            return self._ct.head()
         self._drop_cancelled_head()
         return self._heap[0] if self._heap else None
 
     def pop_until(self, end: SimTime) -> Optional[tuple[SimTime, Callable[[], None]]]:
         """Pop the earliest event with time < end, else None."""
+        if self._ct is not None:
+            return self._ct.pop_until(end)
         self._drop_cancelled_head()
         if self._heap and self._heap[0][0] < end:
             time, _band, _key, seq, task = heapq.heappop(self._heap)
@@ -128,6 +145,8 @@ class EventQueue:
         them in its pending store)."""
         out = [(e[0], e[1]) for e in self._heap
                if e[3] not in self._cancelled and e[1] != exclude_band]
+        if self._ct is not None and exclude_band != BAND_APP:
+            out += self._ct.live_times()
         out.sort()
         return out
 
@@ -136,11 +155,13 @@ class EventQueue:
         — live_times without materializing the sorted list (the telemetry
         sampler calls this once per host per sample)."""
         cancelled = self._cancelled
-        n = 0
+        n = len(self._ct) if (self._ct is not None
+                              and exclude_band != BAND_APP) else 0
         for e in self._heap:
             if e[1] != exclude_band and e[3] not in cancelled:
                 n += 1
         return n
 
     def __len__(self) -> int:
-        return len(self._heap) - len(self._cancelled)
+        n = len(self._heap) - len(self._cancelled)
+        return n if self._ct is None else n + len(self._ct)

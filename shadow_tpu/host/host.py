@@ -157,17 +157,22 @@ class Host:
         self._inbox = None
         eq = self.equeue
         heap = eq._heap
+        # with the C engine attached (a host it hands to this loop), C
+        # timers live outside `heap`: the fast path and the cached head
+        # below read only `heap`, so they are off, and head() and
+        # pop_until() merge both heaps
+        py_only = eq._ct is None
         dispatch = self.dispatch_row
         pos = 0
         ln = len(rows)
         # fast path: no heap events at all (common for workload hosts with
         # no pending timers) — straight row drain, re-checking only the
         # cheap emptiness bit in case a dispatch scheduled something
-        while pos < ln and not heap:
+        while pos < ln and not heap and py_only:
             dispatch(rows[pos])
             pos += 1
             n += 1
-        if heap:
+        if heap or not py_only:
             head = eq.head
             pop = eq.pop_until
             # the inbox<->heap merge with a CACHED head: while heap[0]
@@ -180,7 +185,8 @@ class Host:
             # hot row instead of a method call.
             h0 = None
             while True:
-                if h0 is not None and pos < ln and heap and heap[0] is h0:
+                if (h0 is not None and pos < ln and heap and heap[0] is h0
+                        and py_only):
                     row = rows[pos]
                     ti = row[0]
                     if (ti < h0[0]
